@@ -41,6 +41,9 @@ def main(argv: list[str]) -> int:
     )
     from predictionio_tpu.workflow.core import run_train
 
+    from predictionio_tpu.utils.jaxenv import ensure_compile_cache
+
+    ensure_compile_cache()
     with open(argv[1]) as f:
         spec = json.load(f)
     storage = Storage(storage_config_from_json(spec["storage"]))

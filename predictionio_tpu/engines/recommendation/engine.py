@@ -264,7 +264,9 @@ class ALSAlgorithmParams:
     # half the bytes, bf16xbf16->f32 scoring. Scores shift by the
     # quantization/rounding error (~1% relative for int8 at serving
     # rank — see tests/test_recommend_pallas.py bounds), so both are
-    # explicit opt-ins; "f32" keeps exact scoring. Applies to the
+    # explicit opt-ins; "f32" keeps f32 storage and accumulation (on the
+    # TPU the MXU still rounds the dot's operands to bf16: scores within
+    # 2^-8·Σ|u_k·x_k| of exact — ops/recommend_pallas.py). Applies to the
     # single-device staged state AND the sharded tier (ISSUE 14
     # brought ShardedRuntime to dtype parity).
     serve_dtype: str = "f32"
@@ -603,17 +605,23 @@ class ALSAlgorithm(Algorithm):
         vocab_ids = list(model.factors.user_vocab.to_dict())
         if not vocab_ids:
             return
+        # one known item id (none for an empty catalog: no filter then)
+        item_ids = list(model.factors.item_vocab.to_dict())[:1]
         for batch in (1, 8, 64):  # the full serving bucket ladder
             # nomask program
             self._predict_batch(
                 model, [Query(user=vocab_ids[0], num=10)] * batch
             )
-            # masked program (filters allocate the exclusion-mask variant)
-            self._predict_batch(
-                model,
-                [Query(user=vocab_ids[0], num=10, blacklist=["__warmup__"])]
-                * batch,
-            )
+            # exclusion programs — one per wire form, each its own
+            # compiled signature: a known-id blacklist ships as a row
+            # list (an unknown id would drop out and warm nothing); a
+            # whitelist (like a category filter or a blacklist over
+            # ROWLIST_MAX ids) ships as packed bit words
+            for filt in ({"blacklist": item_ids}, {"whitelist": item_ids}):
+                self._predict_batch(
+                    model,
+                    [Query(user=vocab_ids[0], num=10, **filt)] * batch,
+                )
 
     def _exclusion_mask(
         self, model: ALSModel, queries: Sequence[Query]

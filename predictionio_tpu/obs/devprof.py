@@ -64,11 +64,10 @@ from predictionio_tpu.analysis import tsan as _tsan
 
 # -- platform peaks ---------------------------------------------------------
 
-#: device_kind substring (lowercase) → (peak FLOP/s, peak HBM bytes/s).
-#: TPU numbers are the published per-chip bf16 dense peaks; the CPU row
-#: is a deliberately round server-class fallback so MFU stays a small
-#: honest fraction instead of None on dev boxes. Longest match wins
-#: ("tpu v5 lite" before "tpu v5").
+#: device_kind substring (lowercase) → (peak FLOP/s, peak HBM bytes/s):
+#: the published per-chip bf16 dense peaks. Longest match wins ("tpu v5
+#: lite" before "tpu v5"). A device_kind with no row has NO peaks — MFU
+#: and the HBM fraction then read None rather than a made-up number.
 PEAK_TABLE: dict[str, tuple[float, float]] = {
     "tpu v2": (45e12, 700e9),
     "tpu v3": (123e12, 900e9),
@@ -79,7 +78,6 @@ PEAK_TABLE: dict[str, tuple[float, float]] = {
     "tpu v5": (459e12, 2765e9),
     "tpu v6 lite": (918e12, 1640e9),
     "tpu v6e": (918e12, 1640e9),
-    "cpu": (2e11, 50e9),
 }
 
 #: dtype-aware peak FLOP/s per generation (ISSUE 11 satellite, carried
@@ -102,8 +100,6 @@ PEAK_DTYPE_TABLE: dict[str, dict[str, float]] = {
     "tpu v5": {"f32": 229.5e12, "int8": 918e12},
     "tpu v6 lite": {"f32": 459e12, "int8": 1836e12},
     "tpu v6e": {"f32": 459e12, "int8": 1836e12},
-    # CPU fallback: one round number for every dtype — dev boxes only
-    "cpu": {"f32": 2e11, "int8": 2e11},
 }
 
 #: batch padding ratio lives in [0, 1); these resolve the interesting
@@ -126,15 +122,14 @@ def platform_info(dtype: Optional[str] = None) -> dict:
     column (ISSUE 11 satellite); None/"bf16" keeps the legacy bf16
     entry. The resolved dtype peak rides in `peak_flops`; `peak_flops`
     with no dtype is unchanged from every prior PR."""
-    platform = kind = None
+    platform = kind = count = None
     if "jax" in sys.modules:
-        try:
-            import jax
+        import jax
 
-            dev = jax.devices()[0]
-            platform, kind = dev.platform, dev.device_kind
-        except Exception:
-            pass
+        devs = jax.devices()
+        platform, kind, count = (
+            devs[0].platform, devs[0].device_kind, len(devs)
+        )
     dt = dtype if dtype in ("int8", "f32") else None
     env_name = {
         "int8": "PIO_PEAK_FLOPS_INT8", "f32": "PIO_PEAK_FLOPS_F32",
@@ -146,31 +141,23 @@ def platform_info(dtype: Optional[str] = None) -> dict:
         peak_flops = _env_float("PIO_PEAK_FLOPS")
     peak_hbm = _env_float("PIO_PEAK_HBM_BPS")
     source = "env" if (peak_flops or peak_hbm) else None
-    if peak_flops is None or peak_hbm is None:
-        best = None
-        for key in (kind, platform):
-            if not key:
-                continue
-            lowered = str(key).lower()
-            for entry, peaks in PEAK_TABLE.items():
-                if entry in lowered and (
-                    best is None or len(entry) > len(best[0])
-                ):
-                    best = (entry, peaks)
-            if best is not None:
-                break
+    if (peak_flops is None or peak_hbm is None) and kind:
+        lowered = str(kind).lower()
+        best = max(
+            (e for e in PEAK_TABLE if e in lowered), key=len, default=None
+        )
         if best is not None:
             source = source or "table"
             if peak_flops is None:
-                peak_flops = best[1][0]
-                if dt is not None:
-                    dtyped = PEAK_DTYPE_TABLE.get(best[0], {})
-                    peak_flops = dtyped.get(dt, peak_flops)
+                peak_flops = PEAK_DTYPE_TABLE[best].get(
+                    dt, PEAK_TABLE[best][0]
+                )
             if peak_hbm is None:
-                peak_hbm = best[1][1]
+                peak_hbm = PEAK_TABLE[best][1]
     return {
         "platform": platform,
         "device_kind": kind,
+        "device_count": count,
         "peak_flops": peak_flops,
         "peak_hbm_bps": peak_hbm,
         "peak_source": source or "none",
@@ -253,6 +240,9 @@ class _Exec:
     # dtype → [flops, device_seconds, invocations] splits it so each
     # column rooflines against its own peak
     dtype_totals: dict = field(default_factory=dict)
+    # signature key of the most recent call — whose static kwargs the
+    # report shows as "what this executable last ran with"
+    last_sig: Optional[tuple] = None
 
 
 class ProfTotals(NamedTuple):
@@ -291,6 +281,18 @@ def _signature(args: tuple, kwargs: dict) -> tuple:
         return _leaf_sig(x)
 
     return (walk(args), walk(kwargs))
+
+
+def _static_kwargs(sig: Optional[tuple]) -> dict:
+    """JSON-able view of a `_signature` key's hashable kwargs."""
+    if sig is None or len(sig) != 2:
+        return {}
+    return {
+        k: v[1] if isinstance(v[1], (str, int, bool, type(None)))
+        else str(v[1])
+        for k, v in sig[1]
+        if isinstance(v, tuple) and len(v) == 2 and v[0] == "v"
+    }
 
 
 def _arg_device_span(args: tuple, kwargs: dict) -> float:
@@ -466,6 +468,7 @@ class DeviceProfiler:
                     # trip count in — kwarg scaling would double-count
                     scale = 1.0
                 rec.invocations += 1
+                rec.last_sig = sig
                 rec.device_seconds += dt
                 rec.flops_total += analysis.flops * scale
                 rec.bytes_total += analysis.bytes_accessed * scale
@@ -643,6 +646,11 @@ class DeviceProfiler:
         latest = sigs[-1] if sigs else _SigAnalysis()
         out = {
             "name": rec.name,
+            # the static (non-array) kwargs of the most recent call —
+            # kernel mode, storage dtype, mesh: which path an executable
+            # took is read from what RAN, not from what a gate was
+            # expected to pick
+            "static_kwargs": _static_kwargs(rec.last_sig),
             "signatures": len(rec.signatures),
             "invocations": rec.invocations,
             "compile_seconds": round(rec.compile_seconds, 4),

@@ -5,9 +5,8 @@ duration listener accumulates `/jax/core/compile/*` events — notably
 `backend_compile_duration`, one per XLA compile). Live-buffer gauges are
 callback gauges sampled at scrape time via `jax.live_arrays()`, so a
 `GET /metrics` shows the device-memory footprint *now*, not at some
-earlier sampling tick. Everything degrades to 0 when jax is absent or
-its private monitoring API moves — observability must never break
-serving."""
+earlier sampling tick. A process that never imported jax reads every
+gauge as 0 and never pays the import."""
 
 from __future__ import annotations
 
@@ -42,12 +41,9 @@ def ensure_compile_listener() -> None:
         if _listener_installed:
             return
         _listener_installed = True
-    try:
-        from jax._src import monitoring as _monitoring
+    import jax.monitoring
 
-        _monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:
-        pass  # private API drift: compile gauges stay at 0
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def compile_snapshot() -> tuple[int, float]:
@@ -91,12 +87,9 @@ def _live_arrays() -> list:
         # must not pay the multi-second jax import on their first scrape;
         # no jax loaded ⇒ no live buffers, truthfully
         return []
-    try:
-        import jax
+    import jax
 
-        return list(jax.live_arrays())
-    except Exception:
-        return []
+    return list(jax.live_arrays())
 
 
 def install_jax_gauges(registry: MetricsRegistry) -> None:
@@ -124,6 +117,6 @@ def install_jax_gauges(registry: MetricsRegistry) -> None:
         "jax_live_buffer_bytes",
         "bytes held by live jax arrays (sampled at scrape)",
         lambda: float(
-            sum(getattr(a, "nbytes", 0) or 0 for a in _live_arrays())
+            sum(a.nbytes for a in _live_arrays())
         ),
     )

@@ -157,11 +157,7 @@ def fused_row_pass(  # lint: disable=jit-boundary — inner boundary:
             jax.ShapeDtypeStruct((n_rows, k), jnp.float32),
             jax.ShapeDtypeStruct((n_rows, k * k), jnp.float32),
         ],
-        # jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5
-        compiler_params=getattr(
-            pltpu, "CompilerParams",
-            getattr(pltpu, "TPUCompilerParams", None),
-        )(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -210,11 +206,7 @@ def fused_col_pass(  # lint: disable=jit-boundary — inner boundary:
             jax.ShapeDtypeStruct((n_cols, k), jnp.float32),
             jax.ShapeDtypeStruct((n_cols, k * k), jnp.float32),
         ],
-        # jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5
-        compiler_params=getattr(
-            pltpu, "CompilerParams",
-            getattr(pltpu, "TPUCompilerParams", None),
-        )(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -249,26 +241,16 @@ def resolve_mode(requested: str = "auto"):
     with MXU work anyway. Kept in-tree with interpret-mode equivalence
     tests: PIO_PALLAS_DENSE=1 opts in (e.g. for re-measurement on a
     chip generation with cheaper VPU compares or costlier HBM)."""
-    import os
-
     if requested in (None, "off"):
         return None
     if requested == "interpret":
         return "interpret"
     env = _env_str("PIO_PALLAS_DENSE").strip()
     if env == "1":
-        return "tpu" if available() else None
+        from predictionio_tpu.utils.jaxenv import on_tpu
+
+        return "tpu" if on_tpu() else None
     if env == "interpret":
         return "interpret"
     return None
 
-
-def available() -> bool:
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-        from jax.experimental.pallas import tpu as _  # noqa: F401
-
-        return True
-    except Exception:
-        return False

@@ -61,20 +61,38 @@ parity against the XLA two-step in interpret mode (masked / unmasked /
 k edge cases / crafted cross-tile ties / packed-vs-rowlist
 equivalence).
 
-dtype modes: f32 (exact), bf16 (bf16 storage + bf16×bf16→f32 MXU dot —
-half the factor stream, scores within bf16 rounding), int8 (per-row
-symmetric quantization, int8×int8→int32 dot, scale-product dequant in
-registers — ~1/4 the factor stream).
+dtype modes: f32 (f32 storage and accumulation; on the TPU the MXU runs
+a default-precision dot on bf16-rounded operands, so scores sit within
+2^-8·Σ|q_k·x_k| of exact — see "On the chip" below), bf16 (bf16 storage +
+bf16×bf16→f32 MXU dot — half the factor stream, scores within bf16
+rounding), int8 (per-row symmetric quantization, int8×int8→int32 dot,
+scale-product dequant in registers — ~1/4 the factor stream).
 
 Gating mirrors ops/windowed_pallas.py: `resolve_mode("auto")` returns
-"tpu" only where the Mosaic lowering can actually run, "interpret"
-under PIO_PALLAS_RECOMMEND=interpret (the CPU test path), else None —
-callers then keep the XLA two-step (which still gets the int8/bf16,
-packed-mask, and donation wins). This box is CPU-only, so the TPU
-lowering is validated structurally (every primitive used has a Mosaic
-rule on this jax: while/cond/concatenate/slice/iota/reduce_max/
-select_n/dot_general/shift_right_logical/broadcast_in_dim); first TPU
-deployment must re-run the parity suite in "tpu" mode.
+"tpu" where jax's default backend is a TPU, "interpret" under
+PIO_PALLAS_RECOMMEND=interpret (the CPU test path), else None — callers
+then keep the XLA two-step (which still gets the int8/bf16, packed-mask,
+and donation wins).
+
+On the chip (v5e, jax 0.9.0, PR 21 — tests/chip_parity.py re-runs it;
+tests/test_tpu_lowering.py compiles every variant against a compile-only
+v5e topology in tier-1). Every variant — f32/bf16/int8 × no mask / packed
+words / row list × B 1/8/64 at k = 128, and the precomputed-score tail —
+compiles and runs. Against the XLA two-step on the same device: int8
+matches exactly; bf16 to a few f32 ulps with identical indices; cosine
+`similar` and the CCO tail exactly; crafted ties, a fully-masked row and
+k == n_items keep `lax.top_k`'s order; packed words and row lists give
+identical answers. f32 matches BIT-FOR-BIT at B = 8 and 64 — both paths
+run the MXU on bf16-rounded operands, at worst 2^-8.1 of the row's
+largest Σ|q_k·x_k| off a numpy f32 product — but NOT at B = 1, where XLA
+lowers `q @ items.T` as an exact f32 matrix-vector product and the
+kernel still uses the MXU: there a mode change moves f32 scores by up to
+that bound and reorders near-ties (64–81 % of top-128 indices equal on
+random factors). The
+packed-word input was the one variant the Mosaic lowering refused, as
+first written: a (B, tile/32) block of the (B, I_p/32) array is neither
+(8, 128)-divisible nor the full dims (`_fused_call` relays the words
+tile-major).
 """
 
 from __future__ import annotations
@@ -347,8 +365,16 @@ def _fused_call(
         in_specs.append(pl.BlockSpec((1, tile), lambda j: (0, j)))
         args.extend(scale_args)
     if mask_kind == "bits":
-        in_specs.append(pl.BlockSpec((b, tile // 32), lambda j: (0, j)))
-        args.append(mask_arg)
+        # tile-major words: a (B, tile/32) block of the (B, I_p/32) array
+        # is neither (8, 128)-divisible nor the full dims, which the
+        # Mosaic lowering refuses; relaid (n_tiles, B, tile/32) the
+        # block's trailing dims ARE the array's. The wire/sharding form
+        # stays (B, I_p/32) — this relayout is in-jit, per local slab.
+        words = mask_arg.reshape(b, n_tiles, tile // 32).transpose(1, 0, 2)
+        in_specs.append(
+            pl.BlockSpec((None, b, tile // 32), lambda j: (j, 0, 0))
+        )
+        args.append(words)
     elif mask_kind == "rows":
         in_specs.append(pl.BlockSpec((b, n_excl), lambda j: (0, 0)))
         args.append(mask_arg)
@@ -358,10 +384,6 @@ def _fused_call(
         scaled=scaled, int8=int8, precomputed=precomputed,
         n_tiles=n_tiles,
     )
-    # jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5
-    cp = getattr(
-        pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-    )(dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         kernel,
         grid=(n_tiles,),
@@ -378,7 +400,9 @@ def _fused_call(
             pltpu.VMEM((b, k), jnp.float32),
             pltpu.VMEM((b, k), jnp.int32),
         ],
-        compiler_params=cp,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(*args)
 
@@ -506,10 +530,12 @@ def _bspec(shape, index_map):
 
 def xla_scores(q, items, qs, isc):
     """The XLA fallback's score semantics, shared by EVERY serving verb
-    on every tier so a mode change can never change scores: int8
-    accumulates in int32 and dequantizes by the scale product; bf16
-    accumulates in f32; caller-supplied scales (cosine inverse norms)
-    multiply the same way the kernel's register pass does.
+    on every tier: int8 accumulates in int32 and dequantizes by the scale
+    product; bf16 accumulates in f32; caller-supplied scales (cosine
+    inverse norms) multiply the same way the kernel's register pass
+    does. int8 and bf16 scores are the kernel's; f32 scores are too on
+    the CPU and, on the TPU, from B = 8 up (module docstring, "On the
+    chip": at B = 1 XLA's product is exact and the kernel's is not).
 
     The f32/bf16 dot is spelled `q @ items.T`, NOT dot_general with a
     (1,)/(1,) contraction: measured on this jax's CPU backend the
@@ -611,17 +637,6 @@ def inv_norms_np(arr, pad_to: int = 0):
 # ---------------------------------------------------------------------------
 
 
-def available() -> bool:
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-        from jax.experimental.pallas import tpu as _  # noqa: F401
-
-        return True
-    except Exception:
-        return False
-
-
 def resolve_mode(requested: str = "auto"):
     """None (XLA two-step), "tpu", or "interpret" — resolved OUTSIDE
     the jit so trace caches key on it (windowed_pallas precedent).
@@ -640,6 +655,6 @@ def resolve_mode(requested: str = "auto"):
         return None
     if env == "interpret":
         return "interpret"
-    if env == "1":
-        return "tpu" if available() else None
-    return "tpu" if available() else None
+    from predictionio_tpu.utils.jaxenv import on_tpu
+
+    return "tpu" if on_tpu() else None

@@ -205,20 +205,20 @@ def resolve_pallas_mode(requested: str = "auto") -> Optional[str]:
     "interpret" forces the interpreter, "1"/unset means Pallas whenever
     the default device is a TPU. Callers embedding the result in a jit
     must treat it as a static argument (stage_windowed does)."""
-    from predictionio_tpu.ops import windowed_pallas
+    from predictionio_tpu.utils.jaxenv import on_tpu
 
     if requested in (None, "off"):
         return None
     if requested == "interpret":
         return "interpret"
     if requested in ("tpu", "1"):
-        return "tpu" if windowed_pallas.available() else None
+        return "tpu" if on_tpu() else None
     env = _env_str("PIO_PALLAS_WINDOWED").strip()
     if env == "0":
         return None
     if env == "interpret":
         return "interpret"
-    return "tpu" if windowed_pallas.available() else None
+    return "tpu" if on_tpu() else None
 
 
 def windowed_gram_b(

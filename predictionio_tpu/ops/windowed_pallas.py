@@ -85,8 +85,6 @@ def block_partials(
     Callers (windowed_gram_b) segment-sum the block partials into window
     rows exactly as they do for the XLA einsum path.
     """
-    # lazy: pallas.tpu cannot always import in a CPU-only process (tests
-    # force a CPU platform and strip the TPU plugin)
     from jax.experimental import pallas as pl
 
     n_blocks, k, b_e = y_t.shape
@@ -125,14 +123,3 @@ block_partials = _devprof.instrument(
     "ops.windowed_block_partials", block_partials
 )
 
-
-def available() -> bool:
-    """True when the TPU Pallas lowering can run here."""
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-        from jax.experimental.pallas import tpu as _  # noqa: F401
-
-        return True
-    except Exception:
-        return False

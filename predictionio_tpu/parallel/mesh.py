@@ -28,21 +28,11 @@ MODEL_AXIS = "mp"
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable `shard_map`: newer jax exposes `jax.shard_map`
-    (replication check kwarg `check_vma`), 0.4.x only
-    `jax.experimental.shard_map` (`check_rep`). Every sharded program
-    in the tree builds through this shim so a jax upgrade is one-line."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(
+    """`jax.shard_map` with the replication check off by default — every
+    sharded program in the tree builds through this one call site."""
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
+        check_vma=check,
     )
 
 

@@ -202,13 +202,13 @@ def cmd_accesskey_delete(args) -> int:
 
 
 def _serve_until_interrupt(server, banner: str) -> int:
-    """Start a ServerProcess, print the banner, block until Ctrl-C."""
-    import threading
-
+    """Start a ServerProcess, print the banner, block until Ctrl-C or
+    until the server stops itself (`GET /stop` ends the process — a
+    stopped server must not leave its process holding the device)."""
     port = server.start()
-    print(banner.format(port=port))
+    print(banner.format(port=port), flush=True)
     try:
-        threading.Event().wait()
+        server.wait()
     except KeyboardInterrupt:
         server.stop()
     return 0
@@ -216,8 +216,14 @@ def _serve_until_interrupt(server, banner: str) -> int:
 
 def cmd_train(args) -> int:
     from predictionio_tpu.core.base import WorkflowParams
-    from predictionio_tpu.workflow.core import load_variant, run_train
+    from predictionio_tpu.utils.jaxenv import ensure_compile_cache
+    from predictionio_tpu.workflow.core import (
+        format_device_profile,
+        load_variant,
+        run_train,
+    )
 
+    ensure_compile_cache()
     variant = load_variant(args.engine_json)
     wp = WorkflowParams(
         batch=args.batch or "",
@@ -237,21 +243,35 @@ def cmd_train(args) -> int:
     timings = (inst.env or {}).get("stage_timings")
     if timings:
         print(f"[INFO] Stage timings (s): {timings}")
+    profile = (inst.env or {}).get("device_profile")
+    if profile:
+        for line in format_device_profile(_json.loads(profile)):
+            print(f"[INFO] {line}")
     return 0 if inst.status in ("COMPLETED", "INTERRUPTED") else 1
 
 
 def cmd_deploy(args) -> int:
-    from predictionio_tpu.workflow.core import load_variant
+    from predictionio_tpu.utils.jaxenv import ensure_compile_cache
+    from predictionio_tpu.workflow.core import (
+        device_profile,
+        format_device_profile,
+        load_variant,
+    )
     from predictionio_tpu.workflow.server import (
         QueryServer,
         QueryServerConfig,
         latest_completed_runtime,
     )
 
+    ensure_compile_cache()
     variant = load_variant(args.engine_json)
     runtime = latest_completed_runtime(
         _storage(), variant["id"], args.engine_version, variant["id"]
     )
+    # warm-up has run every serving program once: say where, and in
+    # which kernel mode, before reporting the engine live
+    for line in format_device_profile(device_profile()):
+        print(f"[INFO] {line}")
     config = QueryServerConfig(
         ip=args.ip,
         port=args.port,

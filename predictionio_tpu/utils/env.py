@@ -351,10 +351,12 @@ _k("PIO_CAS_SETTLE_MAX_S", "float", 2.0,
 # -- bench harness -----------------------------------------------------------
 _k("PIO_BENCH_SCALE", "enum", "",
    "Set small for the CI-sized bench shapes (100K-scale).")
-_k("PIO_BENCH_HBM_PEAK", "float", 819e9,
-   "HBM roof (bytes/s) bench.py reports bandwidth fractions against.")
-_k("PIO_BENCH_PEAK_FLOPS", "float", 197e12,
-   "FLOP/s roof bench.py reports MFU against.")
+_k("PIO_BENCH_HBM_PEAK", "float", None,
+   "HBM roof (bytes/s) override for bench.py; unset = the devprof peak "
+   "table row for the device_kind (no row, no number).")
+_k("PIO_BENCH_PEAK_FLOPS", "float", None,
+   "FLOP/s roof override for bench.py; unset = the devprof peak table "
+   "row for the device_kind (no row, no number).")
 
 
 def knob_registry() -> list[Knob]:

@@ -620,7 +620,13 @@ class ServerProcess:
             self._server.server_close()
             self._server = None
 
+    def wait(self) -> None:
+        """Block until the serving thread exits — stop() from another
+        thread, or a server that stops itself (`GET /stop`)."""
+        thread = self._thread
+        if thread is not None:
+            thread.join()
+
     def serve_forever(self) -> None:
         self.start()
-        assert self._thread is not None
-        self._thread.join()
+        self.wait()

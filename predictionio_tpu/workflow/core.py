@@ -55,6 +55,52 @@ def load_variant(path: str) -> dict:
     return variant
 
 
+def device_profile() -> dict:
+    """Where this process's device work ran, from the profiler registry:
+    platform / device_kind / device_count as jax reports them (None in a
+    process that never loaded jax) and, per executed executable, the
+    static kwargs it ran with plus its compile and device seconds.
+    `pio train` records it on the EngineInstance row, `pio deploy`
+    prints it after warm-up; `GET /debug/profile` is the live view of
+    the same registry."""
+    from predictionio_tpu.obs import devprof
+
+    report = devprof.report()
+    keep = (
+        "static_kwargs", "invocations", "compile_seconds", "device_seconds",
+    )
+    return {
+        **{
+            k: report["platform"][k]
+            for k in ("platform", "device_kind", "device_count")
+        },
+        "executables": {
+            row["name"]: {k: row[k] for k in keep}
+            for row in report["executables"]
+        },
+    }
+
+
+def format_device_profile(profile: dict) -> list[str]:
+    """The `[INFO]` lines `pio train` / `pio deploy` print."""
+    lines = [
+        f"Device: platform={profile['platform']} "
+        f"device_kind={profile['device_kind']} "
+        f"device_count={profile['device_count']}"
+    ]
+    for name, row in sorted(profile["executables"].items()):
+        shown = ", ".join(
+            f"{k}={v}" for k, v in sorted(row["static_kwargs"].items())
+            if k in ("mode", "pallas_mode", "dense_dtype", "mesh")
+        )
+        lines.append(
+            f"Executable: {name}({shown}) x{row['invocations']} "
+            f"compile {row['compile_seconds']}s "
+            f"device {row['device_seconds']}s"
+        )
+    return lines
+
+
 def runtime_context_from_variant(
     storage: Storage,
     variant: dict,
@@ -138,13 +184,18 @@ def run_train(
     ctx = runtime_context_from_variant(storage, variant, "train", wp)
     ctx.instance_id = instance_id
 
-    def _record_timings() -> None:
+    def _record_timings(where_it_ran: bool = True) -> None:
         # the EngineInstance blob stays as a point-in-time snapshot of
         # what the unified registry recorded live (ISSUE 1)
         instance.env = dict(instance.env or {})
         instance.env["stage_timings"] = json.dumps(
             {k: round(v, 4) for k, v in ctx.stage_timings.items()}
         )
+        if where_it_ran:
+            # ... and of WHERE it ran: the device as jax reports it and
+            # the executables this process has run, with their static
+            # kwargs (which ALS path, which storage dtype, which mode)
+            instance.env["device_profile"] = json.dumps(device_profile())
 
     def _count_run(status: str) -> None:
         get_default_registry().counter(
@@ -213,7 +264,10 @@ def run_train(
     except Exception:
         instance.status = "ABORTED"
         instance.end_time = _dt.datetime.now(_dt.timezone.utc)
-        _record_timings()  # partial timings show WHERE the failed run spent time
+        # partial timings show WHERE the failed run spent time; the
+        # device is not asked again here — if it is what failed, asking
+        # would raise over the error being reported
+        _record_timings(where_it_ran=False)
         _count_run("ABORTED")
         instances.update(instance)
         raise

@@ -169,6 +169,24 @@ def test_platform_missing_from_peak_table_yields_no_mfu(monkeypatch):
     assert "mfu" not in prof  # derived fields absent, not wrong
 
 
+def test_unknown_device_kind_has_no_peaks(monkeypatch):
+    """PR 21: peaks come from the device_kind row or an env pin, never
+    from the platform name — the CPU (or any kind the table lacks)
+    yields None peaks and `peak_source == "none"`."""
+    jax = pytest.importorskip("jax")
+    monkeypatch.delenv("PIO_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("PIO_PEAK_HBM_BPS", raising=False)
+    info = devprof.platform_info()
+    assert info["platform"] == jax.devices()[0].platform == "cpu"
+    assert info["device_kind"] == jax.devices()[0].device_kind
+    assert info["device_count"] == len(jax.devices())
+    assert info["peak_flops"] is None and info["peak_hbm_bps"] is None
+    assert info["peak_source"] == "none"
+    assert devprof.platform_info("int8")["peak_flops"] is None
+    assert devprof.mfu(1e9, 1.0) is None
+    assert devprof.hbm_fraction(1e9, 1.0) is None
+
+
 def test_env_peak_override(monkeypatch):
     monkeypatch.setenv("PIO_PEAK_FLOPS", "1e12")
     monkeypatch.setenv("PIO_PEAK_HBM_BPS", "1e11")
@@ -186,9 +204,14 @@ def test_env_peak_override(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_real_jit_cost_memory_and_scale():
+def test_real_jit_cost_memory_and_scale(monkeypatch):
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
+
+    # the CPU has no row in the peak table (PR 21: no made-up peaks), so
+    # the derived roofline fields are exercised against operator pins
+    monkeypatch.setenv("PIO_PEAK_FLOPS", "2e11")
+    monkeypatch.setenv("PIO_PEAK_HBM_BPS", "5e10")
 
     @jax.jit
     def mm(a, b):
@@ -381,6 +404,10 @@ def test_query_server_debug_profile_acceptance(mem_storage, monkeypatch):
             {"name": "als", "params": {"rank": 4, "num_iterations": 3}}
         ],
     }
+    # a derived MFU needs a peak; the CPU test platform has none in the
+    # table (PR 21), so pin one the way an operator would
+    monkeypatch.setenv("PIO_PEAK_FLOPS", "2e11")
+    monkeypatch.setenv("PIO_PEAK_HBM_BPS", "5e10")
     run_train(mem_storage, variant)
     runtime = latest_completed_runtime(mem_storage, "profrec", "0", "profrec")
     srv = QueryServer(
