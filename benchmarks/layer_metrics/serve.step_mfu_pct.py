@@ -1,0 +1,4 @@
+"""The whole serving step's share of the chip's bf16 peak in the saturated
+cell, counted from the configuration; moves `query_qps`."""
+
+from benchmarks.serving_metrics import serve_step_mfu_pct as read  # noqa: F401
