@@ -209,17 +209,17 @@ class Engine(BaseEngine):
 
     # -- train (reference Engine.train:154 + object Engine.train:622) ------
     def train(self, ctx: RuntimeContext, engine_params: EngineParams) -> list[Any]:
-        # stage timings come FROM the spans (ISSUE 2): ctx.stage_timings
-        # feeds the EngineInstance row snapshot, the bridge declared at
-        # module import feeds train_stage_seconds{stage}, and the spans
-        # themselves land in /debug/traces — one measurement, three views
+        # stage timings come FROM the spans (ISSUE 2): run_train's span
+        # collector feeds the EngineInstance row snapshot, the bridge
+        # declared at module import feeds train_stage_seconds{stage},
+        # and the spans themselves land in /debug/traces — one
+        # measurement, three views
         wp = ctx.workflow_params
         with _stage_span("train.read") as sp:
             data_source = self.make_data_source(engine_params)
             sp.attrs["datasource"] = type(data_source).__name__
             td = data_source.read_training(ctx)
             _sanity(td, "training data", wp)
-        ctx.stage_timings["read"] = sp.duration
         if wp.stop_after_read:
             raise StopAfterReadInterruption()
 
@@ -228,11 +228,10 @@ class Engine(BaseEngine):
             sp.attrs["preparator"] = type(preparator).__name__
             pd = preparator.prepare(ctx, td)
             _sanity(pd, "prepared data", wp)
-        ctx.stage_timings["prepare"] = sp.duration
         if wp.stop_after_prepare:
             raise StopAfterPrepareInterruption()
 
-        with _stage_span("train.train") as sp:
+        with _stage_span("train.train"):
             algorithms = self.make_algorithms(engine_params)
             if not algorithms:
                 raise ParamsError("engine has no algorithms configured")
@@ -245,7 +244,6 @@ class Engine(BaseEngine):
                     model = algo.train(ctx, pd)
                 _sanity(model, f"model of algorithm #{i}", wp)
                 models.append(model)
-        ctx.stage_timings["train"] = sp.duration
         return models
 
     # -- serializable models (reference makeSerializableModels:283) --------

@@ -63,10 +63,6 @@ class RuntimeContext:
     # the EngineInstance id of the current train run ("" outside train
     # workflows) — keys mid-training checkpoints in MODELDATA
     instance_id: str = ""
-    # per-stage wall-clock seconds (read/prepare/train/persist), filled by
-    # Engine.train + run_train and recorded on the EngineInstance row
-    # (SURVEY §5 observability; reference had only Spark-UI visibility)
-    stage_timings: dict = field(default_factory=dict)
 
     @property
     def is_serving(self) -> bool:

@@ -37,6 +37,7 @@ from predictionio_tpu.core.base import RuntimeContext
 from predictionio_tpu.data.store.event_store import EventStoreFacade
 from predictionio_tpu.models import als
 from predictionio_tpu.obs import devprof as _devprof
+from predictionio_tpu.obs import spans as _spans
 
 
 # -- query/result (reference Engine.scala of the template) ------------------
@@ -701,83 +702,99 @@ class ALSAlgorithm(Algorithm):
     def _predict_batch(
         self, model: ALSModel, queries: Sequence[Query]
     ) -> list[PredictedResult]:
-        vocab = model.factors.user_vocab
-        known = [(i, vocab.get(q.user)) for i, q in enumerate(queries)]
-        known_ix = [(i, u) for i, u in known if u is not None]
-        results: list[PredictedResult] = [PredictedResult() for _ in queries]
-        if not known_ix:
-            return results
-        # fixed device-side k (pow2-bucketed above a floor) so q.num does
-        # NOT create a new compiled program per distinct value — warmup can
-        # actually cover live traffic; results are sliced to num on host
-        n_items = model.factors.item_factors.shape[0]
+        from predictionio_tpu.ops.topk import NEG_INF
         from predictionio_tpu.utils.bucket import batch_bucket, topk_bucket
 
-        k_req = min(max(q.num for q in queries), n_items)
-        k = topk_bucket(k_req, n_items)
-        user_rows = np.array([u for _, u in known_ix], dtype=np.int64)
-        full_mask, full_rows = self._exclusion_args(model, queries)
-        keep = [i for i, _ in known_ix]
-        sub_mask = full_mask[keep] if full_mask is not None else None
-        sub_rows = full_rows[keep] if full_rows is not None else None
-        n_real = len(user_rows)
-        bucket = batch_bucket(n_real)
-        if bucket != n_real:
-            user_rows = np.concatenate(
-                [user_rows, np.zeros(bucket - n_real, dtype=np.int64)]
-            )
-            if sub_mask is not None:
-                sub_mask = np.concatenate(
-                    [sub_mask, np.zeros((bucket - n_real, sub_mask.shape[1]), bool)]
+        results: list[PredictedResult] = [PredictedResult() for _ in queries]
+        # the three host/device phases of a batch are spans (ISSUE 25):
+        # what the host does before the device pass, the pass until the
+        # answers are host arrays, and the decode back to item ids
+        with _spans.span("als.predict.prepare") as sp:
+            vocab = model.factors.user_vocab
+            known = [(i, vocab.get(q.user)) for i, q in enumerate(queries)]
+            known_ix = [(i, u) for i, u in known if u is not None]
+            sp.attrs["live"] = len(known_ix)
+            if not known_ix:
+                return results
+            # fixed device-side k (pow2-bucketed above a floor) so q.num
+            # does NOT create a new compiled program per distinct value —
+            # warmup can actually cover live traffic; results are sliced
+            # to num on host
+            n_items = model.factors.item_factors.shape[0]
+            k_req = min(max(q.num for q in queries), n_items)
+            k = topk_bucket(k_req, n_items)
+            user_rows = np.array([u for _, u in known_ix], dtype=np.int64)
+            full_mask, full_rows = self._exclusion_args(model, queries)
+            keep = [i for i, _ in known_ix]
+            sub_mask = full_mask[keep] if full_mask is not None else None
+            sub_rows = full_rows[keep] if full_rows is not None else None
+            n_real = len(user_rows)
+            bucket = batch_bucket(n_real)
+            sp.attrs["bucket"] = bucket
+            if bucket != n_real:
+                user_rows = np.concatenate(
+                    [user_rows, np.zeros(bucket - n_real, dtype=np.int64)]
                 )
-            if sub_rows is not None:
-                sub_rows = np.concatenate([
-                    sub_rows,
-                    np.full(
-                        (bucket - n_real, sub_rows.shape[1]), -1, np.int32
-                    ),
-                ])
-        # padding-waste accounting (ISSUE 3) lives HERE, at the pad site:
-        # this is the only place that knows both the live row count
-        # (vocab-known users, not the micro-batch's group size) and the
-        # bucket the device program actually ran at
-        prof0 = _devprof.snapshot()
-        srt = (
-            model.sharded_runtime()
-            if getattr(self.params, "shard_serving", False)
-            else None
-        )
-        if srt is not None:
-            # fleet sharded path (ISSUE 10): local top-k per shard +
-            # global merge; factor state stays row-sharded in HBM
-            scores, items = srt.recommend(
-                user_rows, k, exclude_mask=sub_mask,
-                exclude_rows=sub_rows,
+                if sub_mask is not None:
+                    sub_mask = np.concatenate([
+                        sub_mask,
+                        np.zeros((bucket - n_real, sub_mask.shape[1]), bool),
+                    ])
+                if sub_rows is not None:
+                    sub_rows = np.concatenate([
+                        sub_rows,
+                        np.full(
+                            (bucket - n_real, sub_rows.shape[1]), -1, np.int32
+                        ),
+                    ])
+        with _spans.span("als.predict.device"):
+            # padding-waste accounting (ISSUE 3) lives HERE, at the pad
+            # site: this is the only place that knows both the live row
+            # count (vocab-known users, not the micro-batch's group size)
+            # and the bucket the device program actually ran at
+            prof0 = _devprof.snapshot()
+            srt = (
+                model.sharded_runtime()
+                if getattr(self.params, "shard_serving", False)
+                else None
             )
-        else:
-            # staged serving state (ISSUE 11/14): fused one-pass kernel
-            # where the lowering runs, int8/bf16 when the params opt
-            # in, exclusion as a row list or packed bit words — never
-            # an f32 mask — and resident factor state either way
-            scores, items = als.recommend_serving(
-                model.serving_state(), user_rows, k,
-                exclude_mask=sub_mask, exclude_rows=sub_rows,
+            if srt is not None:
+                # fleet sharded path (ISSUE 10): local top-k per shard +
+                # global merge; factor state stays row-sharded in HBM
+                scores, items = srt.recommend(
+                    user_rows, k, exclude_mask=sub_mask,
+                    exclude_rows=sub_rows,
+                )
+            else:
+                # staged serving state (ISSUE 11/14): fused one-pass
+                # kernel where the lowering runs, int8/bf16 when the
+                # params opt in, exclusion as a row list or packed bit
+                # words — never an f32 mask — and resident factor state
+                # either way
+                scores, items = als.recommend_serving(
+                    model.serving_state(), user_rows, k,
+                    exclude_mask=sub_mask, exclude_rows=sub_rows,
+                )
+            _devprof.record_batch_padding(
+                n_real, bucket,
+                flops=_devprof.snapshot().flops - prof0.flops,
             )
-        _devprof.record_batch_padding(
-            n_real, bucket, flops=_devprof.snapshot().flops - prof0.flops
-        )
-        scores, items = scores[:n_real], items[:n_real]
-        inv = model.factors.item_vocab.inverse()
-        from predictionio_tpu.ops.topk import NEG_INF
-
-        for row, (qi, _u) in enumerate(known_ix):
-            n = min(queries[qi].num, k)
-            item_scores = [
-                ItemScore(item=inv(int(ix)), score=float(s))
-                for s, ix in zip(scores[row][:n], items[row][:n])
-                if s > NEG_INF / 2
-            ]
-            results[qi] = PredictedResult(item_scores=item_scores)
+            scores = np.asarray(scores)[:n_real]
+            items = np.asarray(items)[:n_real]
+        with _spans.span("als.predict.decode"):
+            with _spans.span("als.predict.vocab_inverse"):
+                inv = model.factors.item_vocab.inverse()
+            for row, (qi, _u) in enumerate(known_ix):
+                n = min(queries[qi].num, k)
+                item_scores = [
+                    ItemScore(item=inv(int(ix)), score=float(s))
+                    for s, ix in zip(scores[row][:n], items[row][:n])
+                    if s > NEG_INF / 2
+                ]
+                results[qi] = PredictedResult(item_scores=item_scores)
+            # freeing the copy is part of what it costs (~40 ms at 5.7 M
+            # ids, PERF.md PR 25): inside the span, not after it
+            del inv
         return results
 
     def predict(self, model: ALSModel, query: Query) -> PredictedResult:

@@ -42,6 +42,8 @@ import numpy as np
 
 from predictionio_tpu.data.store.bimap import BiMap
 from predictionio_tpu.obs import devprof as _devprof
+from predictionio_tpu.obs import spans as _spans
+from predictionio_tpu.obs.jaxmon import compile_snapshot
 from predictionio_tpu.utils.env import env_int, env_str
 from predictionio_tpu.ops.segment import (
     batched_cg,
@@ -688,6 +690,24 @@ _train_jit_dense_sharded = _devprof.instrument(
 )
 
 
+def _run_program(program, *args, **kwargs):
+    """Run a whole-train program under the `als.train.program` span:
+    from dispatch until the factors are ready on the device."""
+    compiles = compile_snapshot()[0]
+    with _spans.span(
+        "als.train.program", iterations=kwargs["iterations"]
+    ) as sp:
+        out = jax.block_until_ready(program(*args, **kwargs))
+        sp.attrs["jit_compiles"] = compile_snapshot()[0] - compiles
+    return out
+
+
+def _copy_back(uf, itf, n_users: int, n_items: int):
+    """Both factor tables, device -> host, cut to the real rows."""
+    with _spans.span("als.train.copy_back"):
+        return np.asarray(uf)[:n_users], np.asarray(itf)[:n_items]
+
+
 @dataclass
 class StagedDenseTrain:
     """A dense-path train with the rating matrix resident on device.
@@ -710,14 +730,16 @@ class StagedDenseTrain:
                 for k, v in self.static_kwargs.items()
                 if k != "pallas_mode"
             }
-            return _train_jit_dense_sharded(*self.device_args, **kwargs)
+            return _run_program(
+                _train_jit_dense_sharded, *self.device_args, **kwargs
+            )
         kwargs = {
             k: v for k, v in self.static_kwargs.items() if k != "mesh"
         }
-        return _train_jit_dense(*self.device_args, **kwargs)
+        return _run_program(_train_jit_dense, *self.device_args, **kwargs)
 
     def factors(self, uf, itf) -> tuple[np.ndarray, np.ndarray]:
-        return np.asarray(uf)[: self.n_users], np.asarray(itf)[: self.n_items]
+        return _copy_back(uf, itf, self.n_users, self.n_items)
 
 
 def dense_matrix_bytes(
@@ -761,40 +783,41 @@ def dense_eligible(
     Auto mode also requires DENSE_AUTO_MIN_EDGES so small (test-scale)
     trains keep their f32-exact windowed numerics unless PIO_DENSE_ALS=1
     opts in."""
-    env = env_str("PIO_DENSE_ALS").strip()
-    if env == "0":
-        return False
-    if params.rank > GRAM_SOLVER_MAX_RANK:
-        return False
-    if mesh is not None and jax.process_count() > 1:
-        return False
-    if env != "1" and len(rows) < DENSE_AUTO_MIN_EDGES:
-        return False
-    budget = env_int("PIO_DENSE_ALS_BYTES", DENSE_DEFAULT_BYTES)
-    if dense_dtype == "bf16":  # the default: predict what auto picks
-        from predictionio_tpu.ops.dense import int8_scale
+    with _spans.span("als.train.dense_eligible"):
+        env = env_str("PIO_DENSE_ALS").strip()
+        if env == "0":
+            return False
+        if params.rank > GRAM_SOLVER_MAX_RANK:
+            return False
+        if mesh is not None and jax.process_count() > 1:
+            return False
+        if env != "1" and len(rows) < DENSE_AUTO_MIN_EDGES:
+            return False
+        budget = env_int("PIO_DENSE_ALS_BYTES", DENSE_DEFAULT_BYTES)
+        if dense_dtype == "bf16":  # the default: predict what auto picks
+            from predictionio_tpu.ops.dense import int8_scale
 
-        if int8_scale(vals) is not None:
-            dense_dtype = "int8"
-    dp = mp = 1
-    if mesh is not None and getattr(mesh, "devices", None) is not None:
-        from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+            if int8_scale(vals) is not None:
+                dense_dtype = "int8"
+        dp = mp = 1
+        if mesh is not None and getattr(mesh, "devices", None) is not None:
+            from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
-        dp = int(mesh.shape.get(DATA_AXIS, 1))
-        mp = int(mesh.shape.get(MODEL_AXIS, 1))
-    if dense_matrix_bytes(
-        n_users, n_items, dense_dtype, dp=dp, mp=mp
-    ) > budget:
-        return False
-    if not params.implicit_prefs and np.any(vals == 0.0):
-        return False
-    key = rows.astype(np.int64) * np.int64(n_items) + cols.astype(np.int64)
-    if np.unique(key).size != len(key):
-        logging.getLogger(__name__).info(
-            "dense ALS path skipped: duplicate (user, item) pairs"
-        )
-        return False
-    return True
+            dp = int(mesh.shape.get(DATA_AXIS, 1))
+            mp = int(mesh.shape.get(MODEL_AXIS, 1))
+        if dense_matrix_bytes(
+            n_users, n_items, dense_dtype, dp=dp, mp=mp
+        ) > budget:
+            return False
+        if not params.implicit_prefs and np.any(vals == 0.0):
+            return False
+        key = rows.astype(np.int64) * np.int64(n_items) + cols.astype(np.int64)
+        if np.unique(key).size != len(key):
+            logging.getLogger(__name__).info(
+                "dense ALS path skipped: duplicate (user, item) pairs"
+            )
+            return False
+        return True
 
 
 def _dense_pallas_mode():
@@ -818,8 +841,6 @@ def stage_dense(
     are) — half the footprint and HBM stream of bf16, with block-local
     dequantization; otherwise bf16. "f32" is the exactness mode tests
     compare against the windowed path with."""
-    import time as _time
-
     from predictionio_tpu.ops.dense import (
         COL_PAD,
         ROW_BLOCK,
@@ -827,68 +848,62 @@ def stage_dense(
         int8_scale,
     )
 
-    t0 = _time.perf_counter()
-    rows = np.asarray(rows, dtype=np.int32)
-    cols = np.asarray(cols, dtype=np.int32)
-    vals = np.asarray(vals, dtype=np.float32)
-    scale = 1.0
-    if dense_dtype in ("auto", "int8"):
-        s_q = int8_scale(vals)
-        if s_q is not None:
-            dense_dtype, scale = "int8", s_q
-        elif dense_dtype == "int8":
-            raise ValueError(
-                "dense_dtype='int8' but ratings are not exactly int8-"
-                "quantizable; use 'bf16' or 'auto'"
-            )
-        else:
-            dense_dtype = "bf16"
-    dp = mp = 1
-    if mesh is not None and mesh.devices.size > 1:
-        from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+    with _spans.span("als.stage.host_prep") as prep_sp:
+        rows = np.asarray(rows, dtype=np.int32)
+        cols = np.asarray(cols, dtype=np.int32)
+        vals = np.asarray(vals, dtype=np.float32)
+        scale = 1.0
+        if dense_dtype in ("auto", "int8"):
+            s_q = int8_scale(vals)
+            if s_q is not None:
+                dense_dtype, scale = "int8", s_q
+            elif dense_dtype == "int8":
+                raise ValueError(
+                    "dense_dtype='int8' but ratings are not exactly int8-"
+                    "quantizable; use 'bf16' or 'auto'"
+                )
+            else:
+                dense_dtype = "bf16"
+        dp = mp = 1
+        if mesh is not None and mesh.devices.size > 1:
+            from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
-        dp = int(mesh.shape.get(DATA_AXIS, 1))
-        mp = int(mesh.shape.get(MODEL_AXIS, 1))
-    # user rows pad to a slab multiple so every dp device scans whole
-    # row blocks of its own slab; with mp > 1 (ISSUE 10) item columns
-    # pad likewise so every mp device owns whole COL_PAD column blocks
-    n_u_p = -(-n_users // (ROW_BLOCK * dp)) * (ROW_BLOCK * dp)
-    n_i_p = -(-n_items // (COL_PAD * mp)) * (COL_PAD * mp)
-    if user_deg is None:
-        user_deg = np.zeros(n_users, np.float32)
-        np.add.at(user_deg, rows, 1.0)
-    if item_deg is None:
-        item_deg = np.zeros(n_items, np.float32)
-        np.add.at(item_deg, cols, 1.0)
+            dp = int(mesh.shape.get(DATA_AXIS, 1))
+            mp = int(mesh.shape.get(MODEL_AXIS, 1))
+        # user rows pad to a slab multiple so every dp device scans whole
+        # row blocks of its own slab; with mp > 1 (ISSUE 10) item columns
+        # pad likewise so every mp device owns whole COL_PAD column blocks
+        n_u_p = -(-n_users // (ROW_BLOCK * dp)) * (ROW_BLOCK * dp)
+        n_i_p = -(-n_items // (COL_PAD * mp)) * (COL_PAD * mp)
+        if user_deg is None:
+            user_deg = np.zeros(n_users, np.float32)
+            np.add.at(user_deg, rows, 1.0)
+        if item_deg is None:
+            item_deg = np.zeros(n_items, np.float32)
+            np.add.at(item_deg, cols, 1.0)
 
-    def pad_deg(deg, n_padded):
-        out = np.full(n_padded, -1.0, np.float32)  # -1 marks padding
-        out[: len(deg)] = deg
-        return out
+        def pad_deg(deg, n_padded):
+            out = np.full(n_padded, -1.0, np.float32)  # -1 marks padding
+            out[: len(deg)] = deg
+            return out
 
-    uf0 = itf0 = None
-    if init_factors is not None:
-        uf_in = np.asarray(init_factors[0], np.float32)
-        itf_in = np.asarray(init_factors[1], np.float32)
-        if uf_in.shape != (n_users, params.rank) or itf_in.shape != (
-            n_items, params.rank,
-        ):
-            raise ValueError(
-                "init_factors shapes do not match (n_users/n_items, rank)"
-            )
-        uf0 = np.zeros((n_u_p, params.rank), np.float32)
-        uf0[:n_users] = uf_in
-        itf0 = np.zeros((n_i_p, params.rank), np.float32)
-        itf0[:n_items] = itf_in
-    host_prep = _time.perf_counter() - t0
+        uf0 = itf0 = None
+        if init_factors is not None:
+            uf_in = np.asarray(init_factors[0], np.float32)
+            itf_in = np.asarray(init_factors[1], np.float32)
+            if uf_in.shape != (n_users, params.rank) or itf_in.shape != (
+                n_items, params.rank,
+            ):
+                raise ValueError(
+                    "init_factors shapes do not match (n_users/n_items, rank)"
+                )
+            uf0 = np.zeros((n_u_p, params.rank), np.float32)
+            uf0[:n_users] = uf_in
+            itf0 = np.zeros((n_i_p, params.rank), np.float32)
+            itf0[:n_items] = itf_in
 
-    t0 = _time.perf_counter()
-    r = densify(
-        jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals),
-        n_rows_p=n_u_p, n_cols_p=n_i_p, dense_dtype=dense_dtype,
-        scale=scale,
-    )
-    if mesh is not None and mesh.devices.size > 1:
+    sharded = mesh is not None and mesh.devices.size > 1
+    if sharded:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
@@ -905,25 +920,32 @@ def stage_dense(
             itf_sh = NamedSharding(mesh, P(MODEL_AXIS, None))
         else:
             r_sh, ideg_sh, itf_sh = row_sh, rep, rep
-        device_args = (
-            jax.device_put(r, r_sh),
+    else:
+        r_sh = vec_sh = ideg_sh = row_sh = itf_sh = None
+
+    # staging is asynchronous: each span waits for what it started, so
+    # transfer and densify are their own times and not part of the train's
+    with _spans.span("als.stage.transfer") as xfer_sp:
+        coo = (jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals))
+        rest = (
             jax.device_put(pad_deg(user_deg, n_u_p), vec_sh),
             jax.device_put(pad_deg(item_deg, n_i_p), ideg_sh),
             jax.device_put(uf0, row_sh) if uf0 is not None else None,
             jax.device_put(itf0, itf_sh) if itf0 is not None else None,
         )
-    else:
-        device_args = (
-            r,
-            jax.device_put(pad_deg(user_deg, n_u_p)),
-            jax.device_put(pad_deg(item_deg, n_i_p)),
-            jax.device_put(uf0) if uf0 is not None else None,
-            jax.device_put(itf0) if itf0 is not None else None,
+        jax.block_until_ready((coo, rest))
+        xfer_sp.attrs["bytes"] = sum(
+            a.nbytes for a in coo + rest if a is not None
         )
-    # staging is asynchronous: wait for it so transfer_sec is the
-    # staging time and not part of the train's
-    jax.block_until_ready(device_args[0])
-    transfer = _time.perf_counter() - t0
+    with _spans.span("als.stage.densify") as densify_sp:
+        r = densify(
+            *coo, n_rows_p=n_u_p, n_cols_p=n_i_p, dense_dtype=dense_dtype,
+            scale=scale,
+        )
+        if sharded:
+            r = jax.device_put(r, r_sh)
+        jax.block_until_ready(r)
+    device_args = (r,) + rest
     return StagedDenseTrain(
         device_args=device_args,
         static_kwargs=dict(
@@ -947,8 +969,9 @@ def stage_dense(
         ),
         n_users=n_users,
         n_items=n_items,
-        host_prep_sec=host_prep,
-        transfer_sec=transfer,
+        host_prep_sec=prep_sp.duration,
+        # R made resident: the COO transfer and the on-device densify
+        transfer_sec=xfer_sp.duration + densify_sp.duration,
     )
 
 
@@ -1463,13 +1486,14 @@ def train(
     factor matrices are row-sharded over the model axis when it has more
     than one device, else replicated.
     """
-    rows = np.asarray(rows, dtype=np.int32)
-    cols = np.asarray(cols, dtype=np.int32)
-    vals = np.asarray(vals, dtype=np.float32)
-    user_deg = np.zeros(n_users, np.float32)
-    np.add.at(user_deg, rows, 1.0)
-    item_deg = np.zeros(n_items, np.float32)
-    np.add.at(item_deg, cols, 1.0)
+    with _spans.span("als.train.degrees"):
+        rows = np.asarray(rows, dtype=np.int32)
+        cols = np.asarray(cols, dtype=np.int32)
+        vals = np.asarray(vals, dtype=np.float32)
+        user_deg = np.zeros(n_users, np.float32)
+        np.add.at(user_deg, rows, 1.0)
+        item_deg = np.zeros(n_items, np.float32)
+        np.add.at(item_deg, cols, 1.0)
 
     if dense_eligible(rows, cols, vals, n_users, n_items, params, mesh):
         return _train_dense(
@@ -1557,10 +1581,10 @@ def train(
                 jax.device_put(a, rep_sh) if a is not None else None
                 for a in args[8:]
             ]
-        uf, itf = _train_jit(*device_args, mesh=mesh, **kwargs)
+        uf, itf = _run_program(_train_jit, *device_args, mesh=mesh, **kwargs)
     else:
-        uf, itf = _train_jit(*args, **kwargs)
-    uf, itf = np.asarray(uf), np.asarray(itf)
+        uf, itf = _run_program(_train_jit, *args, **kwargs)
+    uf, itf = _copy_back(uf, itf, n_users, n_items)
     return ALSFactors(
         user_factors=uf,
         item_factors=itf,
@@ -1588,10 +1612,12 @@ class StagedWindowedTrain:
 
     def run(self) -> tuple[jax.Array, jax.Array]:
         """One full train; returns window-padded device factor arrays."""
-        return _train_jit_windowed(*self.device_args, **self.static_kwargs)
+        return _run_program(
+            _train_jit_windowed, *self.device_args, **self.static_kwargs
+        )
 
     def factors(self, uf: jax.Array, itf: jax.Array) -> tuple[np.ndarray, np.ndarray]:
-        return np.asarray(uf)[: self.n_users], np.asarray(itf)[: self.n_items]
+        return _copy_back(uf, itf, self.n_users, self.n_items)
 
 
 def stage_windowed(
@@ -1609,106 +1635,106 @@ def stage_windowed(
     each process stages only its contiguous slice of parts — the
     HBPEvents.scala:84-90 partitioned-read role). Degrees/init factors
     are replicated; mp row-sharding is applied inside the jit."""
-    import time as _time
+    with _spans.span("als.stage.host_prep") as prep_sp:
+        # single gate for both staging sharding and mesh pass-through: a
+        # model-parallel-only mesh (dp=1, mp>1) still stages replicated
+        # arrays but must reach the jit so mp row-sharding applies (ADVICE r4)
+        use_mesh = mesh is not None and mesh.devices.size > 1
+        n_parts = 1
+        if use_mesh:
+            from predictionio_tpu.parallel.mesh import DATA_AXIS
 
-    t0 = _time.perf_counter()
-    # single gate for both staging sharding and mesh pass-through: a
-    # model-parallel-only mesh (dp=1, mp>1) still stages replicated
-    # arrays but must reach the jit so mp row-sharding applies (ADVICE r4)
-    use_mesh = mesh is not None and mesh.devices.size > 1
-    n_parts = 1
-    if use_mesh:
-        from predictionio_tpu.parallel.mesh import DATA_AXIS
+            n_parts = int(mesh.shape.get(DATA_AXIS, 1))
+        if user_deg is None:
+            user_deg = np.zeros(n_users, np.float32)
+            np.add.at(user_deg, rows, 1.0)
+        if item_deg is None:
+            item_deg = np.zeros(n_items, np.float32)
+            np.add.at(item_deg, cols, 1.0)
+        by_user = np.argsort(rows, kind="stable")
+        by_item = np.argsort(cols, kind="stable")
+        plan_u = plan_windows(rows[by_user], n_users, n_parts)
+        plan_i = plan_windows(cols[by_item], n_items, n_parts)
 
-        n_parts = int(mesh.shape.get(DATA_AXIS, 1))
-    if user_deg is None:
-        user_deg = np.zeros(n_users, np.float32)
-        np.add.at(user_deg, rows, 1.0)
-    if item_deg is None:
-        item_deg = np.zeros(n_items, np.float32)
-        np.add.at(item_deg, cols, 1.0)
-    by_user = np.argsort(rows, kind="stable")
-    by_item = np.argsort(cols, kind="stable")
-    plan_u = plan_windows(rows[by_user], n_users, n_parts)
-    plan_i = plan_windows(cols[by_item], n_items, n_parts)
+        def pad_deg(deg, n_padded):
+            out = np.full(n_padded, -1.0, np.float32)  # -1 marks window padding
+            out[: len(deg)] = deg
+            return out
 
-    def pad_deg(deg, n_padded):
-        out = np.full(n_padded, -1.0, np.float32)  # -1 marks window padding
-        out[: len(deg)] = deg
-        return out
-
-    uf0 = itf0 = None
-    if init_factors is not None:
-        uf_in = np.asarray(init_factors[0], np.float32)
-        itf_in = np.asarray(init_factors[1], np.float32)
-        if uf_in.shape != (n_users, params.rank) or itf_in.shape != (
-            n_items, params.rank,
-        ):
-            raise ValueError(
-                "init_factors shapes do not match (n_users/n_items, rank)"
-            )
-        uf0 = np.zeros((plan_u.n_rows_padded, params.rank), np.float32)
-        uf0[:n_users] = uf_in
-        itf0 = np.zeros((plan_i.n_rows_padded, params.rank), np.float32)
-        itf0[:n_items] = itf_in
-
-    host_args = (
-        plan_u.take(cols[by_user]),
-        plan_u.take(vals[by_user]),
-        plan_u.chunked_valid(),
-        plan_u.chunked_local(),
-        plan_u.block_window,
-        plan_i.take(rows[by_item]),
-        plan_i.take(vals[by_item]),
-        plan_i.chunked_valid(),
-        plan_i.chunked_local(),
-        plan_i.block_window,
-        pad_deg(user_deg, plan_u.n_rows_padded),
-        pad_deg(item_deg, plan_i.n_rows_padded),
-        uf0, itf0,
-    )
-    host_prep = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
-    if use_mesh:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from predictionio_tpu.parallel.mesh import DATA_AXIS
-
-        n_procs = jax.process_count()
-        p_idx = jax.process_index()
-
-        def put(a):
-            if a is None:
-                return None
-            # chunk arrays (P, L, CB, B_E) and block_window (P*L*CB,)
-            # shard their leading axis over dp; everything else
-            # (degrees, init factors) is replicated. With dp == 1
-            # (mp-only mesh) NOTHING is dp-sharded — the multi-process
-            # slice below would otherwise compute shape[0] // n_procs
-            # = 0 and hand GSPMD an empty local buffer
-            sharded = n_parts > 1 and (
-                a.ndim == 4 or a.dtype == np.int32 and a.ndim == 1
-            )
-            spec = (
-                P(DATA_AXIS, *([None] * (a.ndim - 1))) if sharded else P()
-            )
-            sh = NamedSharding(mesh, spec)
-            if n_procs > 1:
-                local = a
-                if sharded:
-                    per = a.shape[0] // n_procs
-                    local = a[p_idx * per : (p_idx + 1) * per]
-                return jax.make_array_from_process_local_data(
-                    sh, local, a.shape
+        uf0 = itf0 = None
+        if init_factors is not None:
+            uf_in = np.asarray(init_factors[0], np.float32)
+            itf_in = np.asarray(init_factors[1], np.float32)
+            if uf_in.shape != (n_users, params.rank) or itf_in.shape != (
+                n_items, params.rank,
+            ):
+                raise ValueError(
+                    "init_factors shapes do not match (n_users/n_items, rank)"
                 )
-            return jax.device_put(a, sh)
+            uf0 = np.zeros((plan_u.n_rows_padded, params.rank), np.float32)
+            uf0[:n_users] = uf_in
+            itf0 = np.zeros((plan_i.n_rows_padded, params.rank), np.float32)
+            itf0[:n_items] = itf_in
 
-        device_args = tuple(put(a) for a in host_args)
-    else:
-        device_args = tuple(
-            jax.device_put(a) if a is not None else None for a in host_args
+        host_args = (
+            plan_u.take(cols[by_user]),
+            plan_u.take(vals[by_user]),
+            plan_u.chunked_valid(),
+            plan_u.chunked_local(),
+            plan_u.block_window,
+            plan_i.take(rows[by_item]),
+            plan_i.take(vals[by_item]),
+            plan_i.chunked_valid(),
+            plan_i.chunked_local(),
+            plan_i.block_window,
+            pad_deg(user_deg, plan_u.n_rows_padded),
+            pad_deg(item_deg, plan_i.n_rows_padded),
+            uf0, itf0,
         )
-    transfer = _time.perf_counter() - t0
+    with _spans.span("als.stage.transfer") as xfer_sp:
+        if use_mesh:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            from predictionio_tpu.parallel.mesh import DATA_AXIS
+
+            n_procs = jax.process_count()
+            p_idx = jax.process_index()
+
+            def put(a):
+                if a is None:
+                    return None
+                # chunk arrays (P, L, CB, B_E) and block_window (P*L*CB,)
+                # shard their leading axis over dp; everything else
+                # (degrees, init factors) is replicated. With dp == 1
+                # (mp-only mesh) NOTHING is dp-sharded — the multi-process
+                # slice below would otherwise compute shape[0] // n_procs
+                # = 0 and hand GSPMD an empty local buffer
+                sharded = n_parts > 1 and (
+                    a.ndim == 4 or a.dtype == np.int32 and a.ndim == 1
+                )
+                spec = (
+                    P(DATA_AXIS, *([None] * (a.ndim - 1))) if sharded else P()
+                )
+                sh = NamedSharding(mesh, spec)
+                if n_procs > 1:
+                    local = a
+                    if sharded:
+                        per = a.shape[0] // n_procs
+                        local = a[p_idx * per : (p_idx + 1) * per]
+                    return jax.make_array_from_process_local_data(
+                        sh, local, a.shape
+                    )
+                return jax.device_put(a, sh)
+
+            device_args = tuple(put(a) for a in host_args)
+        else:
+            device_args = tuple(
+                jax.device_put(a) if a is not None else None for a in host_args
+            )
+        jax.block_until_ready(device_args)
+        xfer_sp.attrs["bytes"] = sum(
+            a.nbytes for a in host_args if a is not None
+        )
     from predictionio_tpu.ops.windowed import resolve_pallas_mode
 
     return StagedWindowedTrain(
@@ -1729,8 +1755,8 @@ def stage_windowed(
         ),
         n_users=n_users,
         n_items=n_items,
-        host_prep_sec=host_prep,
-        transfer_sec=transfer,
+        host_prep_sec=prep_sp.duration,
+        transfer_sec=xfer_sp.duration,
     )
 
 

@@ -32,6 +32,24 @@ attributes on the recorder for tests/benchmarks):
   PIO_TRACE_SLOW_MS  always-keep latency threshold (default 250)
   PIO_TRACE_SAMPLE   keep probability for the rest (default 0.1)
 
+Clocks (ISSUE 25): a span carries its epoch `start` (the fleet collector
+stitches processes on it) AND `start_mono`, `time.monotonic()` — the
+clock its duration, `stats()` windows and the chip benchmark's windows
+are on. When `jax` is already loaded, `span()` also runs its body inside
+`jax.profiler.TraceAnnotation(name)`: with the profiler stopped that is
+one atomic check, with a profiler trace running every program span is an
+event on the trace's `/host:CPU` plane, on the device events' clock, so
+a device-idle gap can be put down to the span that covered it. This
+module never imports jax itself — data-plane processes stay free of it.
+
+- `stats(since_mono, until_mono)` answers "where did the last minute
+  go, by layer" from the spans themselves: per span name the count, the
+  total and the SELF seconds (duration minus what its child spans
+  cover) of the spans that ended in the window, from one-second buckets
+  kept for `STATS_WINDOW_S`.
+- `collect()` gathers the same per-name seconds for one job (a train):
+  every span recorded in the calling context while the block runs.
+
 Thread-safety: one lock guards the recorder's maps; span context lives
 in ContextVars, so keep-alive handler threads and the micro-batch
 dispatcher cannot leak spans across requests."""
@@ -39,11 +57,12 @@ dispatcher cannot leak spans across requests."""
 from __future__ import annotations
 
 import contextvars
+import math
 import os
 import random
+import sys
 import threading
 import time
-import uuid
 from collections import OrderedDict, deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -58,8 +77,16 @@ _current_span_id: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar
 )
 
 
+# span ids have to be unique, not secret: a generator of this module's
+# own, not uuid4 — its urandom syscall hands the interpreter lock over,
+# and under a thread that holds the lock for long (the serving path's
+# vocabulary copy) every span would be a chance to wait for it again
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)
+
+
 def new_span_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return f"{_ids.getrandbits(64):016x}"
 
 
 def current_span_id() -> Optional[str]:
@@ -86,6 +113,10 @@ class Span:
     duration: float = 0.0  # seconds
     attrs: dict[str, Any] = field(default_factory=dict)
     error: bool = False
+    # time.monotonic() at the start: this process's clock only, so it
+    # stays out of to_dict(). 0.0 on a span built after the fact from
+    # an epoch start; record() derives it then.
+    start_mono: float = 0.0
 
     def to_dict(self) -> dict[str, Any]:
         return {
@@ -102,6 +133,83 @@ class Span:
 
 def _env_float(name: str, default: float) -> float:
     return _env.env_float(name, default)
+
+
+#: how far back `SpanRecorder.stats()` can look, in one-second buckets
+STATS_WINDOW_S = 900
+
+_TraceAnnotation: Any = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _trace_annotation(name: str) -> Any:
+    """`jax.profiler.TraceAnnotation(name)` if this process has loaded
+    jax, else None. Never imports it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _TraceAnnotation = getattr(profiler, "TraceAnnotation", None)
+        if _TraceAnnotation is None:
+            return None
+    return _TraceAnnotation(name)
+
+
+def _covered(intervals: list[tuple[float, float]], lo: float, hi: float) -> float:
+    """Length of the union of `intervals` inside [lo, hi]."""
+    total, reach = 0.0, lo
+    for start, end in sorted(intervals):
+        start, end = max(start, reach), min(end, hi)
+        if end > start:
+            total += end - start
+            reach = end
+    return total
+
+
+@contextmanager
+def detached() -> Iterator[None]:
+    """Leave the ambient trace for the block: a span opened inside roots
+    a trace of its own. For batch-level work that outlives the request
+    whose context it borrowed — a span that ended in a trace whose root
+    has already been sampled on would never be finalized."""
+    trace_token = _tracing.set_trace_id(None)
+    span_token = _current_span_id.set(None)
+    try:
+        yield
+    finally:
+        _current_span_id.reset(span_token)
+        _tracing.reset_trace_id(trace_token)
+
+
+class SpanTotals:
+    """What `collect()` gathers: seconds per span name, and the seconds
+    of the enclosing spans that no child span covers."""
+
+    def __init__(self) -> None:
+        self.seconds: dict[str, float] = {}
+        self.unattributed = 0.0
+
+    def add(self, sp: Span, self_s: float, leaf: bool) -> None:
+        self.seconds[sp.name] = self.seconds.get(sp.name, 0.0) + sp.duration
+        if not leaf:
+            self.unattributed += self_s
+
+
+_collector: contextvars.ContextVar[Optional[SpanTotals]] = contextvars.ContextVar(
+    "pio_span_collector", default=None
+)
+
+
+@contextmanager
+def collect() -> Iterator[SpanTotals]:
+    """Gather every span recorded in this context while the block runs
+    (spans of other threads are not this job's). Once the block's own
+    root span is in, `unattributed` is that root minus what its leaf
+    spans cover."""
+    totals = SpanTotals()
+    token = _collector.set(totals)
+    try:
+        yield totals
+    finally:
+        _collector.reset(token)
 
 
 class SpanRecorder:
@@ -156,6 +264,11 @@ class SpanRecorder:
         # assembled hedged trace must show. Bounded ring; the
         # collector dedups on span_id across overlapping polls.
         self._recent: deque[Span] = deque(maxlen=4096)  # guarded-by: _lock
+        # stats(): span name -> {monotonic second -> [count, total_s,
+        # self_s]} of the spans that ended in that second, and the
+        # intervals of recorded spans whose parent is still to come
+        self._stats: dict[str, dict[int, list]] = {}  # guarded-by: _lock
+        self._child_intervals: "OrderedDict[str, list]" = OrderedDict()  # guarded-by: _lock
 
     # -- recording ---------------------------------------------------------
     @contextmanager
@@ -188,16 +301,21 @@ class SpanRecorder:
             ),
             start=time.time(),
             attrs=dict(attrs),
+            start_mono=time.monotonic(),
         )
         span_token = _current_span_id.set(sp.span_id)
-        t0 = time.perf_counter()
+        annotation = _trace_annotation(name)
+        if annotation is not None:
+            annotation.__enter__()
         try:
             yield sp
         except BaseException:
             sp.error = True
             raise
         finally:
-            sp.duration = time.perf_counter() - t0
+            sp.duration = time.monotonic() - sp.start_mono
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             _current_span_id.reset(span_token)
             if trace_token is not None:
                 _tracing.reset_trace_id(trace_token)
@@ -213,7 +331,10 @@ class SpanRecorder:
                 bridge(sp)
             except Exception:
                 pass  # a metrics hiccup must never break the request
+        if sp.start_mono == 0.0:
+            sp.start_mono = time.monotonic() - (time.time() - sp.start)
         with self._lock:
+            self._account(sp)
             self._recent.append(sp)
             kept = self._traces.get(sp.trace_id)
             if kept is not None:
@@ -265,6 +386,34 @@ class SpanRecorder:
                     cap["trace_ids"].append(sp.trace_id)
             while len(self._traces) > self.max_traces:
                 self._traces.popitem(last=False)
+
+    def _account(self, sp: Span) -> None:  # lint: holds=_lock
+        """Windowed statistics, before tail sampling: every span counts.
+        Self time is the duration minus the union of the child spans
+        recorded so far (children end, or are recorded after the fact,
+        before their parent is; siblings may overlap, hence union)."""
+        end = sp.start_mono + sp.duration
+        kids = self._child_intervals.pop(sp.span_id, None)
+        self_s = sp.duration
+        if kids:
+            self_s -= _covered(kids, sp.start_mono, end)
+        if sp.parent_span_id is not None:
+            self._child_intervals.setdefault(sp.parent_span_id, []).append(
+                (sp.start_mono, end)
+            )
+            # a parent in another process never comes to collect
+            while len(self._child_intervals) > 4096:
+                self._child_intervals.popitem(last=False)
+        seconds = self._stats.setdefault(sp.name, {})
+        bucket = seconds.setdefault(int(end), [0, 0.0, 0.0])
+        bucket[0] += 1
+        bucket[1] += sp.duration
+        bucket[2] += self_s
+        while len(seconds) > STATS_WINDOW_S:
+            del seconds[next(iter(seconds))]
+        totals = _collector.get()
+        if totals is not None:
+            totals.add(sp, self_s, leaf=not kids)
 
     def _keep_reason(self, spans: list[Span]) -> Optional[str]:
         if any(s.error for s in spans):
@@ -374,6 +523,29 @@ class SpanRecorder:
         }
 
     # -- reading -----------------------------------------------------------
+    def stats(
+        self, since_mono: float, until_mono: Optional[float] = None
+    ) -> dict[str, dict[str, float]]:
+        """{name: {"count", "total_s", "self_s"}} over the spans that
+        ENDED between the two `time.monotonic()` instants (until: now),
+        to the whole second — a bucket that overlaps the window counts.
+        Reaches back STATS_WINDOW_S seconds of recorded spans."""
+        if until_mono is None:
+            until_mono = time.monotonic()
+        lo, hi = math.floor(since_mono), math.ceil(until_mono)
+        out: dict[str, dict[str, float]] = {}
+        with self._lock:
+            for name, seconds in self._stats.items():
+                count, total, self_s = 0, 0.0, 0.0
+                for sec, (n, t, s) in seconds.items():
+                    if lo <= sec < hi:
+                        count, total, self_s = count + n, total + t, self_s + s
+                if count:
+                    out[name] = {
+                        "count": count, "total_s": total, "self_s": self_s,
+                    }
+        return out
+
     def recent(self, since: float = 0.0) -> list[Span]:
         """Raw completed spans (pre-sampling) whose END falls at or
         after `since` — the `/debug/traces?spans=1` dump the fleet
@@ -486,6 +658,8 @@ class SpanRecorder:
             self._captures.clear()
             self._forced.clear()
             self._recent.clear()
+            self._stats.clear()
+            self._child_intervals.clear()
 
 
 _default_recorder: Optional[SpanRecorder] = None

@@ -12,7 +12,7 @@ Commands:
   accesskey new|list|delete
   train / deploy / eval / eventserver
   status / export / import
-  metrics / trace list|show|export / profile list|show|capture
+  metrics / trace list|show|export|stats / profile list|show|capture
   faults list|set|clear
   jobs submit|list|show|logs|worker / models list|show|promote|rollback|gc
   rollout start|status|abort
@@ -24,6 +24,7 @@ import argparse
 import json as _json
 import os
 import sys
+import time
 from typing import Optional
 
 from predictionio_tpu.data.storage.base import App, Channel
@@ -799,8 +800,10 @@ def _fleet_collector():
 
 
 def cmd_trace(args) -> int:
-    """`pio trace list|show|export` — the retained (tail-sampled) traces
-    of a running server (--url http://host:port) or of this process.
+    """`pio trace list|show|export|stats` — the retained (tail-sampled)
+    traces of a running server (--url http://host:port) or of this
+    process; `stats` is where the last --window seconds went by span
+    name, over every span (before sampling).
     With --fleet, the ASSEMBLED cross-process traces of the fleet
     collector (gateway root + per-attempt children + replica-side
     server spans stitched by request id) instead of one process's
@@ -812,6 +815,28 @@ def cmd_trace(args) -> int:
     url = getattr(args, "url", None)
     fleet = getattr(args, "fleet", False)
     action = args.trace_action
+    if action == "stats":
+        if url:
+            table = _fetch_debug_traces(
+                url, f"stats=1&window={args.window}"
+            )["spans"]
+        else:
+            table = get_default_recorder().stats(
+                time.monotonic() - args.window
+            )
+        print(f"[INFO] spans ended in the last {args.window:g} s "
+              f"({len(table)} name(s)), by self time:")
+        print(f"[INFO]   {'span':<28} {'count':>8} {'total_s':>10} "
+              f"{'self_s':>10} {'mean_ms':>9}")
+        for name, row in sorted(
+            table.items(), key=lambda kv: -kv[1]["self_s"]
+        ):
+            print(
+                f"[INFO]   {name:<28} {row['count']:>8} "
+                f"{row['total_s']:>10.3f} {row['self_s']:>10.3f} "
+                f"{1e3 * row['total_s'] / row['count']:>9.2f}"
+            )
+        return 0
     if action == "list":
         if url:
             params = f"limit={args.limit}"
@@ -2204,6 +2229,15 @@ def build_parser() -> argparse.ArgumentParser:
                     help="export assembled fleet traces")
     te.add_argument("--output", required=True)
     te.set_defaults(func=cmd_trace)
+    tt = tsub.add_parser(
+        "stats",
+        help="where the last seconds went, by span name: count, total "
+             "and self seconds of every span (no trace export needed)",
+    )
+    tt.add_argument("--url", help="server base URL")
+    tt.add_argument("--window", type=float, default=60.0,
+                    help="seconds to look back (default 60, at most 900)")
+    tt.set_defaults(func=cmd_trace)
 
     # profile (ISSUE 3: device-profile accounting from the console)
     s = sub.add_parser(

@@ -222,7 +222,9 @@ class JsonHandler(BaseHTTPRequestHandler):
 
     def _serve_debug_traces(self) -> None:
         """GET /debug/traces — recent retained traces (tail-sampled);
-        `?trace_id=` for one trace's full span list, plus
+        `?stats=1[&window=<seconds>]` for where the last seconds went by
+        span name (count, total and self seconds of EVERY span, before
+        sampling); `?trace_id=` for one trace's full span list, plus
         `&format=perfetto` for Chrome trace-event JSON of it;
         `?min_duration_ms=` / `?error=1` filter the summary listing so
         operators pull only slow/errored traces without exporting the
@@ -242,6 +244,17 @@ class JsonHandler(BaseHTTPRequestHandler):
             self._respond(200, {
                 "now": time.time(),
                 "spans": [s.to_dict() for s in recorder.recent(since)],
+            })
+            return
+        if qs.get("stats") in ("1", "true", "yes"):
+            try:
+                window = float(qs.get("window", 60) or 60)
+            except ValueError:
+                window = 60.0
+            window = min(max(window, 1.0), float(_obs_spans.STATS_WINDOW_S))
+            self._respond(200, {
+                "window_s": window,
+                "spans": recorder.stats(time.monotonic() - window),
             })
             return
         if qs.get("fleet") in ("1", "true", "yes"):

@@ -33,6 +33,13 @@ from predictionio_tpu.obs import spans as _spans
 
 log = logging.getLogger(__name__)
 
+#: span name -> key in EngineInstance.env["stage_timings"]; every other
+#: span of a train job stands there under its own name
+_STAGE_KEYS = {
+    "train.read": "read", "train.prepare": "prepare",
+    "train.train": "train", "train.persist": "persist", "train": "job",
+}
+
 
 def _stage_json(stage: tuple[str, Any]) -> str:
     """Persist a (stage-name, params) pair — the name matters: deploy must
@@ -186,11 +193,17 @@ def run_train(
 
     def _record_timings(where_it_ran: bool = True) -> None:
         # the EngineInstance blob stays as a point-in-time snapshot of
-        # what the unified registry recorded live (ISSUE 1)
+        # what the unified registry recorded live (ISSUE 1): every span
+        # that completed under this job, name -> seconds (ISSUE 25); the
+        # four DASE stages and the job's root span keep the short keys
+        # `pio status` and deploy/registry.py read
+        timings = {
+            _STAGE_KEYS.get(name, name): round(seconds, 4)
+            for name, seconds in collected.seconds.items()
+        }
+        timings["unattributed"] = round(collected.unattributed, 4)
         instance.env = dict(instance.env or {})
-        instance.env["stage_timings"] = json.dumps(
-            {k: round(v, 4) for k, v in ctx.stage_timings.items()}
-        )
+        instance.env["stage_timings"] = json.dumps(timings)
         if where_it_ran:
             # ... and of WHERE it ran: the device as jax reports it and
             # the executables this process has run, with their static
@@ -203,74 +216,73 @@ def run_train(
             ("status",),  # label-bound: literal status set
         ).inc(status=status)
 
-    try:
-        # root span of the whole train (ISSUE 2): opens a trace if the
-        # caller didn't (CLI `pio train`), parents every DASE stage span
-        # engine.train emits, and — because an aborted train marks it
-        # errored — guarantees tail sampling retains failed runs
-        with _spans.span(
-            "train", server="train", instance_id=instance_id,
-            engine=instance.engine_id, variant=instance.engine_variant,
-        ):
-            instance.status = "TRAINING"
-            instances.update(instance)
-            with profile_cm:
-                try:
-                    models = engine.train(ctx, engine_params)
-                except (
-                    StopAfterReadInterruption, StopAfterPrepareInterruption
-                ) as e:
-                    # intentional debug stop-points, not failures
-                    # (reference CoreWorkflow.scala:88-93 logs
-                    # "Training interrupted")
-                    log.info("training interrupted by %s", type(e).__name__)
-                    instance.status = "INTERRUPTED"
-                    instance.end_time = _dt.datetime.now(_dt.timezone.utc)
-                    _record_timings()
-                    _count_run("INTERRUPTED")
-                    instances.update(instance)
-                    return instance
-                if wp.save_model:
-                    from predictionio_tpu.controller.engine import (
-                        _stage_span,
-                    )
+    with _spans.collect() as collected:
+        try:
+            # root span of the whole train (ISSUE 2): opens a trace if the
+            # caller didn't (CLI `pio train`), parents every DASE stage span
+            # engine.train emits, and — because an aborted train marks it
+            # errored — guarantees tail sampling retains failed runs
+            with _spans.span(
+                "train", server="train", instance_id=instance_id,
+                engine=instance.engine_id, variant=instance.engine_variant,
+            ):
+                instance.status = "TRAINING"
+                instances.update(instance)
+                with profile_cm:
+                    try:
+                        models = engine.train(ctx, engine_params)
+                    except (
+                        StopAfterReadInterruption, StopAfterPrepareInterruption
+                    ) as e:
+                        # intentional debug stop-points, not failures
+                        # (reference CoreWorkflow.scala:88-93 logs
+                        # "Training interrupted")
+                        log.info("training interrupted by %s", type(e).__name__)
+                        instance.status = "INTERRUPTED"
+                        instance.end_time = _dt.datetime.now(_dt.timezone.utc)
+                        _record_timings()
+                        _count_run("INTERRUPTED")
+                        instances.update(instance)
+                        return instance
+                    if wp.save_model:
+                        from predictionio_tpu.controller.engine import (
+                            _stage_span,
+                        )
 
-                    with _stage_span("train.persist") as persist_sp:
-                        serializable = engine.make_serializable_models(
-                            ctx, models, engine_params, instance_id
-                        )
-                        storage.get_model_data_models().insert(
-                            Model(
-                                id=instance_id,
-                                models=serialize_models(serializable),
+                        # the histogram observation comes from the span via
+                        # the bridge in controller/engine.py
+                        with _stage_span("train.persist"):
+                            serializable = engine.make_serializable_models(
+                                ctx, models, engine_params, instance_id
                             )
-                        )
-                    # the histogram observation comes from the span via
-                    # the bridge in controller/engine.py; the row snapshot
-                    # keeps reading ctx.stage_timings
-                    ctx.stage_timings["persist"] = persist_sp.duration
-        instance.status = "COMPLETED"
-        instance.end_time = _dt.datetime.now(_dt.timezone.utc)
-        _record_timings()
-        _count_run("COMPLETED")
-        instances.update(instance)
-        _register_manifest(storage, instance, variant)
-        log.info(
-            "training completed: instance %s (stages: %s)",
-            instance_id,
-            {k: round(v, 3) for k, v in ctx.stage_timings.items()},
-        )
-        return instance
-    except Exception:
-        instance.status = "ABORTED"
-        instance.end_time = _dt.datetime.now(_dt.timezone.utc)
-        # partial timings show WHERE the failed run spent time; the
-        # device is not asked again here — if it is what failed, asking
-        # would raise over the error being reported
-        _record_timings(where_it_ran=False)
-        _count_run("ABORTED")
-        instances.update(instance)
-        raise
+                            with _spans.span("persist.serialize") as sp:
+                                blob = serialize_models(serializable)
+                                sp.attrs["bytes"] = len(blob)
+                            with _spans.span("persist.write"):
+                                storage.get_model_data_models().insert(
+                                    Model(id=instance_id, models=blob)
+                                )
+            instance.status = "COMPLETED"
+            instance.end_time = _dt.datetime.now(_dt.timezone.utc)
+            _record_timings()
+            _count_run("COMPLETED")
+            instances.update(instance)
+            _register_manifest(storage, instance, variant)
+            log.info(
+                "training completed: instance %s (stages: %s)",
+                instance_id, instance.env["stage_timings"],
+            )
+            return instance
+        except Exception:
+            instance.status = "ABORTED"
+            instance.end_time = _dt.datetime.now(_dt.timezone.utc)
+            # partial timings show WHERE the failed run spent time; the
+            # device is not asked again here — if it is what failed, asking
+            # would raise over the error being reported
+            _record_timings(where_it_ran=False)
+            _count_run("ABORTED")
+            instances.update(instance)
+            raise
 
 
 def _register_manifest(

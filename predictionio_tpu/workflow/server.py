@@ -32,6 +32,7 @@ import predictionio_tpu.resilience.faults as _faults
 from predictionio_tpu.controller.params import ParamsError, extract_params
 from predictionio_tpu.resilience.deadline import DeadlineExceeded
 from predictionio_tpu.obs import BATCH_SIZE_BUCKETS, server_registry
+from predictionio_tpu.obs.jaxmon import compile_snapshot
 from predictionio_tpu.core.base import RuntimeContext
 from predictionio_tpu.data.storage.base import EngineInstance, StorageError
 from predictionio_tpu.data.storage.registry import Storage
@@ -539,49 +540,52 @@ class _Handler(JsonHandler):
                 owner.bookkeep_variant(variant, seconds, error)
 
         try:
-            raw = self._raw_body.decode()
-            try:
-                query_json = json.loads(raw or "null")
-            except json.JSONDecodeError as e:
-                raise _HttpError(400, f"invalid query JSON: {e}")
-            # canary routing (ISSUE 5): sticky hash-of-request fraction
-            # goes to the candidate runtime; snapshot semantics match
-            # /reload — the query is extracted and served against ONE
-            # runtime even if a swap lands mid-flight. Tenant queries
-            # (ISSUE 6) route through the model cache instead — a miss
-            # is a transparent model load, and the returned lease keeps
-            # the runtime un-evictable until bookkeeping finishes.
-            if tenant is not None:
-                from predictionio_tpu.tenancy import ModelLoadError
-
+            # the request's phases are spans (ISSUE 25): body -> Query and
+            # routing here, query.wait in dispatcher.submit, result -> JSON
+            with _spans.span("query.decode", server="query"):
+                raw = self._raw_body.decode()
                 try:
-                    rt, variant, lease = mux.route(
-                        tenant, self._raw_body, bucket=bucket
-                    )
-                except ModelLoadError as e:
-                    raise _HttpError(503, str(e))
-            else:
-                rt, variant = owner.pick_runtime(
-                    self._raw_body, bucket=bucket
-                )
-            custom_from = getattr(
-                rt.query_serializer, "query_from_json", None
-            )
-            if custom_from is None and not isinstance(query_json, dict):
-                raise _HttpError(400, "query must be a JSON object")
-            try:
-                if custom_from is not None:
-                    query = custom_from(query_json)
-                elif rt.query_class is not None:
-                    query = extract_params(rt.query_class, query_json)
-                else:
-                    query = query_json
-            except ParamsError as e:
-                raise _HttpError(400, str(e))
-            except ValueError as e:
-                raise _HttpError(400, f"query serializer rejected: {e}")
+                    query_json = json.loads(raw or "null")
+                except json.JSONDecodeError as e:
+                    raise _HttpError(400, f"invalid query JSON: {e}")
+                # canary routing (ISSUE 5): sticky hash-of-request fraction
+                # goes to the candidate runtime; snapshot semantics match
+                # /reload — the query is extracted and served against ONE
+                # runtime even if a swap lands mid-flight. Tenant queries
+                # (ISSUE 6) route through the model cache instead — a miss
+                # is a transparent model load, and the returned lease keeps
+                # the runtime un-evictable until bookkeeping finishes.
+                if tenant is not None:
+                    from predictionio_tpu.tenancy import ModelLoadError
 
-            supplemented = rt.serving.supplement(query)
+                    try:
+                        rt, variant, lease = mux.route(
+                            tenant, self._raw_body, bucket=bucket
+                        )
+                    except ModelLoadError as e:
+                        raise _HttpError(503, str(e))
+                else:
+                    rt, variant = owner.pick_runtime(
+                        self._raw_body, bucket=bucket
+                    )
+                custom_from = getattr(
+                    rt.query_serializer, "query_from_json", None
+                )
+                if custom_from is None and not isinstance(query_json, dict):
+                    raise _HttpError(400, "query must be a JSON object")
+                try:
+                    if custom_from is not None:
+                        query = custom_from(query_json)
+                    elif rt.query_class is not None:
+                        query = extract_params(rt.query_class, query_json)
+                    else:
+                        query = query_json
+                except ParamsError as e:
+                    raise _HttpError(400, str(e))
+                except ValueError as e:
+                    raise _HttpError(400, f"query serializer rejected: {e}")
+
+                supplemented = rt.serving.supplement(query)
             try:
                 if owner.dispatcher is not None:
                     prediction = owner.dispatcher.submit(
@@ -606,37 +610,41 @@ class _Handler(JsonHandler):
                 # algorithms raise ValueError for query-level contract
                 # violations (e.g. category filter without category data)
                 raise _HttpError(400, str(e))
-            custom_to = getattr(rt.query_serializer, "result_to_json", None)
-            result = (
-                custom_to(prediction) if custom_to is not None
-                else _to_jsonable(prediction)
-            )
-            # shadow agreement compares the SERIALIZED result before
-            # output blockers run — blockers may stamp per-request data
-            # (ids, timestamps) that would read as disagreement
-            shadow_reference = result
-
-            for plugin in owner.output_blockers:
-                result = plugin.process(query_json, result, {})
-
-            owner.bookkeep(time.perf_counter() - t0)
-            _book(time.perf_counter() - t0, error=False)
-            variant_booked = True
-            if tenant is None:
-                # server-level shadow mirroring and the feedback loop
-                # are single-tenant surfaces; tenant traffic must not
-                # leak into the server rollout's agreement windows
-                owner.maybe_shadow(
-                    self._raw_body, query_json, shadow_reference,
-                    bucket=bucket,
+            with _spans.span("query.encode", server="query"):
+                custom_to = getattr(rt.query_serializer, "result_to_json", None)
+                result = (
+                    custom_to(prediction) if custom_to is not None
+                    else _to_jsonable(prediction)
                 )
-                owner.feedback_async(query_json, result)
-            for plugin in owner.output_sniffers:
-                try:
-                    plugin.process(query_json, result, {})
-                except Exception:
-                    log.exception("output sniffer failed")
-            self._respond(200, result)
+                # shadow agreement compares the SERIALIZED result before
+                # output blockers run — blockers may stamp per-request data
+                # (ids, timestamps) that would read as disagreement
+                shadow_reference = result
+
+                for plugin in owner.output_blockers:
+                    result = plugin.process(query_json, result, {})
+
+                owner.bookkeep(time.perf_counter() - t0)
+                _book(time.perf_counter() - t0, error=False)
+                variant_booked = True
+                if tenant is None:
+                    # server-level shadow mirroring and the feedback loop
+                    # are single-tenant surfaces; tenant traffic must not
+                    # leak into the server rollout's agreement windows
+                    owner.maybe_shadow(
+                        self._raw_body, query_json, shadow_reference,
+                        bucket=bucket,
+                    )
+                    owner.feedback_async(query_json, result)
+                for plugin in owner.output_sniffers:
+                    try:
+                        plugin.process(query_json, result, {})
+                    except Exception:
+                        log.exception("output sniffer failed")
+                # encoded inside the span; _respond then only writes, and
+                # records server.request once its children are all in
+                payload = json.dumps(result)
+            self._respond(200, payload)
         except _HttpError as e:
             # post-routing 4xx DO feed the verdict windows: a candidate
             # whose stricter query class 400s its whole traffic
@@ -801,13 +809,17 @@ class _BatchDispatcher:
         wait = timeout
         if deadline is not None:
             wait = min(wait, max(0.0, deadline - _t.monotonic()))
-        try:
-            return fut.result(timeout=wait)
-        except _FutTimeout:
-            p.cancelled = True  # drain must not burn device time on this
-            raise DeadlineExceeded(
-                "query abandoned: deadline passed while queued for dispatch"
-            )
+        # opened AFTER tctx was taken: the dispatcher's per-query spans
+        # stay children of the request's own span, beside this one
+        with _spans.span("query.wait", server="query"):
+            try:
+                return fut.result(timeout=wait)
+            except _FutTimeout:
+                p.cancelled = True  # drain must not burn device time on this
+                raise DeadlineExceeded(
+                    "query abandoned: deadline passed while queued for "
+                    "dispatch"
+                )
 
     def stop(self) -> None:
         self._stop.set()
@@ -930,23 +942,37 @@ class _BatchDispatcher:
                         "dispatch.device",
                         scope=f"tenant/{group_tenant}", scoped_only=True,
                     )
-                per_algo = [
-                    dict(algo.batch_predict(
-                        algo.serving_context, model, queries
-                    ))
-                    for algo, model in zip(rt.algorithms, rt.models)
-                ]
+                # the batch's own work is real spans on this thread
+                # (ISSUE 25): a profiler trace shows them, stats() sums
+                # them, and the algorithm's spans nest under them
+                with _spans.span(
+                    "batch.predict", server="query", batch_size=len(group),
+                ) as predict_sp:
+                    compiles = compile_snapshot()[0]
+                    per_algo = [
+                        dict(algo.batch_predict(
+                            algo.serving_context, model, queries
+                        ))
+                        for algo, model in zip(rt.algorithms, rt.models)
+                    ]
+                    predict_sp.attrs["jit_compiles"] = (
+                        compile_snapshot()[0] - compiles
+                    )
                 self.last_batch_sec = time.perf_counter() - t0
                 for i in range(len(group)):
                     _child(i, "batch.device_dispatch", now_wall,
                            self.last_batch_sec, span_id=dev_ids[i])
                 if registry is not None:
-                    # device-time histogram stays per coalesced BATCH
-                    # (the per-query device spans above share its wall
-                    # time; bridging them would inflate the count)
+                    # one observation per coalesced BATCH (the per-query
+                    # device spans above share its wall time; bridging
+                    # them would inflate the count). The name says
+                    # "device"; what it times is all of batch_predict on
+                    # the host's clock — lookups, the device pass, the
+                    # decode. The device pass alone is als.predict.device.
                     registry.histogram(
                         "batch_device_seconds",
-                        "device time per coalesced batch (dispatch to fetch)",
+                        "wall time of batch_predict per coalesced batch: "
+                        "host lookups, device pass and decode",
                     ).observe(self.last_batch_sec)
                 self.owner.bookkeep_predict(self.last_batch_sec, len(group))
                 # per-tenant device-seconds accounting (ISSUE 6): each
@@ -964,23 +990,28 @@ class _BatchDispatcher:
                             counts[p.tenant] = counts.get(p.tenant, 0) + 1
                     for tid, n in counts.items():
                         charge(tid, per_query * n)
-                for i, p in enumerate(group):
-                    t_s = time.perf_counter()
-                    try:
-                        result = rt.serving.serve(
-                            p.query, [pa[i] for pa in per_algo]
-                        )
-                    except Exception as e:  # serve failure is per-query
+                # a trace of its own: the first reply below lets its
+                # request finish, and sample, while this loop still runs
+                with _spans.detached(), _spans.span(
+                    "batch.serve", server="query", batch_size=len(group),
+                ):
+                    for i, p in enumerate(group):
+                        t_s = time.perf_counter()
+                        try:
+                            result = rt.serving.serve(
+                                p.query, [pa[i] for pa in per_algo]
+                            )
+                        except Exception as e:  # serve failure is per-query
+                            dur = time.perf_counter() - t_s
+                            _child(i, "batch.result_transfer",
+                                   time.time() - dur, dur, error=True)
+                            p.fut.set_exception(e)
+                            continue
                         dur = time.perf_counter() - t_s
+                        # result-transfer/serve: per-query fetch + combinator
                         _child(i, "batch.result_transfer",
-                               time.time() - dur, dur, error=True)
-                        p.fut.set_exception(e)
-                        continue
-                    dur = time.perf_counter() - t_s
-                    # result-transfer/serve: per-query fetch + combinator
-                    _child(i, "batch.result_transfer",
-                           time.time() - dur, dur)
-                    p.fut.set_result(result)
+                               time.time() - dur, dur)
+                        p.fut.set_result(result)
             except Exception:
                 # one bad query must not poison the batch: retry
                 # individually so each waiter gets its own result or its

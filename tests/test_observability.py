@@ -64,11 +64,43 @@ def test_stage_timings_recorded_on_instance(storage):
     inst = run_train(storage, VARIANT)
     assert inst.status == "COMPLETED"
     timings = json.loads(inst.env["stage_timings"])
-    assert set(timings) == {"read", "prepare", "train", "persist"}
+    # the four stages keep their keys; every other span of the job
+    # stands beside them under its own name (ISSUE 25)
+    assert {"read", "prepare", "train", "persist"} <= set(timings)
     assert all(v >= 0 for v in timings.values())
     # the recorded row round-trips through storage too
     stored = storage.get_meta_data_engine_instances().get(inst.id)
     assert json.loads(stored.env["stage_timings"]) == timings
+
+
+def test_stage_timings_come_from_every_span_of_the_job(storage):
+    """ISSUE 25: the four stage keys keep the values of their stage
+    spans; every other span of the job stands beside them by name, with
+    the job's root span and what no leaf span covers."""
+    from predictionio_tpu.obs.spans import get_default_recorder
+
+    recorder = get_default_recorder()
+    inst = run_train(storage, VARIANT)
+    timings = json.loads(inst.env["stage_timings"])
+    [trace_id] = [sp.trace_id for sp in recorder.recent()
+                  if sp.attrs.get("instance_id") == inst.id]
+    mine = {}
+    for sp in recorder.recent():
+        if sp.trace_id == trace_id:
+            mine[sp.name] = mine.get(sp.name, 0.0) + sp.duration
+    for stage in ("read", "prepare", "train", "persist"):
+        assert timings[stage] == round(mine[f"train.{stage}"], 4)
+    assert timings["job"] == round(mine["train"], 4)
+    for name in ("train.algorithm", "als.train.degrees", "als.stage.host_prep",
+                 "als.stage.transfer", "als.train.program",
+                 "als.train.copy_back", "persist.serialize", "persist.write"):
+        assert timings[name] == pytest.approx(mine[name], abs=1e-4), name
+    assert 0.0 <= timings["unattributed"] < timings["job"]
+    leaves = sum(v for k, v in timings.items()
+                 if k.startswith(("als.", "persist."))
+                 or k in ("read", "prepare"))
+    assert timings["job"] == pytest.approx(
+        leaves + timings["unattributed"], abs=2e-3)
 
 
 def test_profile_dir_produces_trace(storage, tmp_path):

@@ -183,6 +183,50 @@ def test_default_config_batches(served):
     assert srv.config.micro_batch
 
 
+def test_debug_traces_stats_answers_for_the_asked_window(served, capsys):
+    """ISSUE 25: where the last seconds went, by span name, from the
+    spans themselves — every query, not only the sampled traces."""
+    _, _, port = served
+    for u in range(4):
+        status, _ = post(port, "/queries.json", {"user": f"u{u}", "num": 3})
+        assert status == 200
+    # the root span records as the reply goes out: poll for the fourth
+    deadline = time.time() + 10
+    while time.time() < deadline:
+        _, text = get(port, "/debug/traces?stats=1&window=30")
+        table = json.loads(text)
+        if table["spans"].get("server.request", {}).get("count", 0) >= 4:
+            break
+        time.sleep(0.05)
+    assert table["window_s"] == 30.0
+    spans = table["spans"]
+    for name in ("server.request", "query.decode", "query.wait",
+                 "query.encode", "batch.queue_wait", "batch.predict",
+                 "batch.serve", "als.predict.prepare", "als.predict.device",
+                 "als.predict.decode", "als.predict.vocab_inverse"):
+        assert spans[name]["count"] >= 1, name
+        assert 0.0 <= spans[name]["self_s"] <= spans[name]["total_s"] + 1e-9
+    assert spans["query.wait"]["count"] >= 4
+    # a request's self time leaves out what its child spans cover
+    req = spans["server.request"]
+    assert req["self_s"] < req["total_s"]
+    # the decode span's children cover part of it, never more than it
+    decode = spans["als.predict.decode"]
+    assert decode["self_s"] <= decode["total_s"]
+    # a window that holds nothing yet answers with an empty table
+    _, text = get(port, "/debug/traces?stats=1&window=bogus")
+    assert json.loads(text)["window_s"] == 60.0
+
+    # `pio trace stats` prints the same table
+    from predictionio_tpu.tools import console
+
+    assert console.main(
+        ["trace", "stats", "--url", f"http://127.0.0.1:{port}",
+         "--window", "30"]) == 0
+    out = capsys.readouterr().out
+    assert "als.predict.vocab_inverse" in out and "self_s" in out
+
+
 def test_load_32_clients_qps_and_p99(served):
     """32 concurrent clients against the DEFAULT config: sustained qps and
     bounded p99, and the adaptive window + device-time bookkeeping move."""

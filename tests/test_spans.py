@@ -352,3 +352,202 @@ def test_route_label_cardinality_bounded():
     assert label("/queries.json") == "/queries.json"
     assert label("/cmd/app") == "/cmd/app"
     assert label("/metrics") == "/metrics"
+
+
+# -- one clock, windowed statistics, the per-job collector (ISSUE 25) ---------
+
+
+def _after_the_fact(recorder, name, start_mono, duration, parent=None,
+                    span_id=None, trace_id="t-stats"):
+    """A span built from known instants, as the dispatcher builds its
+    per-query spans."""
+    sp = Span(trace_id=trace_id, span_id=span_id or new_span_id(), name=name,
+              parent_span_id=parent, start=1.0, duration=duration,
+              start_mono=start_mono)
+    recorder.record(sp)
+    return sp
+
+
+def test_span_stamps_the_monotonic_clock_beside_the_epoch(recorder):
+    import time
+
+    before = time.monotonic()
+    with recorder.span("clocked") as sp:
+        pass
+    assert before <= sp.start_mono <= time.monotonic()
+    assert abs(sp.start - time.time()) < 60  # the epoch start stays
+    assert "start_mono" not in sp.to_dict()  # one process's clock only
+
+
+def test_stats_honours_the_windows_edges(recorder):
+    for end in (10.5, 11.5, 12.5):
+        _after_the_fact(recorder, "batch.predict", end - 0.25, 0.25)
+    _after_the_fact(recorder, "other", 11.0, 0.5)
+    inside = recorder.stats(11.0, 12.0)
+    assert inside["batch.predict"] == {
+        "count": 1, "total_s": 0.25, "self_s": 0.25}
+    assert inside["other"]["count"] == 1
+    # a second that overlaps the window counts whole
+    assert recorder.stats(10.9, 12.1)["batch.predict"]["count"] == 3
+    assert recorder.stats(13.0, 20.0) == {}
+    # no upper edge: up to now, far past these hand-made instants
+    assert recorder.stats(12.0)["batch.predict"]["count"] == 1
+
+
+def test_stats_keeps_a_bounded_number_of_seconds(recorder):
+    from predictionio_tpu.obs.spans import STATS_WINDOW_S
+
+    for second in range(STATS_WINDOW_S + 50):
+        _after_the_fact(recorder, "tick", second + 0.1, 0.2)
+    assert len(recorder._stats["tick"]) == STATS_WINDOW_S
+    assert recorder.stats(0.0, 49.9) == {}  # the oldest seconds are gone
+    assert recorder.stats(0.0, 1e9)["tick"]["count"] == STATS_WINDOW_S
+
+
+def test_self_time_is_duration_minus_the_union_of_overlapping_children(recorder):
+    parent = new_span_id()
+    # queue_wait lies inside assemble, as the dispatcher's two spans do
+    _after_the_fact(recorder, "batch.queue_wait", 102.0, 3.0, parent=parent)
+    _after_the_fact(recorder, "batch.assemble", 101.0, 5.0, parent=parent)
+    # a child that sticks out of its parent counts only where it covers it
+    _after_the_fact(recorder, "late", 109.0, 4.0, parent=parent)
+    _after_the_fact(recorder, "server.request", 100.0, 10.0, span_id=parent)
+    row = recorder.stats(100.0, 120.0)["server.request"]
+    assert row["total_s"] == pytest.approx(10.0)
+    assert row["self_s"] == pytest.approx(10.0 - 5.0 - 1.0)
+    assert recorder.stats(100.0, 120.0)["batch.assemble"]["self_s"] == 5.0
+
+
+def test_self_time_sees_a_child_recorded_from_another_thread(recorder):
+    import time
+
+    with recorder.span("query.wait") as parent:
+        t0 = time.monotonic()
+        time.sleep(0.05)
+        covered = time.monotonic() - t0
+
+        def dispatcher():  # after the fact, epoch start only: no start_mono
+            recorder.record(Span(
+                trace_id=parent.trace_id, span_id=new_span_id(),
+                name="batch.device_dispatch", parent_span_id=parent.span_id,
+                start=time.time() - covered, duration=covered))
+
+        worker = threading.Thread(target=dispatcher)
+        worker.start()
+        worker.join(timeout=5)
+        assert not worker.is_alive()
+    row = recorder.stats(t0 - 1.0)["query.wait"]
+    assert row["self_s"] == pytest.approx(parent.duration - covered, abs=0.01)
+    assert row["self_s"] < parent.duration - 0.04
+
+
+def test_collector_gathers_a_jobs_spans_and_what_they_leave_unnamed(recorder):
+    import time
+
+    from predictionio_tpu.obs.spans import collect
+
+    with collect() as totals:
+        with recorder.span("train") as root:
+            with recorder.span("train.read"):
+                time.sleep(0.01)
+            time.sleep(0.02)  # the root's own: nothing names it
+            with recorder.span("train.train") as stage:
+                with recorder.span("als.train.degrees") as leaf:
+                    time.sleep(0.01)
+                with recorder.span("als.train.degrees"):
+                    pass
+    with recorder.span("outside"):  # after the block: not this job's
+        pass
+    assert set(totals.seconds) == {
+        "train", "train.read", "train.train", "als.train.degrees"}
+    assert totals.seconds["train"] == root.duration
+    assert totals.seconds["train.train"] == stage.duration
+    assert totals.seconds["als.train.degrees"] >= leaf.duration  # summed
+    leaves = totals.seconds["train.read"] + totals.seconds["als.train.degrees"]
+    assert totals.unattributed == pytest.approx(root.duration - leaves)
+    assert totals.unattributed >= 0.02
+
+    # spans of another thread are another job's
+    def elsewhere():
+        with recorder.span("elsewhere"):
+            pass
+
+    with collect() as mine:
+        worker = threading.Thread(target=elsewhere)
+        worker.start()
+        worker.join(timeout=5)
+        with recorder.span("here"):
+            pass
+    assert set(mine.seconds) == {"here"}
+
+
+def test_detached_span_roots_a_trace_of_its_own(recorder):
+    from predictionio_tpu.obs.spans import detached
+
+    with recorder.span("batch.predict") as outer:
+        with detached(), recorder.span("batch.serve") as inner:
+            pass
+        with recorder.span("nested") as nested:
+            pass
+    assert inner.trace_id != outer.trace_id and inner.parent_span_id is None
+    assert nested.parent_span_id == outer.span_id  # the context came back
+    assert [s.name for s in recorder.get_trace(inner.trace_id)] == ["batch.serve"]
+
+
+def test_obs_spans_never_imports_jax():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from predictionio_tpu.obs import spans\n"
+        "import predictionio_tpu.obs\n"
+        "with spans.span('x'):\n"
+        "    pass\n"
+        "assert spans.get_default_recorder().stats(0.0)['x']['count'] == 1\n"
+        "print('jax' in sys.modules)\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "False"
+
+
+def test_span_with_jax_loaded_and_no_profiler_writes_no_trace(
+        recorder, tmp_path, monkeypatch):
+    import jax
+
+    from predictionio_tpu.obs import spans
+
+    monkeypatch.chdir(tmp_path)
+    assert isinstance(spans._trace_annotation("x"), jax.profiler.TraceAnnotation)
+    with recorder.span("quiet") as sp:
+        pass
+    assert sp.duration >= 0.0 and not sp.error
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_span_is_an_event_of_a_running_profiler_trace(recorder, tmp_path):
+    """The tentpole: under a profiler trace a program span lands on the
+    /host:CPU plane of the same .xplane.pb the device events are in."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with recorder.span("als.train.degrees"):
+            time.sleep(0.02)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = [
+        ev.duration_ns
+        for plane in ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines for ev in line.events
+        if ev.name == "als.train.degrees"
+    ]
+    assert len(found) == 1 and found[0] >= 15_000_000

@@ -220,7 +220,13 @@ def test_trace_spans_cross_process_query(keep_all_traces):
         rpcs = by_name["storage.rpc"]
         fetch = [s for s in rpcs if s["attrs"]["dao"] == "events"]
         assert fetch, rpcs
-        assert all(s["parent_span_id"] == device["span_id"] for s in fetch)
+        # (since ISSUE 25 through the batch's own batch.predict span)
+        predict = by_name["batch.predict"][0]
+        assert predict["parent_span_id"] == device["span_id"]
+        assert all(s["parent_span_id"] == predict["span_id"] for s in fetch)
+        # the request's own phases stand beside the dispatcher's spans
+        for name in ("query.decode", "query.wait", "query.encode"):
+            assert by_name[name][0]["parent_span_id"] == root["span_id"]
         # ...and the storage DAEMON's server span parented under the rpc
         # client span across the process boundary via X-Parent-Span
         daemon_spans = [
