@@ -18,14 +18,11 @@ V = TypeVar("V", bound=Hashable)
 class BiMap(Generic[K, V]):
     __slots__ = ("_fwd", "_rev")
 
-    def __init__(self, forward: Mapping[K, V], _rev: Optional[dict] = None):
+    def __init__(self, forward: Mapping[K, V]):
         self._fwd = dict(forward)
-        if _rev is not None:
-            self._rev = _rev
-        else:
-            self._rev = {v: k for k, v in self._fwd.items()}
-            if len(self._rev) != len(self._fwd):
-                raise ValueError("BiMap values must be unique")
+        self._rev = {v: k for k, v in self._fwd.items()}
+        if len(self._rev) != len(self._fwd):
+            raise ValueError("BiMap values must be unique")
 
     def __call__(self, key: K) -> V:
         return self._fwd[key]
@@ -39,7 +36,12 @@ class BiMap(Generic[K, V]):
     __contains__ = contains
 
     def inverse(self) -> "BiMap[V, K]":
-        return BiMap(self._rev, _rev=self._fwd)
+        """O(1): the inverse shares this map's two dicts, swapped. Neither
+        is reachable from outside (``to_dict`` copies), so neither map can
+        change under the other."""
+        inv = BiMap.__new__(BiMap)
+        inv._fwd, inv._rev = self._rev, self._fwd
+        return inv
 
     def take(self, keys: Iterable[K]) -> "BiMap[K, V]":
         return BiMap({k: self._fwd[k] for k in keys if k in self._fwd})

@@ -603,15 +603,16 @@ class ALSAlgorithm(Algorithm):
         shapes."""
         if model.factors.user_factors.shape[0] == 0:
             return
-        vocab_ids = list(model.factors.user_vocab.to_dict())
-        if not vocab_ids:
+        user = next(iter(model.factors.user_vocab), None)
+        if user is None:
             return
         # one known item id (none for an empty catalog: no filter then)
-        item_ids = list(model.factors.item_vocab.to_dict())[:1]
+        item = next(iter(model.factors.item_vocab), None)
+        item_ids = [] if item is None else [item]
         for batch in (1, 8, 64):  # the full serving bucket ladder
             # nomask program
             self._predict_batch(
-                model, [Query(user=vocab_ids[0], num=10)] * batch
+                model, [Query(user=user, num=10)] * batch
             )
             # exclusion programs — one per wire form, each its own
             # compiled signature: a known-id blacklist ships as a row
@@ -621,7 +622,7 @@ class ALSAlgorithm(Algorithm):
             for filt in ({"blacklist": item_ids}, {"whitelist": item_ids}):
                 self._predict_batch(
                     model,
-                    [Query(user=vocab_ids[0], num=10, **filt)] * batch,
+                    [Query(user=user, num=10, **filt)] * batch,
                 )
 
     def _exclusion_mask(
@@ -782,8 +783,7 @@ class ALSAlgorithm(Algorithm):
             scores = np.asarray(scores)[:n_real]
             items = np.asarray(items)[:n_real]
         with _spans.span("als.predict.decode"):
-            with _spans.span("als.predict.vocab_inverse"):
-                inv = model.factors.item_vocab.inverse()
+            inv = model.factors.item_vocab.inverse()
             for row, (qi, _u) in enumerate(known_ix):
                 n = min(queries[qi].num, k)
                 item_scores = [
@@ -792,9 +792,6 @@ class ALSAlgorithm(Algorithm):
                     if s > NEG_INF / 2
                 ]
                 results[qi] = PredictedResult(item_scores=item_scores)
-            # freeing the copy is part of what it costs (~40 ms at 5.7 M
-            # ids, PERF.md PR 25): inside the span, not after it
-            del inv
         return results
 
     def predict(self, model: ALSModel, query: Query) -> PredictedResult:

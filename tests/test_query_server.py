@@ -203,16 +203,18 @@ def test_debug_traces_stats_answers_for_the_asked_window(served, capsys):
     for name in ("server.request", "query.decode", "query.wait",
                  "query.encode", "batch.queue_wait", "batch.predict",
                  "batch.serve", "als.predict.prepare", "als.predict.device",
-                 "als.predict.decode", "als.predict.vocab_inverse"):
+                 "als.predict.decode"):
         assert spans[name]["count"] >= 1, name
         assert 0.0 <= spans[name]["self_s"] <= spans[name]["total_s"] + 1e-9
     assert spans["query.wait"]["count"] >= 4
     # a request's self time leaves out what its child spans cover
     req = spans["server.request"]
     assert req["self_s"] < req["total_s"]
-    # the decode span's children cover part of it, never more than it
+    # ISSUE 26: the decode span has no child since inverse() stopped
+    # copying the vocabulary, so all of it is its own
+    assert "als.predict.vocab_inverse" not in spans
     decode = spans["als.predict.decode"]
-    assert decode["self_s"] <= decode["total_s"]
+    assert decode["self_s"] == pytest.approx(decode["total_s"])
     # a window that holds nothing yet answers with an empty table
     _, text = get(port, "/debug/traces?stats=1&window=bogus")
     assert json.loads(text)["window_s"] == 60.0
@@ -224,7 +226,7 @@ def test_debug_traces_stats_answers_for_the_asked_window(served, capsys):
         ["trace", "stats", "--url", f"http://127.0.0.1:{port}",
          "--window", "30"]) == 0
     out = capsys.readouterr().out
-    assert "als.predict.vocab_inverse" in out and "self_s" in out
+    assert "als.predict.decode" in out and "self_s" in out
 
 
 def test_load_32_clients_qps_and_p99(served):

@@ -229,6 +229,83 @@ def test_recommendation_serve_dtype_int8_end_to_end():
     assert model.resident_device_bytes() < f32_bytes
 
 
+def _count_calls(monkeypatch, cls, name):
+    calls = []
+    real = getattr(cls, name)
+
+    def counted(*a, **kw):
+        calls.append(name)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(cls, name, counted)
+    return calls
+
+
+def _big_vocab_case():
+    """A 100,000-item model and queries with an unknown user and
+    blacklists (known, last-row and unknown ids)."""
+    from predictionio_tpu.engines.recommendation.engine import (
+        ALSAlgorithm,
+        ALSAlgorithmParams,
+        ALSModel,
+        Query,
+    )
+
+    f = _als_factors(np.random.RandomState(1), i=100_000)
+    queries = [
+        Query(user="u1", num=10),
+        Query(user="nobody", num=10),
+        Query(user="u2", num=7, blacklist=["i0", "i5", "i99999", "no-such"]),
+        Query(user="u29", num=3),
+        Query(user="u1", num=10, blacklist=["i3"]),
+    ]
+    return ALSAlgorithm(ALSAlgorithmParams()), ALSModel(f), f, queries
+
+
+@pytest.mark.parametrize("call", ["predict_batch", "warmup"])
+def test_serving_makes_no_copy_the_size_of_the_vocabulary(monkeypatch, call):
+    """ISSUE 26: at 100,000 items neither a batch nor warm-up builds a
+    BiMap through the copying constructor or lists one with to_dict()."""
+    from predictionio_tpu.data.store.bimap import BiMap
+
+    algo, model, _f, queries = _big_vocab_case()
+    built = _count_calls(monkeypatch, BiMap, "__init__")
+    listed = _count_calls(monkeypatch, BiMap, "to_dict")
+    if call == "warmup":
+        algo.warmup(model)
+    else:
+        out = algo._predict_batch(model, queries)
+        assert [len(p.item_scores) for p in out] == [10, 0, 7, 3, 10]
+    assert built == [] and listed == []
+
+
+def test_replies_equal_the_copying_decodes(monkeypatch):
+    """Same ids, same scores, same order as with the parent's inverse(),
+    which built its result through the copying constructor."""
+    from predictionio_tpu.data.store.bimap import BiMap
+
+    algo, model, f, queries = _big_vocab_case()
+    shared = algo._predict_batch(model, queries)
+    monkeypatch.setattr(
+        BiMap, "inverse",
+        lambda self: BiMap({v: k for k, v in self.to_dict().items()}),
+    )
+    copied = algo._predict_batch(model, queries)
+
+    def replies(preds):
+        return [[(s.item, s.score) for s in p.item_scores] for p in preds]
+
+    assert replies(shared) == replies(copied)
+    # and they are the right replies: numpy's own ranking of the products
+    scores = f.item_factors @ f.user_factors[2]
+    scores[[0, 5, 99_999]] = -np.inf
+    want = [f"i{ix}" for ix in np.argsort(-scores)[:7]]
+    assert [s.item for s in shared[2].item_scores] == want
+    assert shared[1].item_scores == []
+    without_i3 = [s.item for s in shared[0].item_scores if s.item != "i3"]
+    assert [s.item for s in shared[4].item_scores][:9] == without_i3[:9]
+
+
 def test_similarproduct_sharded_matches_host_ranking():
     jax = pytest.importorskip("jax")
     if len(jax.devices()) < 2:
