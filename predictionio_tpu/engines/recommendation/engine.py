@@ -313,10 +313,35 @@ class ALSModel:
         the stage lock (pipelined batches must not double-stage)."""
         with self._stage_lock:
             if self._serving_state is None:
+                self._check_fits_one_device()
                 self._serving_state = als.stage_serving(
                     self.factors, serve_dtype=self.serve_dtype
                 )
             return self._serving_state
+
+    def _check_fits_one_device(self) -> None:
+        """`OversizedModelError`, naming the sharded tier, where the
+        factor state is over one device's budget — PIO_SERVE_HBM_BYTES,
+        else the memory the device reports (a CPU reports none: no
+        gate) — instead of a death in the allocator mid-staging."""
+        import jax
+
+        from predictionio_tpu.fleet import check_single_device_budget
+        from predictionio_tpu.utils.env import env_opt_float
+
+        budget = env_opt_float("PIO_SERVE_HBM_BYTES")
+        if budget is None:
+            stats = jax.devices()[0].memory_stats() or {}
+            budget = stats.get("bytes_limit")
+        if budget is None:
+            return
+        check_single_device_budget(
+            self.factors.user_factors.shape[0],
+            self.factors.item_factors.shape[0],
+            self.factors.user_factors.shape[1],
+            float(budget),
+            serve_dtype=self.serve_dtype,
+        )
 
     def adopt_serving(self, old_state, dirty_users=None, dirty_items=None):
         """Fold-in publish hook (online/foldin.py:_clone_model): carry

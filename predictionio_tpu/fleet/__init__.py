@@ -78,6 +78,37 @@ def stage_serving_runtime(user_factors, item_factors, **kwargs):
 __all__.append("stage_serving_runtime")
 
 
+def bridge_sharded_metrics(registry):
+    """Count the sharded tier's batches into `registry` off the span
+    `sharded.dispatch` that `ShardedRuntime.recommend` records (the span
+    is the one observation, as with the dispatcher's queue wait):
+    `sharded_batches_total{form}` by the exclusion's wire form ("none",
+    "rows" = a blacklist's row list, "mask" = a dense filter) and
+    `sharded_exclusion_bytes_total`, the packed exclusion words the host
+    built and shipped. Returns the callback, for `unbridge`."""
+    from predictionio_tpu.obs import spans as _spans
+
+    batches = registry.counter(
+        "sharded_batches_total",
+        "batches through ShardedRuntime.recommend, by exclusion form",
+        labelnames=("form",),  # label-bound: literal none|rows|mask
+    )
+    nbytes = registry.counter(
+        "sharded_exclusion_bytes_total",
+        "bytes of packed exclusion words built and shipped per batch",
+    )
+
+    def observe(sp):
+        batches.inc(form=sp.attrs.get("form", "none"))
+        nbytes.inc(float(sp.attrs.get("exclusion_bytes", 0)))
+
+    _spans.get_default_recorder().bridge("sharded.dispatch", observe)
+    return observe
+
+
+__all__.append("bridge_sharded_metrics")
+
+
 def __getattr__(name):
     if name in _LAZY_RUNTIME:
         from predictionio_tpu.fleet import runtime as _runtime

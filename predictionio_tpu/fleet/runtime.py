@@ -68,6 +68,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.obs import devprof as _devprof
+from predictionio_tpu.obs import spans as _spans
 from predictionio_tpu.ops.segment import batched_cg, f32_gram
 from predictionio_tpu.ops.topk import NEG_INF
 from predictionio_tpu.parallel.mesh import (
@@ -97,18 +98,26 @@ def factor_state_bytes(
     return (n_users + n_items) * rank * dtype_bytes
 
 
+#: bytes of one stored factor cell, by serve dtype
+SERVE_DTYPE_BYTES = {"f32": 4, "bf16": 2, "int8": 1}
+
+
 def check_single_device_budget(
-    n_users: int, n_items: int, rank: int, budget_bytes: float
+    n_users: int, n_items: int, rank: int, budget_bytes: float,
+    serve_dtype: str = "f32",
 ) -> None:
     """Raise when a SINGLE-device runtime cannot hold this factor
     state — the gate the sharded tier exists to pass (bench's
     oversized-catalog proof calls this for the refusal side)."""
-    need = factor_state_bytes(n_users, n_items, rank)
+    need = factor_state_bytes(
+        n_users, n_items, rank, SERVE_DTYPE_BYTES[serve_dtype]
+    )
     if need > budget_bytes:
         raise OversizedModelError(
             f"factor state needs {need / 1e9:.2f} GB resident but the "
             f"single-device budget is {budget_bytes / 1e9:.2f} GB — "
-            "serve it sharded (fleet.ShardedRuntime)"
+            "serve it sharded (fleet.ShardedRuntime; the engine's "
+            "`shard_serving: true`)"
         )
 
 
@@ -651,13 +660,8 @@ class ShardedRuntime:
             _rp.ITEM_PAD if self.serve_mode is not None else 32
         )
         i_p = -(-max(itf.shape[0], 1) // quantum) * quantum
-        if i_p != itf.shape[0]:
-            itf = np.concatenate([
-                itf,
-                np.zeros((i_p - itf.shape[0], itf.shape[1]), itf.dtype),
-            ])
         self.n_users, self.rank = uf.shape
-        self.n_items = int(np.asarray(item_factors).shape[0])
+        self.n_items = int(itf.shape[0])
         if device_budget_bytes is not None:
             per_shard = self._staged_bytes_estimate(uf, itf) / self.n_shards
             if per_shard > device_budget_bytes:
@@ -683,26 +687,44 @@ class ShardedRuntime:
         # queries, folds, and swaps (CreateServer-style resident state);
         # they live in ONE immutable _ShardState tuple that readers
         # snapshot atomically and publishes swap atomically
-        uscale = iscale = None
-        if serve_dtype == "int8":
-            uq, us = _rp.quantize_rows_np(uf)
-            iq, isc = _rp.quantize_rows_np(itf)
-            uf_dev = shard_rows(mesh, uq)
-            itf_dev = shard_rows(mesh, iq)
-            uscale = shard_rows(mesh, us[:, None])
-            iscale = self._put_cols(np.ascontiguousarray(isc[None, :]))
-        else:
-            uf_dev = shard_rows(mesh, uf)
-            itf_dev = shard_rows(mesh, itf)
-            if serve_dtype == "bf16":
-                uf_dev = uf_dev.astype(jnp.bfloat16)
-                itf_dev = itf_dev.astype(jnp.bfloat16)
-        # inverse norms (from the f32 rows) serve the cosine verbs off
-        # the same slab; i_p is col-shardable by construction
-        self._state = _ShardState(
-            uf=uf_dev, itf=itf_dev, uscale=uscale, iscale=iscale,
-            iinv=self._put_cols(_rp.inv_norms_np(itf, i_p)),
-        )
+        # staging is spans (ISSUE 27): what the host prepares (inverse
+        # norms, int8 quantization), then the sharded puts — the item
+        # pad rides them — until every slab is resident
+        with _spans.span(
+            "sharded.stage", shards=self.n_shards, dtype=serve_dtype
+        ):
+            with _spans.span("sharded.stage.pad"):
+                # inverse norms (from the f32 rows) serve the cosine
+                # verbs off the same slab; i_p is col-shardable by
+                # construction
+                iinv = _rp.inv_norms_np(itf, i_p)
+                us = isc = None
+                if serve_dtype == "int8":
+                    uf, us = _rp.quantize_rows_np(uf)
+                    itf, isc = _rp.quantize_rows_np(itf)
+                    # a pad row's scale is a zero row's: 1.0
+                    isc = np.concatenate([
+                        isc, np.ones(i_p - isc.shape[0], isc.dtype)
+                    ])
+            with _spans.span("sharded.stage.transfer") as sp:
+                uscale = iscale = None
+                uf_dev = shard_rows(mesh, uf)
+                # the item pad rides the put: only the last shard's
+                # slab is copied to add its zero rows
+                itf_dev = shard_rows(mesh, itf, pad_to=i_p)
+                if serve_dtype == "int8":
+                    uscale = shard_rows(mesh, us[:, None])
+                    iscale = self._put_cols(
+                        np.ascontiguousarray(isc[None, :])
+                    )
+                elif serve_dtype == "bf16":
+                    uf_dev = uf_dev.astype(jnp.bfloat16)
+                    itf_dev = itf_dev.astype(jnp.bfloat16)
+                self._state = jax.block_until_ready(_ShardState(
+                    uf=uf_dev, itf=itf_dev, uscale=uscale, iscale=iscale,
+                    iinv=self._put_cols(iinv),
+                ))
+                sp.attrs["bytes"] = int(self.device_bytes()["total"])
 
     def _put_cols(self, arr: np.ndarray):
         return jax.device_put(
@@ -717,7 +739,7 @@ class ShardedRuntime:
         a tiny catalog that plainly fits."""
         u_p = pad_rows_to_shards(self.n_users, self.n_shards)
         i_p = pad_rows_to_shards(self.n_items, self.n_shards)
-        cell = {"f32": 4, "bf16": 2, "int8": 1}[self.serve_dtype]
+        cell = SERVE_DTYPE_BYTES[self.serve_dtype]
         total = (u_p + i_p) * self.rank * cell + i_p * 4  # + inv norms
         if self.serve_dtype == "int8":
             total += (u_p + i_p) * 4  # scale vectors
@@ -784,20 +806,54 @@ class ShardedRuntime:
         into packed words host-side — the sharded tier always ships
         bit-packed exclusion (1/32 the f32-equivalent bytes)."""
         k = min(int(k), self.n_items)
-        rows = jnp.asarray(np.asarray(user_indices, np.int32))
-        if exclude_rows is not None and exclude_mask is None:
-            bits = self._pack_rows(exclude_rows)
-        else:
-            bits = self._pack_mask(exclude_mask)
-        with self._lease() as st, _collective_guard(self.mesh):
-            vals, idx = jax.block_until_ready(_sharded_recommend(
-                rows, st.uf, st.itf, st.uscale, st.iscale, bits,
-                k=k, n_items=self.n_items, mesh=self.mesh,
-                mode=self.serve_mode,
-            ))
-        return np.asarray(vals), np.asarray(idx)
+        rows_np = np.asarray(user_indices, np.int32)
+        # the three phases of a sharded batch are spans (ISSUE 27): the
+        # host's build of the exclusion words, the collective program
+        # with its puts until the answers are ready, the copies back
+        form = (
+            "mask" if exclude_mask is not None
+            else "rows" if exclude_rows is not None else "none"
+        )
+        words = None
+        if form != "none":
+            with _spans.span("sharded.pack_exclusions", form=form) as sp:
+                words = (
+                    self._pack_mask(exclude_mask) if form == "mask"
+                    else self._pack_rows(exclude_rows)
+                )
+                sp.attrs["rows"] = int(words.shape[0])
+                sp.attrs["bytes"] = int(words.nbytes)
+        nbytes = 0 if words is None else int(words.nbytes)
+        with _spans.span(
+            "sharded.dispatch", batch=len(rows_np), shards=self.n_shards,
+            form=form, exclusion_bytes=nbytes,
+        ):
+            with self._lease() as st, _collective_guard(self.mesh):
+                # the puts are a child span, held until the arrays are
+                # resident: the host re-lays the words for the column-
+                # sharded put before they cross, and that is not the
+                # program's time (the program cannot start without them)
+                with _spans.span(
+                    "sharded.dispatch.put", bytes=nbytes + rows_np.nbytes
+                ):
+                    rows = jnp.asarray(rows_np)
+                    bits = None if words is None else self._put_cols(words)
+                    jax.block_until_ready((rows, bits))
+                vals, idx = jax.block_until_ready(_sharded_recommend(
+                    rows, st.uf, st.itf, st.uscale, st.iscale, bits,
+                    k=k, n_items=self.n_items, mesh=self.mesh,
+                    mode=self.serve_mode,
+                ))
+            # the words go where they came: dropping the last reference
+            # unmaps up to 157 MB of host pages and frees the device's
+            # copy, which would else fall, unnamed, at this call's return
+            if words is not None:
+                with _spans.span("sharded.dispatch.release"):
+                    del words, bits
+        with _spans.span("sharded.copy_back"):
+            return np.asarray(vals), np.asarray(idx)
 
-    def _pack_rows(self, exclude_rows) -> Optional[jax.Array]:
+    def _pack_rows(self, exclude_rows) -> np.ndarray:
         """Exclusion ROW LISTS (the small-blacklist form) scatter their
         ids straight into packed words — never a dense (B, n_items)
         intermediate, which at the catalog scales this tier exists for
@@ -812,20 +868,16 @@ class ShardedRuntime:
                 words, (b_idx, ids >> 5),
                 np.uint32(1) << (ids & 31).astype(np.uint32),
             )
-        return self._put_cols(words.view(np.int32))
+        return words.view(np.int32)
 
-    def _pack_mask(self, exclude_mask) -> Optional[jax.Array]:
+    def _pack_mask(self, exclude_mask) -> np.ndarray:
         """Bool exclusion mask → bit-packed words at the sharded item
-        width, column-sharded over the mesh — 1/32 the f32-equivalent
-        mask bytes on the wire and in HBM (ISSUE 14)."""
-        if exclude_mask is None:
-            return None
+        width, shipped column-sharded over the mesh — 1/32 the
+        f32-equivalent mask bytes on the wire and in HBM (ISSUE 14)."""
         from predictionio_tpu.ops.recommend_pallas import pack_mask_np
 
         i_p = int(self._state.itf.shape[0])
-        return self._put_cols(
-            pack_mask_np(np.asarray(exclude_mask, bool), i_p)
-        )
+        return pack_mask_np(np.asarray(exclude_mask, bool), i_p)
 
     def similar_vectors(
         self,
@@ -838,7 +890,10 @@ class ShardedRuntime:
         state (ISSUE 11 satellite)."""
         k = min(int(k), self.n_items)
         vecs = jnp.asarray(np.asarray(vectors, np.float32))
-        bits = self._pack_mask(exclude_mask)
+        bits = (
+            None if exclude_mask is None
+            else self._put_cols(self._pack_mask(exclude_mask))
+        )
         with self._lease() as st, _collective_guard(self.mesh):
             vals, idx = jax.block_until_ready(_sharded_similar_vecs(
                 vecs, st.itf, st.iscale, st.iinv, bits,

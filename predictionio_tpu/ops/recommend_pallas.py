@@ -627,8 +627,12 @@ def inv_norms_np(arr, pad_to: int = 0):
     arr = np.asarray(arr, np.float32)
     n = arr.shape[0]
     out = np.zeros((1, max(pad_to, n)), np.float32)
-    if n:
-        out[0, :n] = 1.0 / (np.linalg.norm(arr, axis=1) + 1e-9)
+    # in row chunks: `norm` squares into a temporary the size of its
+    # input, 10 GB more of host memory to touch at a 19.7 M-row table
+    chunk = 1 << 18
+    for lo in range(0, n, chunk):
+        hi = min(lo + chunk, n)
+        out[0, lo:hi] = 1.0 / (np.linalg.norm(arr[lo:hi], axis=1) + 1e-9)
     return out
 
 

@@ -136,25 +136,40 @@ def pad_rows_to_shards(n_rows: int, n_shards: int) -> int:
     return -(-max(n_rows, 1) // n_shards) * n_shards
 
 
-def shard_rows(mesh: Mesh, array: np.ndarray, axis_name: str = MODEL_AXIS):
-    """Zero-pad axis 0 to a whole-slab multiple of the axis size and
-    row-shard it over `axis_name` (remaining axes replicated). Callers
-    must keep pad rows inert (zero factors score 0 and are masked out
-    of top-k by the global-index pad mask).
+def shard_rows(
+    mesh: Mesh, array: np.ndarray, axis_name: str = MODEL_AXIS,
+    pad_to: int = 0,
+):
+    """Zero-pad axis 0 to a whole-slab multiple of the axis size (and to
+    at least `pad_to` rows) and row-shard it over `axis_name` (remaining
+    axes replicated). Callers must keep pad rows inert (zero factors
+    score 0 and are masked out of top-k by the global-index pad mask).
 
-    The HOST array goes straight into the sharded device_put: routing
+    Each shard's slab is cut from the HOST array as it is put: routing
     through jnp.asarray first would materialize the whole matrix on the
     default device before resharding — an instant OOM for exactly the
-    over-one-HBM catalogs the sharded tier exists to hold."""
+    over-one-HBM catalogs the sharded tier exists to hold. A slab inside
+    the array is a view of it; only a slab that reaches into the pad is
+    copied, so the whole table (10 GB at 19.7 M rows of rank 128) never
+    is."""
     n = int(mesh.shape[axis_name])
-    n_p = pad_rows_to_shards(array.shape[0], n)
-    if n_p != array.shape[0]:
-        array = np.concatenate([
-            array,
-            np.zeros((n_p - array.shape[0],) + array.shape[1:], array.dtype),
-        ])
+    rows = array.shape[0]
+    n_p = pad_rows_to_shards(max(rows, pad_to), n)
     spec = P(axis_name, *([None] * (array.ndim - 1)))
-    return jax.device_put(np.ascontiguousarray(array), NamedSharding(mesh, spec))
+
+    def slab(index):
+        lo, hi, _ = index[0].indices(n_p)
+        part = array[min(lo, rows):min(hi, rows)]
+        if hi > rows:
+            part = np.concatenate([
+                part,
+                np.zeros((hi - max(lo, rows),) + array.shape[1:], array.dtype),
+            ])
+        return part
+
+    return jax.make_array_from_callback(
+        (n_p,) + array.shape[1:], NamedSharding(mesh, spec), slab
+    )
 
 
 def pad_and_shard_rows(mesh: Mesh, *arrays: np.ndarray):

@@ -1346,6 +1346,11 @@ class QueryServer(ServerProcess):
         _spans.get_default_recorder().bridge(
             "batch.queue_wait", self._queue_wait_bridge
         )
+        # the sharded tier's batches and exclusion bytes, counted off
+        # its own `sharded.dispatch` span the same way (ISSUE 27)
+        from predictionio_tpu.fleet import bridge_sharded_metrics
+
+        self._sharded_bridge = bridge_sharded_metrics(self.metrics)
         # load shedding (ISSUE 4): expired/abandoned queries refused
         # before device time, by reason
         self._shed_counter = self.metrics.counter(
@@ -1445,6 +1450,9 @@ class QueryServer(ServerProcess):
             self.dispatcher.stop()
         _spans.get_default_recorder().unbridge(
             "batch.queue_wait", self._queue_wait_bridge
+        )
+        _spans.get_default_recorder().unbridge(
+            "sharded.dispatch", self._sharded_bridge
         )
         with self._feedback_lock:
             pending_feedback = list(self._feedback_threads)
