@@ -82,20 +82,21 @@ def bridge_sharded_metrics(registry):
     """Count the sharded tier's batches into `registry` off the span
     `sharded.dispatch` that `ShardedRuntime.recommend` records (the span
     is the one observation, as with the dispatcher's queue wait):
-    `sharded_batches_total{form}` by the exclusion's wire form ("none",
-    "rows" = a blacklist's row list, "mask" = a dense filter) and
-    `sharded_exclusion_bytes_total`, the packed exclusion words the host
-    built and shipped. Returns the callback, for `unbridge`."""
+    `sharded_batches_total{form}` by the exclusion's WIRE form ("none";
+    "rows" = shipped as ids, a list of at most ROWLIST_MAX a query;
+    "mask" = shipped as packed words, a dense filter or a wider list)
+    and `sharded_exclusion_bytes_total`, the bytes of exclusion the host
+    shipped in either form. Returns the callback, for `unbridge`."""
     from predictionio_tpu.obs import spans as _spans
 
     batches = registry.counter(
         "sharded_batches_total",
-        "batches through ShardedRuntime.recommend, by exclusion form",
+        "batches through ShardedRuntime.recommend, by exclusion wire form",
         labelnames=("form",),  # label-bound: literal none|rows|mask
     )
     nbytes = registry.counter(
         "sharded_exclusion_bytes_total",
-        "bytes of packed exclusion words built and shipped per batch",
+        "bytes of exclusion (row lists or packed words) shipped",
     )
 
     def observe(sp):

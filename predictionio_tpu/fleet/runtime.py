@@ -37,9 +37,12 @@ ISSUE 14 additions:
   kernel (per-shard live counts ride its traced SMEM scalar; item rows
   pre-pad to shards × ITEM_PAD so every slab is tile-divisible).
 - **bit-packed exclusion masks**: the (B, I) bool mask input is gone —
-  exclusion ships as (B, I_p/32) packed words column-sharded over the
-  mesh (1/32 the f32-equivalent bytes), expanded in registers by the
-  kernel or unpacked in-jit by the XLA fallback.
+  a dense exclusion ships as (B, I_p/32) packed words column-sharded
+  over the mesh (1/32 the f32-equivalent bytes), expanded in registers
+  by the kernel or unpacked in-jit by the XLA fallback. A row list of
+  at most ROWLIST_MAX ids a query (a blacklist) ships as the ids
+  themselves, replicated, and each shard renumbers it into its own
+  slab's rows (ISSUE 29): `recommend` picks the encoding by shape.
 - **donated dirty-row publish** (direction-1 item (c)): `update_*_rows`
   re-quantizes ONLY the dirty rows and, once in-flight readers drain
   (a short writer-priority window on the reader lease), DONATES the
@@ -195,10 +198,13 @@ def _local_score_topk(
     seam the single-device tier serves through
     (ops/recommend_pallas.py:fused_or_xla_topk): the fused one-pass
     kernel when a mode resolved (the per-shard live count rides the
-    traced SMEM scalar; packed words / local-id row lists apply in
-    registers), else the XLA two-step with identical semantics
+    traced SMEM scalar), else the XLA two-step with identical semantics
     (including the batch-size-stable dot spelling its docstring
-    records)."""
+    records). The exclusion arrives in ONE of its two encodings, never
+    both: `mask_bits_l`, this shard's columns of the packed words, or
+    `excl_local`, a (B, E) list of SHARD-LOCAL rows in which -1 (the
+    pad, and every row another shard owns) is inert; both apply in
+    registers."""
     from predictionio_tpu.ops.recommend_pallas import fused_or_xla_topk
 
     return fused_or_xla_topk(
@@ -217,6 +223,9 @@ def _sharded_recommend(
     uscale: Optional[jax.Array],  # (U_p, 1) f32 row-sharded (int8)
     iscale: Optional[jax.Array],  # (1, I_p) f32 col-sharded (int8)
     mask_bits: Optional[jax.Array],  # (B, I_p/32) int32 col-sharded
+    excl_rows: Optional[jax.Array] = None,  # (B, E) int32 replicated:
+    # GLOBAL item rows, -1 padded — the other encoding of the same
+    # exclusion set, never beside mask_bits
     *,
     k: int,
     n_items: int,
@@ -226,14 +235,16 @@ def _sharded_recommend(
     """Sharded recommend: the shard-local score+select is the SAME
     verb-agnostic fused pass as the single-device path (ISSUE 14),
     amortized by the local-top-k + all-gather merge — each shard never
-    materializes even its local (B, i_local) score slab."""
+    materializes even its local (B, i_local) score slab. A row list
+    stays a row list into each shard's kernel (ISSUE 29): a shard
+    renumbers the global rows into its own slab's and blanks the rest."""
     n_shards = int(mesh.shape[MODEL_AXIS])
     u_local = uf.shape[0] // n_shards
     i_local = itf.shape[0] // n_shards
     k_l = min(k, i_local)
     int8 = uf.dtype == jnp.int8
 
-    def local(rows_l, uf_l, itf_l, uscale_l, iscale_l, mask_l):
+    def local(rows_l, uf_l, itf_l, uscale_l, iscale_l, mask_l, excl):
         idx = jax.lax.axis_index(MODEL_AXIS)
         qf = jax.lax.psum(
             _owned_rows(rows_l, uf_l, u_local), MODEL_AXIS
@@ -254,8 +265,16 @@ def _sharded_recommend(
             qs = isc_l_ = None
         # per-shard live column count: global vocab clipped to my slab
         live_l = jnp.clip(n_items - idx * i_local, 0, i_local)
+        excl_local = None
+        if excl is not None:
+            # global rows -> this slab's: the list's own pad (-1 stays
+            # negative) and every row another shard owns become -1, which
+            # matches no column in the kernel's compare chain or the XLA
+            # scatter
+            loc = excl - idx * i_local
+            excl_local = jnp.where((loc >= 0) & (loc < i_local), loc, -1)
         v, ix = _local_score_topk(
-            q, itf_l, qs, isc_l_, mask_l, None, live_l,
+            q, itf_l, qs, isc_l_, mask_l, excl_local, live_l,
             k_l=k_l, mode=mode,
         )
         return _merge_topk(v, ix + idx * i_local, k)
@@ -265,7 +284,10 @@ def _sharded_recommend(
     return _sharded_call(
         mesh, local,
         required=[(rows, P()), (uf, sh), (itf, sh)],
-        optional=[(uscale, sh), (iscale, col_sh), (mask_bits, col_sh)],
+        optional=[
+            (uscale, sh), (iscale, col_sh), (mask_bits, col_sh),
+            (excl_rows, P()),
+        ],
     )
 
 
@@ -802,62 +824,85 @@ class ShardedRuntime:
     ) -> tuple[np.ndarray, np.ndarray]:
         """Global top-k items per user from the sharded state; same
         contract as models.als.recommend (scores, item_indices).
-        `exclude_rows` (the small-blacklist row-list form) scatters
-        into packed words host-side — the sharded tier always ships
-        bit-packed exclusion (1/32 the f32-equivalent bytes)."""
+
+        The exclusion crosses the chips in the smaller of its two
+        encodings, chosen from the input's shape alone (the one-chip
+        tier's rule, models/als.py `_exclusion_device_args`): a row
+        list no wider than the kernel's `ROWLIST_MAX` ships as it is,
+        (B, E) int32 replicated — a blacklist of 1-8 ids is 32 B a
+        query — and each shard renumbers it into its own slab's rows;
+        a dense mask, or a wider list, ships as (B, I_p/32) packed
+        words column-sharded over the mesh (1/32 the f32-equivalent
+        bytes, and 2.46 MB a query at 19.7 M items)."""
+        from predictionio_tpu.ops.recommend_pallas import ROWLIST_MAX
+
         k = min(int(k), self.n_items)
         rows_np = np.asarray(user_indices, np.int32)
         # the three phases of a sharded batch are spans (ISSUE 27): the
-        # host's build of the exclusion words, the collective program
-        # with its puts until the answers are ready, the copies back
-        form = (
-            "mask" if exclude_mask is not None
-            else "rows" if exclude_rows is not None else "none"
-        )
-        words = None
+        # host's preparation of the exclusion that ships, the collective
+        # program with its puts until the answers are ready, the copies
+        # back. `form` names the WIRE form: "rows" = shipped as ids,
+        # "mask" = shipped as words, "none"
+        if exclude_mask is not None:
+            form = "mask"
+        elif exclude_rows is None:
+            form = "none"
+        else:
+            wide = np.shape(exclude_rows)[1] > ROWLIST_MAX
+            form = "mask" if wide else "rows"
+        shipped = None  # the ids (form "rows") or the words ("mask")
+        nbytes = 0
         if form != "none":
             with _spans.span("sharded.pack_exclusions", form=form) as sp:
-                words = (
-                    self._pack_mask(exclude_mask) if form == "mask"
-                    else self._pack_rows(exclude_rows)
-                )
-                sp.attrs["rows"] = int(words.shape[0])
-                sp.attrs["bytes"] = int(words.nbytes)
-        nbytes = 0 if words is None else int(words.nbytes)
+                if form == "rows":
+                    shipped = np.ascontiguousarray(exclude_rows, np.int32)
+                elif exclude_mask is not None:
+                    shipped = self._pack_mask(exclude_mask)
+                else:
+                    shipped = self._pack_rows(exclude_rows)
+                nbytes = int(shipped.nbytes)
+                sp.attrs["rows"] = int(shipped.shape[0])
+                sp.attrs["bytes"] = nbytes
         with _spans.span(
             "sharded.dispatch", batch=len(rows_np), shards=self.n_shards,
             form=form, exclusion_bytes=nbytes,
         ):
             with self._lease() as st, _collective_guard(self.mesh):
                 # the puts are a child span, held until the arrays are
-                # resident: the host re-lays the words for the column-
+                # resident: words are re-laid on the host for the column-
                 # sharded put before they cross, and that is not the
-                # program's time (the program cannot start without them)
+                # program's time (the program cannot start without them);
+                # query rows and a row list go replicated, as they are
                 with _spans.span(
                     "sharded.dispatch.put", bytes=nbytes + rows_np.nbytes
                 ):
-                    rows = jnp.asarray(rows_np)
-                    bits = None if words is None else self._put_cols(words)
-                    jax.block_until_ready((rows, bits))
+                    rows, excl = jax.device_put(
+                        (rows_np, shipped if form == "rows" else None),
+                        NamedSharding(self.mesh, P()),
+                    )
+                    bits = (
+                        self._put_cols(shipped) if form == "mask" else None
+                    )
+                    jax.block_until_ready((rows, excl, bits))
                 vals, idx = jax.block_until_ready(_sharded_recommend(
-                    rows, st.uf, st.itf, st.uscale, st.iscale, bits,
+                    rows, st.uf, st.itf, st.uscale, st.iscale, bits, excl,
                     k=k, n_items=self.n_items, mesh=self.mesh,
                     mode=self.serve_mode,
                 ))
-            # the words go where they came: dropping the last reference
+            # words go where they came: dropping the last reference
             # unmaps up to 157 MB of host pages and frees the device's
             # copy, which would else fall, unnamed, at this call's return
-            if words is not None:
+            if form == "mask":
                 with _spans.span("sharded.dispatch.release"):
-                    del words, bits
+                    del shipped, bits
         with _spans.span("sharded.copy_back"):
             return np.asarray(vals), np.asarray(idx)
 
     def _pack_rows(self, exclude_rows) -> np.ndarray:
-        """Exclusion ROW LISTS (the small-blacklist form) scatter their
-        ids straight into packed words — never a dense (B, n_items)
-        intermediate, which at the catalog scales this tier exists for
-        would dwarf the blacklist itself."""
+        """A row list too wide for the kernel's compare chain (over
+        `ROWLIST_MAX`) scatters its ids straight into packed words —
+        never a dense (B, n_items) intermediate, which at the catalog
+        scales this tier exists for would dwarf the list itself."""
         ex = np.asarray(exclude_rows, np.int64)
         i_p = int(self._state.itf.shape[0])
         words = np.zeros((ex.shape[0], i_p // 32), np.uint32)

@@ -10,6 +10,11 @@ blacklist, nothing imported from the program), under the cell's own limits.
 The item count is not a multiple of 512, users come from every shard,
 blacklists are row lists whose ids fall in every shard and in the pad, and
 `num` is larger than the last shard's live rows.
+
+Since ISSUE 29 a row list of at most `ROWLIST_MAX` ids a query crosses the
+shards as ids and each shard renumbers it into its own slab's rows; the
+same lists shipped as packed words (one column wider than the kernel takes)
+and the one-chip tier's `als.recommend_serving` must give the same answer.
 """
 
 from __future__ import annotations
@@ -126,6 +131,110 @@ def test_sharded_runtime_equals_the_unsharded_reference(
     assert got["score_gap"] < 1e-5 and got["rank_gap"] < 1e-5
 
 
+@pytest.fixture(scope="module")
+def tiers(tables):
+    """(ShardedRuntime over 4 shards, the one-chip tier's staged state) by
+    (path, serve_dtype), each staged once for the module."""
+    from predictionio_tpu.fleet import ShardedRuntime
+
+    uf, itf = tables
+    fs = als.ALSFactors(uf, itf, None, None, als.ALSParams(rank=RANK))
+    staged: dict = {}
+
+    def get(path, serve_dtype):
+        if (path, serve_dtype) not in staged:
+            mode = "interpret" if path == "fused" else "off"
+            staged[path, serve_dtype] = (
+                ShardedRuntime(uf, itf, mesh=serving_mesh(SHARDS),
+                               serve_mode=mode, serve_dtype=serve_dtype),
+                als.stage_serving(fs, serve_dtype=serve_dtype, mode=mode),
+            )
+        return staged[path, serve_dtype]
+
+    return get
+
+
+def spy_on_pack_rows(monkeypatch, srt) -> list:
+    """The shapes of the row lists `srt` packs to (B, I_p/32) words from
+    here on: empty for as long as every list crosses as ids."""
+    packed: list = []
+    pack_rows = srt._pack_rows
+    monkeypatch.setattr(
+        srt, "_pack_rows", lambda ex: packed.append(ex.shape) or pack_rows(ex))
+    return packed
+
+
+def awkward_lists(users, width, i_local, i_p, uf, itf):
+    """A blacklist a query, of at most `width` ids, four kinds in turn: the
+    first and last live row of every shard; what the user would be served,
+    one id twice, ids in the last shard's pad and past the table; nothing
+    but the pad value; `width` ids and no pad. Wider lists fill with more
+    of what would be served, so that the exclusion decides the answer."""
+    rng = np.random.default_rng(29)
+    lists = []
+    for n, u in enumerate(users):
+        best = np.argsort(-(itf @ uf[u]))[: max(3, width // 2)].tolist()
+        kind = n % 4
+        if kind == 0:
+            ids = [r for s in range(SHARDS)
+                   for r in (s * i_local,
+                             min((s + 1) * i_local, N_ITEMS) - 1)]
+            ids += best[: width - len(ids)]
+        elif kind == 1:
+            ids = best[:3] + [best[0], N_ITEMS, i_p - 1, i_p, 2 ** 31 - 1]
+            ids += best[3: 3 + width - len(ids)]
+        elif kind == 2:
+            ids = []
+        else:
+            ids = rng.choice(N_ITEMS, width, replace=False).tolist()
+        lists.append(ids)
+    return lists
+
+
+# B = 1, 8, 64 are the serving bucket ladder; E = 8 is what `rowlist_np`
+# gives every list of 1-8 ids, 64 the widest the kernel's chain takes
+@pytest.mark.parametrize("serve_dtype", ["f32", "int8"])
+@pytest.mark.parametrize("path", ["xla", "fused"])
+@pytest.mark.parametrize("width", [8, 64])
+@pytest.mark.parametrize("batch", [1, 8, 64])
+def test_a_row_list_crosses_as_ids_and_answers_as_words_and_one_chip_do(
+        tiers, tables, monkeypatch, batch, width, path, serve_dtype):
+    uf, itf = tables
+    srt, one_chip = tiers(path, serve_dtype)
+    i_p = int(srt._state.itf.shape[0])
+    # every kind of list at every batch size: B = 1 serves four batches
+    n = max(batch, 4)
+    users = np.random.default_rng(batch).choice(N_USERS, n, replace=False)
+    black = awkward_lists(users, width, i_p // SHARDS, i_p, uf, itf)
+    packed = spy_on_pack_rows(monkeypatch, srt)
+    for lo in range(0, n, batch):
+        rows = users[lo: lo + batch]
+        lists = black[lo: lo + batch]
+        ex = rowlist(lists, width)
+        scores, items = srt.recommend(rows, 10, exclude_rows=ex)
+        assert not packed  # ids crossed: no (B, I_p/32) words were built
+        # one pad column more than the kernel takes: the same lists as words
+        wide = np.pad(ex, ((0, 0), (0, 65 - width)), constant_values=-1)
+        w_scores, w_items = srt.recommend(rows, 10, exclude_rows=wide)
+        assert packed.pop() and not packed
+        o_scores, o_items = als.recommend_serving(
+            one_chip, rows, 10, exclude_rows=ex)
+        for got_items, got_scores in ((w_items, w_scores),
+                                      (o_items, o_scores)):
+            np.testing.assert_array_equal(items, got_items)
+            np.testing.assert_allclose(
+                scores, got_scores, rtol=1e-5, atol=1e-6)
+        for row, b in zip(items, lists):
+            assert len(set(row.tolist())) == 10
+            assert 0 <= row.min() and row.max() < N_ITEMS
+            assert not set(row.tolist()) & set(b)
+        if serve_dtype == "f32":  # int8 is the cell's control: it must not
+            got = gaps(rows, items, scores, lists, 10, uf, itf)
+            for name, limit in LIMITS.items():
+                assert got[name] <= limit, (name, got)
+            assert got["score_gap"] < 1e-5 and got["rank_gap"] < 1e-5
+
+
 def test_sharded_runtime_equals_the_reference_under_a_dense_mask(
         runtime, tables):
     """The mask form (a whitelist's complement) packs to the same words."""
@@ -186,16 +295,20 @@ def test_engine_shard_serving_against_the_reference(tables, serve_dtype):
         assert got["score_rms_gap"] > LIMITS["score_rms_gap"], got
 
 
-def test_recommend_records_its_three_spans_and_moves_both_counters(tables):
+def test_recommend_records_its_three_spans_and_moves_both_counters(
+        tables, monkeypatch):
     """Profiler off: `sharded.pack_exclusions`, `sharded.dispatch` (with its
     children `.put` and `.release`) and `sharded.copy_back` with their
     attrs, and the two counters through the bridge a `QueryServer` mounts on
-    its registry."""
+    its registry. `form` is the WIRE form: a row list ships as ids (B * E * 4
+    bytes, no words built, nothing to release); a dense mask, and a list one
+    id wider than the kernel takes, ship as words."""
     from predictionio_tpu.fleet import ShardedRuntime, bridge_sharded_metrics
     from predictionio_tpu.obs.registry import MetricsRegistry
 
     uf, itf = tables
     srt = ShardedRuntime(uf, itf, mesh=serving_mesh(SHARDS))
+    packed = spy_on_pack_rows(monkeypatch, srt)
     recorder = _spans.get_default_recorder()
     registry = MetricsRegistry()
     bridge = bridge_sharded_metrics(registry)
@@ -203,44 +316,105 @@ def test_recommend_records_its_three_spans_and_moves_both_counters(tables):
         t0 = time.time()
         srt.recommend(USERS[:2], 10)
         srt.recommend(USERS, 10, exclude_rows=rowlist(BLACK))
+        assert not packed
         mask = np.zeros((1, N_ITEMS), bool)
         mask[0, :100] = True
         srt.recommend(USERS[:1], 10, exclude_mask=mask)
+        _, items = srt.recommend(
+            USERS[:4], 10, exclude_rows=rowlist(BLACK[:4], 65))
+        assert packed == [(4, 65)]
+        for row, b in zip(items, BLACK):
+            assert not set(row.tolist()) & set(b)  # words exclude too
     finally:
         recorder.unbridge("sharded.dispatch", bridge)
     mine = [s for s in recorder.recent(t0) if s.name.startswith("sharded.")]
     by_name: dict[str, list] = {}
     for s in mine:
         by_name.setdefault(s.name, []).append(s)
-    assert len(by_name["sharded.dispatch"]) == 3
-    assert len(by_name["sharded.copy_back"]) == 3
-    assert len(by_name["sharded.pack_exclusions"]) == 2  # none packs nothing
-    i_p = int(srt._state.itf.shape[0])
+    assert len(by_name["sharded.dispatch"]) == 4
+    assert len(by_name["sharded.copy_back"]) == 4
+    assert len(by_name["sharded.pack_exclusions"]) == 3  # none packs nothing
+    words = int(srt._state.itf.shape[0]) // 32 * 4  # bytes a row, packed
     packs = sorted(by_name["sharded.pack_exclusions"],
                    key=lambda s: s.attrs["rows"])
     assert [(s.attrs["form"], s.attrs["rows"], s.attrs["bytes"])
             for s in packs] == [
-        ("mask", 1, 1 * i_p // 32 * 4), ("rows", 8, 8 * i_p // 32 * 4)]
+        ("mask", 1, 1 * words), ("mask", 4, 4 * words), ("rows", 8, 8 * 8 * 4)]
     dispatches = sorted(by_name["sharded.dispatch"],
                         key=lambda s: s.attrs["batch"])
-    assert [(s.attrs["batch"], s.attrs["shards"], s.attrs["form"])
-            for s in dispatches] == [
-        (1, SHARDS, "mask"), (2, SHARDS, "none"), (8, SHARDS, "rows")]
-    # inside a dispatch: the puts until resident (query rows + words),
-    # and the words' release where there were any
+    assert [(s.attrs["batch"], s.attrs["shards"], s.attrs["form"],
+             s.attrs["exclusion_bytes"]) for s in dispatches] == [
+        (1, SHARDS, "mask", words), (2, SHARDS, "none", 0),
+        (4, SHARDS, "mask", 4 * words), (8, SHARDS, "rows", 8 * 8 * 4)]
+    # inside a dispatch: the puts until resident (query rows + what ships),
+    # and the words' release where words were shipped
     puts = sorted(by_name["sharded.dispatch.put"],
                   key=lambda s: s.attrs["bytes"])
-    assert [s.attrs["bytes"] for s in puts] == [
-        2 * 4, 1 * 4 + 1 * i_p // 32 * 4, 8 * 4 + 8 * i_p // 32 * 4]
+    assert [s.attrs["bytes"] for s in puts] == sorted([
+        2 * 4, 1 * 4 + 1 * words, 4 * 4 + 4 * words, 8 * 4 + 8 * 8 * 4])
     assert len(by_name["sharded.dispatch.release"]) == 2
     own = {s.span_id for s in dispatches}
     for child in puts + by_name["sharded.dispatch.release"]:
         assert child.parent_span_id in own
     assert all(s.duration > 0 for s in mine)
     batches = registry.counter("sharded_batches_total", labelnames=("form",))
-    assert [batches.value(form=f) for f in ("none", "rows", "mask")] == [1, 1, 1]
+    assert [batches.value(form=f) for f in ("none", "rows", "mask")] == [1, 1, 2]
     assert registry.counter("sharded_exclusion_bytes_total").total == \
-        9 * i_p // 32 * 4
+        5 * words + 8 * 8 * 4
+
+
+@pytest.mark.parametrize("path", ["xla", "fused"])
+def test_warmup_compiles_every_program_the_traffic_uses(
+        tables, monkeypatch, path):
+    """After `ALSAlgorithm.warmup` on a `shard_serving` model, a batch at
+    each bucket with no filter, a 1-id and an 8-id blacklist (the row-list
+    program, E = 8) and a whitelist (the words program) compiles nothing:
+    the benchmark's `compiles_in_window` reads the same counter."""
+    from predictionio_tpu.data.store.bimap import BiMap
+    from predictionio_tpu.engines.recommendation.engine import (
+        ALSAlgorithm,
+        ALSAlgorithmParams,
+        ALSModel,
+        Query,
+    )
+    from predictionio_tpu.obs import jaxmon
+
+    if path == "fused":
+        monkeypatch.setenv("PIO_PALLAS_RECOMMEND", "interpret")
+    uf, itf = tables
+    fs = als.ALSFactors(
+        uf, itf,
+        BiMap({f"u{i}": i for i in range(N_USERS)}),
+        BiMap({f"i{i}": i for i in range(N_ITEMS)}),
+        als.ALSParams(rank=RANK),
+    )
+    model = ALSModel(fs)
+    algo = ALSAlgorithm(ALSAlgorithmParams(rank=RANK, shard_serving=True))
+    jaxmon.ensure_compile_listener()
+    algo.warmup(model)
+    assert model.sharded_info()["serve_mode"] == (
+        "interpret" if path == "fused" else "xla")
+    rng = np.random.default_rng(31)
+    filters = [
+        lambda: {},
+        lambda: {"blacklist": [f"i{rng.integers(N_ITEMS)}"]},
+        lambda: {"blacklist": [f"i{r}" for r in rng.choice(N_ITEMS, 8)]},
+        lambda: {"whitelist": [f"i{r}" for r in rng.choice(N_ITEMS, 40)]},
+    ]
+    t0 = time.time()
+    before = jaxmon.compile_snapshot()[0]
+    for rows in (1, 8, 64):
+        for filt in filters:
+            queries = [Query(user=f"u{u}", num=10, **filt())
+                       for u in rng.choice(N_USERS, rows)]
+            results = algo._predict_batch(model, queries)
+            assert all(len(r.item_scores) == 10 for r in results)
+    assert jaxmon.compile_snapshot()[0] == before
+    # and the traffic's blacklists did cross as ids, the whitelists as words
+    shipped = [s.attrs["form"] for s in sorted(
+        (s for s in _spans.get_default_recorder().recent(t0)
+         if s.name == "sharded.dispatch"), key=lambda s: s.start)]
+    assert shipped == ["none", "rows", "rows", "mask"] * 3
 
 
 def test_staging_records_the_stage_spans(tables):

@@ -110,7 +110,7 @@ def test_fused_masked_topk_compiles_at_ur_catalog(v5e, mask_kind):
     ).compile()
 
 
-@pytest.mark.parametrize("mask_kind", ["none", "bits"])
+@pytest.mark.parametrize("mask_kind", ["none", "bits", "rows"])
 @pytest.mark.parametrize("dtype", ["f32", "int8"])
 def test_sharded_recommend_compiles_on_four_shards(v5e, dtype, mask_kind):
     from predictionio_tpu.fleet.runtime import _sharded_recommend
@@ -127,9 +127,11 @@ def test_sharded_recommend_compiles_on_four_shards(v5e, dtype, mask_kind):
     if dtype == "int8":
         scales = (rows_sh((u_p, 1), jnp.float32), cols_sh((1, i_p), jnp.float32))
     bits = cols_sh((8, i_p // 32), jnp.int32) if mask_kind == "bits" else None
+    # a row list crosses replicated; each shard renumbers it (ISSUE 29)
+    excl = rep((8, 8), jnp.int32) if mask_kind == "rows" else None
     _sharded_recommend.lower(
         rep((8,), jnp.int32), rows_sh((u_p, RANK), dt),
-        rows_sh((i_p, RANK), dt), *scales, bits,
+        rows_sh((i_p, RANK), dt), *scales, bits, excl,
         k=TOPK, n_items=26_744, mesh=mesh, mode="tpu",
     ).compile()
 
