@@ -95,7 +95,7 @@ def phase(name: str, seconds: float, note: str = "") -> None:
 
 
 def make_corpus(n_users: int, n_items: int, n_events: int, seed: int):
-    """bench.make_data's recipe — dirichlet(0.3) popularity on both sides,
+    """The corpus recipe — dirichlet(0.3) popularity on both sides,
     UNIQUE (user, item) pairs, ratings 1..5 — plus one guaranteed pair per
     user and per item: the trained width is what the data source sees, so
     a user the draw never picked would silently narrow the model."""

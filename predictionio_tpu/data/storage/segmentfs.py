@@ -1198,7 +1198,7 @@ class SegmentFSEventStore(base.EventStore):
     # -- seal / compact ----------------------------------------------------
     def seal(self, app_id: int, channel_id: Optional[int] = None) -> int:
         """Synchronously seal the namespace's tail; returns rows sealed
-        (public: tests, `pio export`-style tools, bench)."""
+        (public: tests, `pio export`-style tools)."""
         with self._lock:
             ns = self._namespace(app_id, channel_id)
         return self._seal_ns(ns)

@@ -225,7 +225,7 @@ class ShardedEventStore(base.EventStore):
         # CONCURRENT callers (the event server's writer threads), not
         # one: at exactly n_stores workers, 8 ingest writers funnel
         # their per-shard bulk writes through n_stores threads and the
-        # composite throttles BELOW a single store (ISSUE 13 bench).
+        # composite throttles BELOW a single store (seen in ISSUE 13).
         self._pool = ThreadPoolExecutor(
             max_workers=max(8, 4 * len(self._stores)),
             thread_name_prefix="shardcast",

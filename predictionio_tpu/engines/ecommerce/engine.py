@@ -159,6 +159,15 @@ class ECommModel:
         self.item_categories = state["item_categories"]
         self._normed = None
 
+    def with_factors(self, factors: als.ALSFactors, carry=None) -> "ECommModel":
+        """A model around folded factors (online/foldin.py), categories
+        padded out to a grown catalog; no staged state to carry."""
+        cats = self.item_categories
+        n_items = factors.item_factors.shape[0]
+        if cats is not None and len(cats) < n_items:
+            cats = list(cats) + [frozenset()] * (n_items - len(cats))
+        return ECommModel(factors, cats)
+
     def normed_item_factors(self) -> np.ndarray:
         if self._normed is None:
             self._normed = ranking.l2_normalize(self.factors.item_factors)

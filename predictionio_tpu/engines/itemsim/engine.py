@@ -13,7 +13,6 @@ items (multi-item queries rank by total similarity to the basket).
 
 from __future__ import annotations
 
-import threading
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -30,7 +29,8 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.core.base import RuntimeContext
 from predictionio_tpu.data.store.bimap import BiMap
 from predictionio_tpu.data.store.event_store import EventStoreFacade
-from predictionio_tpu.models import dimsum
+from predictionio_tpu.models import als, dimsum
+from predictionio_tpu.models.resident import ResidentServing
 
 
 @dataclass
@@ -118,15 +118,17 @@ class ItemSimModel:
     serve_dtype: str = "f32"
 
     def __post_init__(self):
-        self._stage_lock = threading.Lock()
-
-    def __getstate__(self):
-        state = dict(self.__dict__)
-        # serving state + lock are not part of the pickled model
-        state.pop("_sharded_runtime", None)
-        state.pop("_item_serving", None)
-        state.pop("_stage_lock", None)
-        return state
+        # on-the-fly cosine off resident column vectors: a zero-row user
+        # side, since `similar_items` is the only verb that serves here
+        self.resident = None
+        if self.item_vectors is not None:
+            self.resident = ResidentServing(
+                als.ALSFactors(
+                    np.zeros((0, self.item_vectors.shape[1]), np.float32),
+                    self.item_vectors, None, self.item_vocab,
+                ),
+                self.serve_dtype,
+            )
 
     def __setstate__(self, state):
         # models pickled BEFORE these fields existed must keep loading
@@ -134,55 +136,11 @@ class ItemSimModel:
         state.setdefault("item_vectors", None)
         state.setdefault("serve_dtype", "f32")
         self.__dict__.update(state)
-        self._stage_lock = threading.Lock()
-
-    def sharded_runtime(self):
-        if self.item_vectors is None:
-            return None
-        # locked: concurrent pipelined batches must not double-stage
-        # the sharded vector matrix (same discipline as ALSModel)
-        with self._stage_lock:
-            srt = getattr(self, "_sharded_runtime", None)
-            if srt is False:
-                return None
-            if srt is None:
-                from predictionio_tpu.fleet import stage_serving_runtime
-
-                # no user side: the runtime only serves similar_items
-                self._sharded_runtime = stage_serving_runtime(
-                    np.zeros(
-                        (0, self.item_vectors.shape[1]), np.float32
-                    ),
-                    self.item_vectors,
-                    item_vocab=self.item_vocab,
-                    serve_dtype=self.serve_dtype,
-                )
-                if self._sharded_runtime is False:
-                    return None
-                srt = self._sharded_runtime
-            return srt
-
-    def item_serving(self):
-        """Single-device staged state for the on-the-fly cosine
-        (ISSUE 14): the column vectors stage ONCE (quantized when
-        serve_dtype opts in) and every query runs the fused
-        score+top-k — the per-query numpy (Q, I) cosine matmul and its
-        normalized matrix copy are gone."""
-        if self.item_vectors is None:
-            return None
-        with self._stage_lock:
-            sv = getattr(self, "_item_serving", None)
-            if sv is None:
-                from predictionio_tpu.models import als
-
-                sv = self._item_serving = als.stage_item_serving(
-                    self.item_vectors, serve_dtype=self.serve_dtype
-                )
-            return sv
+        if "resident" not in state:
+            self.__post_init__()
 
     def sharded_info(self):
-        srt = getattr(self, "_sharded_runtime", None)
-        return srt.info() if srt else None
+        return self.resident.info() if self.resident is not None else None
 
 
 class ItemSimAlgorithm(Algorithm):
@@ -232,19 +190,11 @@ class ItemSimAlgorithm(Algorithm):
             # vectors; the per-query numpy cosine matmul is retired) —
             # both truncate to top_n per query item exactly like the
             # precomputed path
-            srt = model.sharded_runtime()
             k = min(model.top_n, n_items)
-            if srt is not None:
-                vals, idx = srt.similar_items(
-                    np.asarray(known, np.int64), k, exclude_self=True
-                )
-            else:
-                from predictionio_tpu.models import als
-
-                vals, idx = als.similar_serving(
-                    model.item_serving(),
-                    np.asarray(known, np.int64), k, exclude_self=True,
-                )
+            vals, idx = model.resident.similar_items(
+                np.asarray(known, np.int64), k, exclude_self=True,
+                shard=True,
+            )
             from predictionio_tpu.ops.topk import NEG_INF
 
             for r in range(len(known)):

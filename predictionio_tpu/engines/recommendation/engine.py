@@ -17,7 +17,6 @@ set (rated >= goal threshold).
 
 from __future__ import annotations
 
-import threading
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -36,6 +35,7 @@ from predictionio_tpu.controller.metrics import OptionAverageMetric
 from predictionio_tpu.core.base import RuntimeContext
 from predictionio_tpu.data.store.event_store import EventStoreFacade
 from predictionio_tpu.models import als
+from predictionio_tpu.models.resident import ResidentServing
 from predictionio_tpu.obs import devprof as _devprof
 from predictionio_tpu.obs import spans as _spans
 
@@ -287,11 +287,9 @@ class ALSModel:
         self.factors = factors
         self.item_categories = item_categories
         self.serve_dtype = serve_dtype
-        self._serving_state = None  # als.ServingFactors when staged
-        self._sharded_runtime = None  # fleet.ShardedRuntime when active
-        self._stage_lock = threading.Lock()
+        self.resident = ResidentServing(factors, serve_dtype)
 
-    # device caches + lock are serving state, not part of the pickled model
+    # the resident serving state is rebuilt on load, never pickled
     def __getstate__(self):
         return {
             "factors": self.factors,
@@ -306,173 +304,46 @@ class ALSModel:
             state.get("serve_dtype", "f32"),
         )
 
-    def serving_state(self):
-        """The staged serving-side factor state (ISSUE 11): pad-aligned
-        for the fused recommend+top-k kernel, int8-quantized when
-        serve_dtype opts in, resident across calls. Staged lazily under
-        the stage lock (pipelined batches must not double-stage)."""
-        with self._stage_lock:
-            if self._serving_state is None:
-                self._check_fits_one_device()
-                self._serving_state = als.stage_serving(
-                    self.factors, serve_dtype=self.serve_dtype
-                )
-            return self._serving_state
+    def with_factors(self, factors: als.ALSFactors, carry=None) -> "ALSModel":
+        """A model around folded factors (online/foldin.py): the same
+        serve dtype — an int8 tenant's fold tick must not silently
+        republish as f32 — and the categories padded out to a grown
+        catalog. `carry` is the tick's `(dirty_users, dirty_items)`,
+        each `(rows, values)` or None: the staged serving state adopts
+        them; None restages lazily."""
+        cats = self.item_categories
+        n_items = factors.item_factors.shape[0]
+        if cats is not None and len(cats) < n_items:
+            cats = list(cats) + [frozenset()] * (n_items - len(cats))
+        new = ALSModel(factors, cats, self.serve_dtype)
+        if carry is not None:
+            new.resident.adopt(self.resident, *carry)
+        return new
 
-    def _check_fits_one_device(self) -> None:
-        """`OversizedModelError`, naming the sharded tier, where the
-        factor state is over one device's budget — PIO_SERVE_HBM_BYTES,
-        else the memory the device reports (a CPU reports none: no
-        gate) — instead of a death in the allocator mid-staging."""
-        import jax
-
-        from predictionio_tpu.fleet import check_single_device_budget
-        from predictionio_tpu.utils.env import env_opt_float
-
-        budget = env_opt_float("PIO_SERVE_HBM_BYTES")
-        if budget is None:
-            stats = jax.devices()[0].memory_stats() or {}
-            budget = stats.get("bytes_limit")
-        if budget is None:
-            return
-        check_single_device_budget(
-            self.factors.user_factors.shape[0],
-            self.factors.item_factors.shape[0],
-            self.factors.user_factors.shape[1],
-            float(budget),
-            serve_dtype=self.serve_dtype,
-        )
-
-    def adopt_serving(self, old_state, dirty_users=None, dirty_items=None):
-        """Fold-in publish hook (online/foldin.py:_clone_model): carry
-        the predecessor's staged serving state by publishing ONLY the
-        tick's dirty rows device-side (quantize-at-fold-in for int8) —
-        copy-on-write off shared buffers, donated into grown private
-        ones — instead of re-staging a factor matrix per tick. Any
-        failure leaves the state unstaged; the next query restages."""
-        if old_state is None:
-            return
-        try:
-            n_users = self.factors.user_factors.shape[0]
-            n_items = self.factors.item_factors.shape[0]
-            ur, uv = dirty_users if dirty_users is not None else (None, None)
-            ir, iv = dirty_items if dirty_items is not None else (None, None)
-            # a side that changed without row attribution cannot be
-            # expressed as row writes — leave unstaged (lazy restage)
-            if dirty_users is None and n_users != old_state.n_users:
-                return
-            if dirty_items is None and n_items != old_state.n_items:
-                return
-            self._serving_state = als.serving_publish_rows(
-                old_state,
-                user_rows=ur, user_vals=uv,
-                item_rows=ir, item_vals=iv,
-                n_users=n_users, n_items=n_items,
-            )
-        except Exception:
-            self._serving_state = None
-
-    def sharded_runtime(self):
-        """The fleet sharded serving state, staged lazily on first use
-        (ISSUE 10) via the shared `fleet.stage_serving_runtime` helper
-        (>= 2 visible devices; PIO_SERVE_HBM_BYTES per-device budget).
-        The single-device outcome is cached as False so the serving hot
-        path doesn't re-probe jax.devices() under the lock per batch."""
-        with self._stage_lock:
-            if self._sharded_runtime is False:
-                return None
-            if self._sharded_runtime is None:
-                from predictionio_tpu.fleet import stage_serving_runtime
-
-                self._sharded_runtime = stage_serving_runtime(
-                    self.factors.user_factors,
-                    self.factors.item_factors,
-                    user_vocab=self.factors.user_vocab,
-                    item_vocab=self.factors.item_vocab,
-                    params=self.factors.params,
-                    # the sharded tier honors the model's serve dtype
-                    # (ISSUE 14): int8/bf16 slabs per shard
-                    serve_dtype=self.serve_dtype,
-                )
-                if self._sharded_runtime is False:
-                    return None
-            return self._sharded_runtime
-
-    def adopt_sharded(self, old_runtime, dirty_users=None, dirty_items=None):
-        """Fold-in publish hook for the SHARDED tier (ISSUE 14,
-        direction-1 item (c)): carry the predecessor's resident sharded
-        state by publishing ONLY the tick's dirty rows through
-        `ShardedRuntime.update_*_rows` — re-quantizing just those rows
-        and donating the slab once in-flight readers drain — instead of
-        re-staging f32 factor matrices per tick. Rows beyond the padded
-        shard extent (vocab growth) leave the state unstaged; the next
-        query rebuilds lazily (the amortized-growth contract)."""
-        if old_runtime is None or old_runtime is False:
-            return
-        # validate BOTH sides BEFORE mutating either: the runtime is
-        # shared in place with the still-serving predecessor, so a
-        # user-side write followed by an item-side growth refusal would
-        # leave the LIVE state half-updated with no rollback
-        for side, dirty in (("user", dirty_users), ("item", dirty_items)):
-            if dirty is not None and not old_runtime.rows_within_extent(
-                side, dirty[0]
-            ):
-                return  # vocab grew past the padded extent: lazy restage
-        try:
-            if dirty_users is not None:
-                ur, uv = dirty_users
-                if len(ur):
-                    old_runtime.update_user_rows(
-                        ur, uv,
-                        # within-pad growth must raise the live extent
-                        # or the grown rows stay masked dead (the
-                        # single-device publish's n_users/n_items twin)
-                        n_users=self.factors.user_factors.shape[0],
-                    )
-            if dirty_items is not None:
-                ir, iv = dirty_items
-                if len(ir):
-                    old_runtime.update_item_rows(
-                        ir, iv,
-                        n_items=self.factors.item_factors.shape[0],
-                    )
-            self._sharded_runtime = old_runtime
-        except Exception:
-            import logging as _logging
-
-            _logging.getLogger(__name__).exception(
-                "sharded dirty-row publish failed mid-carry; the "
-                "runtime may be half-updated — dropping the carry so "
-                "the next query restages from the folded factors"
-            )
-            self._sharded_runtime = None
+    # benchmarks/serving.py:283 frees the staged slabs before its
+    # reference takes the chip by assigning None here; write-only, and
+    # whatever is assigned, the state is dropped (ROADMAP: a benchmark
+    # PR should call `resident.drop()`)
+    _serving_state = property(
+        fset=lambda self, _value: self.resident.drop()
+    )
 
     def sharded_info(self) -> Optional[dict]:
-        """Shard layout for the server's fleet status (None when the
-        sharded tier is not staged)."""
-        srt = self._sharded_runtime
-        return srt.info() if srt else None  # None or the False sentinel
+        return self.resident.info()
 
     def resident_device_bytes(self) -> float:
         """Per-device HBM footprint for the tenant cache's budget
-        (tenancy/cache.py walks to this hook): one SHARD when serving
-        sharded — the whole point of the fleet tier is that no chip
-        holds the catalog — else the factor matrices once (the staged
-        device copies mirror the host arrays 1:1, so counting the
-        host mirrors AND the copies would double-charge)."""
-        srt = self._sharded_runtime
-        if srt:
-            return float(srt.device_bytes()["per_shard"])
-        sv = self._serving_state
-        if sv is not None:
-            # the staged (possibly int8) state is the resident copy —
-            # int8 serving genuinely halves the cache charge
-            return sv.device_nbytes()
+        (tenancy/cache.py walks to this hook): what is staged, else the
+        factor matrices once (the staged device copies mirror the host
+        arrays 1:1, so counting the host mirrors AND the copies would
+        double-charge)."""
+        staged = self.resident.device_bytes()
+        if staged is not None:
+            return staged
         return float(
             self.factors.user_factors.nbytes
             + self.factors.item_factors.nbytes
         )
-
 
 
 class ALSAlgorithm(Algorithm):
@@ -779,28 +650,10 @@ class ALSAlgorithm(Algorithm):
             # count (vocab-known users, not the micro-batch's group size)
             # and the bucket the device program actually ran at
             prof0 = _devprof.snapshot()
-            srt = (
-                model.sharded_runtime()
-                if getattr(self.params, "shard_serving", False)
-                else None
+            scores, items = model.resident.recommend(
+                user_rows, k, exclude_mask=sub_mask, exclude_rows=sub_rows,
+                shard=getattr(self.params, "shard_serving", False),
             )
-            if srt is not None:
-                # fleet sharded path (ISSUE 10): local top-k per shard +
-                # global merge; factor state stays row-sharded in HBM
-                scores, items = srt.recommend(
-                    user_rows, k, exclude_mask=sub_mask,
-                    exclude_rows=sub_rows,
-                )
-            else:
-                # staged serving state (ISSUE 11/14): fused one-pass
-                # kernel where the lowering runs, int8/bf16 when the
-                # params opt in, exclusion as a row list or packed bit
-                # words — never an f32 mask — and resident factor state
-                # either way
-                scores, items = als.recommend_serving(
-                    model.serving_state(), user_rows, k,
-                    exclude_mask=sub_mask, exclude_rows=sub_rows,
-                )
             _devprof.record_batch_padding(
                 n_real, bucket,
                 flops=_devprof.snapshot().flops - prof0.flops,

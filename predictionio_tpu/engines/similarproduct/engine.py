@@ -14,7 +14,6 @@ batched device path lives in models/als.similar_items)."""
 
 from __future__ import annotations
 
-import threading
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -33,6 +32,7 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.core.base import RuntimeContext
 from predictionio_tpu.data.store.event_store import EventStoreFacade
 from predictionio_tpu.models import als, ranking
+from predictionio_tpu.models.resident import ResidentServing
 
 
 @dataclass
@@ -153,11 +153,11 @@ class SimilarModel:
         self.factors = factors
         self.serve_dtype = serve_dtype
         self._normed = None
-        self._serving_state = None  # als.ServingFactors when staged
-        self._sharded_runtime = None  # fleet.ShardedRuntime when active
-        self._stage_lock = threading.Lock()
+        # both sides to the sharded tier, the item side alone to one
+        # chip: the basket cosine never reads a user row
+        self.resident = ResidentServing(factors, serve_dtype, item_only=True)
 
-    # the cache is serving state, not part of the pickled model
+    # the caches are serving state, not part of the pickled model
     def __getstate__(self):
         return {
             "factors": self.factors,
@@ -170,51 +170,19 @@ class SimilarModel:
             state["factors"], state.get("serve_dtype", "f32")
         )
 
+    def with_factors(self, factors: als.ALSFactors, carry=None) -> "SimilarModel":
+        """A model around folded factors (online/foldin.py), same serve
+        dtype. The tick's dirty rows (`carry`) are not published: the
+        one-chip state is item-only, so it restages lazily."""
+        return SimilarModel(factors, self.serve_dtype)
+
     def normed_item_factors(self) -> np.ndarray:
         if self._normed is None:
             self._normed = ranking.l2_normalize(self.factors.item_factors)
         return self._normed
 
-    def serving_state(self):
-        """Staged item-side serving state for the fused basket cosine
-        (ISSUE 14): quantized when serve_dtype opts in, resident
-        across queries. Locked like every other staging."""
-        with self._stage_lock:
-            if self._serving_state is None:
-                self._serving_state = als.stage_item_serving(
-                    self.factors.item_factors,
-                    serve_dtype=self.serve_dtype,
-                )
-            return self._serving_state
-
-    def sharded_runtime(self):
-        """Sharded serving state, staged lazily via the shared
-        `fleet.stage_serving_runtime` helper (same contract as
-        recommendation's ALSModel.sharded_runtime: needs > 1 visible
-        device; PIO_SERVE_HBM_BYTES is the per-device budget; the
-        single-device outcome caches as False). Locked: the pipelined
-        dispatcher can run concurrent batches for one model, and
-        double-staging would transiently double the sharded factor
-        matrices' device footprint."""
-        with self._stage_lock:
-            if self._sharded_runtime is False:
-                return None
-            if self._sharded_runtime is None:
-                from predictionio_tpu.fleet import stage_serving_runtime
-
-                self._sharded_runtime = stage_serving_runtime(
-                    self.factors.user_factors,
-                    self.factors.item_factors,
-                    item_vocab=self.factors.item_vocab,
-                    serve_dtype=self.serve_dtype,
-                )
-                if self._sharded_runtime is False:
-                    return None
-            return self._sharded_runtime
-
     def sharded_info(self):
-        srt = self._sharded_runtime
-        return srt.info() if srt else None
+        return self.resident.info()
 
 
 class _SimilarBase(Algorithm):
@@ -246,11 +214,6 @@ class _SimilarBase(Algorithm):
             return PredictedResult()
         excluded = self._exclusion(model, query, known)
         inv = vocab.inverse()
-        srt = (
-            model.sharded_runtime()
-            if getattr(self.params, "shard_serving", False)
-            else None
-        )
         def basket_result(vals, idx, qnorm):
             # both device routes score the mean of NORMALIZED vectors
             # and divide by the query norm (cosine), so multiply it
@@ -269,28 +232,24 @@ class _SimilarBase(Algorithm):
                 ]
             )
 
-        if srt is not None:
-            # sharded basket cosine (ISSUE 11 satellite): the mean
-            # query vector scores each shard's slab locally; only the
-            # (1, k) candidates ride the ICI merge.
-            q = model.normed_item_factors()[known].mean(axis=0)
-            vals, idx = srt.similar_vectors(
-                q[None, :], query.num, exclude_mask=excluded[None, :]
-            )
-            return basket_result(
-                vals, idx, float(np.linalg.norm(q)) + 1e-9
-            )
+        shard = getattr(self.params, "shard_serving", False)
         serve_dtype = getattr(self.params, "serve_dtype", "f32")
         from predictionio_tpu.ops.recommend_pallas import resolve_mode
 
-        if serve_dtype != "f32" or resolve_mode("auto") is not None:
-            # staged fused basket cosine (ISSUE 14): quantized resident
-            # item factors + one fused score+top-k dispatch; the host
-            # path survives as the exact-f32 CPU default
+        if (
+            model.resident.is_sharded(shard)
+            or serve_dtype != "f32"
+            or resolve_mode("auto") is not None
+        ):
+            # resident basket cosine: the mean query vector scores the
+            # quantized resident item factors in one fused score+top-k
+            # dispatch (sharded: each shard its slab, only the (1, k)
+            # candidates ride the ICI merge); the host path below
+            # survives as the exact-f32 CPU default
             q = model.normed_item_factors()[known].mean(axis=0)
-            vals, idx = als.similar_vectors_serving(
-                model.serving_state(), q[None, :], query.num,
-                exclude_mask=excluded[None, :],
+            vals, idx = model.resident.similar_vectors(
+                q[None, :], query.num, exclude_mask=excluded[None, :],
+                shard=shard,
             )
             return basket_result(
                 vals, idx, float(np.linalg.norm(q)) + 1e-9

@@ -2,7 +2,7 @@
 
 Reference: the ActionML Universal Recommender (external template
 actionml/template-scala-parallel-universal-recommendation — the fork's
-north-star workload, RELEASE.md:3; BASELINE.json configs #5). Its
+north-star workload, RELEASE.md:3). Its
 prerequisites in the fork are all present here: batch events API,
 SelfCleaningDataSource (core/self_cleaning.py), and
 deploy-without-retraining.

@@ -49,35 +49,6 @@ __all__ = [
 ]
 
 
-def stage_serving_runtime(user_factors, item_factors, **kwargs):
-    """Shared lazy staging for the engines' `shard_serving` knobs
-    (recommendation / similarproduct / itemsim): returns a
-    `ShardedRuntime` over the visible devices honoring the
-    PIO_SERVE_HBM_BYTES per-device budget, or ``False`` when fewer
-    than two devices are visible — the sentinel the engine models
-    cache so the serving hot path never re-probes jax.devices().
-    jax imports HERE, never at module import (data-plane discipline)."""
-    import os
-
-    import jax
-
-    if len(jax.devices()) < 2:
-        return False
-    from predictionio_tpu.fleet import runtime as _runtime
-
-    from predictionio_tpu.utils.env import env_opt_float
-
-    return _runtime.ShardedRuntime(
-        user_factors,
-        item_factors,
-        device_budget_bytes=env_opt_float("PIO_SERVE_HBM_BYTES"),
-        **kwargs,
-    )
-
-
-__all__.append("stage_serving_runtime")
-
-
 def bridge_sharded_metrics(registry):
     """Count the sharded tier's batches into `registry` off the span
     `sharded.dispatch` that `ShardedRuntime.recommend` records (the span

@@ -110,8 +110,8 @@ def check_single_device_budget(
     serve_dtype: str = "f32",
 ) -> None:
     """Raise when a SINGLE-device runtime cannot hold this factor
-    state — the gate the sharded tier exists to pass (bench's
-    oversized-catalog proof calls this for the refusal side)."""
+    state — the gate the sharded tier exists to pass
+    (`ResidentServing` runs it before it stages one chip)."""
     need = factor_state_bytes(
         n_users, n_items, rank, SERVE_DTYPE_BYTES[serve_dtype]
     )
@@ -673,15 +673,11 @@ class ShardedRuntime:
         self.serve_dtype = serve_dtype
         uf = np.asarray(user_factors, np.float32)
         itf = np.asarray(item_factors, np.float32)
-        # item rows pad so every shard's slab is tile-divisible for the
-        # fused kernel (ITEM_PAD per shard) — or, on the XLA path, at
-        # least 32-divisible so the packed-mask words column-shard
-        # cleanly (pad rows are zero and die under the per-shard live
-        # count — the usual inertness discipline)
-        quantum = self.n_shards * (
-            _rp.ITEM_PAD if self.serve_mode is not None else 32
+        # item rows pad so every shard's slab is tile-divisible
+        # (ops/recommend_pallas.pad_items owns the rule, both tiers)
+        i_p = _rp.pad_items(
+            itf.shape[0], self.n_shards, fused=self.serve_mode is not None
         )
-        i_p = -(-max(itf.shape[0], 1) // quantum) * quantum
         self.n_users, self.rank = uf.shape
         self.n_items = int(itf.shape[0])
         if device_budget_bytes is not None:
@@ -1033,7 +1029,7 @@ class ShardedRuntime:
         """True when a dirty-row publish for `side` fits the padded
         shard extent — the pre-check a fold-in carry runs on BOTH
         sides BEFORE mutating either, so a grown side can never leave
-        the live runtime half-updated (ALSModel.adopt_sharded)."""
+        the live runtime half-updated (ResidentServing.adopt)."""
         rows = np.asarray(rows, np.int64)
         st = self._state
         table = st.uf if side == "user" else st.itf
@@ -1129,7 +1125,7 @@ class ShardedRuntime:
                     # un-swapped state still references — every further
                     # dispatch against them would crash with an opaque
                     # XLA error. Poison the runtime so leases fail FAST
-                    # and callers restage (adopt_sharded drops the
+                    # and callers restage (ResidentServing.adopt drops the
                     # carry; the predecessor is mid-replacement anyway).
                     self._poisoned = True
                     log.exception(

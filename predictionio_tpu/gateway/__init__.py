@@ -12,7 +12,7 @@ availability layer.
 - **server.py** — `GatewayServer`: the L7 router (health/SLO-aware
   routing, hedged queries at the rolling p95 mark, failover, drain),
 - **autoscale.py** — the closed-loop `Autoscaler` policy + the
-  subprocess ReplicaManager for tests/bench,
+  subprocess ReplicaManager for tests,
 - **replica_main.py** — the replica subprocess entry.
 
 Import discipline: the gateway runs as a data-plane process — this

@@ -6,7 +6,7 @@ engine's burn rate and the gateway's per-replica concurrency every
 evaluation pass and emits **spawn/drain decisions** against a
 :class:`ReplicaManager`. Policy and actuation are deliberately split:
 the in-tree :class:`SubprocessReplicaManager` spawns replica
-subprocesses for tests and the bench harness, a production deployment
+subprocesses for tests, a production deployment
 plugs a k8s/ASG-shaped manager into the same three-method seam —
 either way every decision lands in the bounded decision log and on
 ``gateway_scale_events_total{action}``, so "why did the fleet grow at
@@ -75,7 +75,7 @@ class ReplicaManager:
 
 
 class SubprocessReplicaManager(ReplicaManager):
-    """In-tree manager for tests/bench: replicas are local
+    """In-tree manager for tests: replicas are local
     ``gateway.replica_main`` subprocesses built from an argv template.
     Every ``{n}`` in a template arg is replaced with a per-spawn
     sequence number, so templated ``--replica-id r{n}`` /

@@ -1,9 +1,9 @@
 """Replica subprocess entry (`python -m predictionio_tpu.gateway.replica_main`).
 
-The in-tree replica the SubprocessReplicaManager, the chaos e2e tests,
-and `bench.py --gateway` spawn. Two modes:
+The in-tree replica the SubprocessReplicaManager and the chaos e2e tests
+spawn. Two modes:
 
-- ``--stub`` (tests/bench): serves a deterministic echo engine with an
+- ``--stub`` (tests): serves a deterministic echo engine with an
   optional straggler knob — no storage reads on the query path, no jax
   — so gateway semantics (routing, hedging, failover, drain) are
   measurable without training a model per replica,
@@ -41,7 +41,7 @@ log = logging.getLogger(__name__)
 class _StubAlgo:
     """Echo algorithm: replies with the query, the replica id, and a
     deterministic straggler delay — every `slow_every`-th query sleeps
-    `slow_ms` (the hedging bench's tail source)."""
+    `slow_ms` (the hedging tests' tail source)."""
 
     def __init__(self, replica_id: str, slow_every: int, slow_ms: float):
         self.replica_id = replica_id
@@ -104,7 +104,7 @@ def main(argv: Optional[list[str]] = None) -> int:
     ap.add_argument("--replica-id", default=None,
                     help="explicit identity (overrides --state-dir)")
     ap.add_argument("--stub", action="store_true",
-                    help="serve the echo stub engine (tests/bench)")
+                    help="serve the echo stub engine (tests)")
     ap.add_argument("--slow-every", type=int, default=0,
                     help="stub: every Nth query is a straggler")
     ap.add_argument("--slow-ms", type=float, default=200.0,

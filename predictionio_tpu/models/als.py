@@ -446,8 +446,8 @@ def _train_jit_dense_sharded(
     TPU fori-loop miscompile (batched_cg's docstring) bit at FULL scale
     while small shapes passed. This rig has one chip, so the sharded
     path is validated on CPU meshes + the dryrun only; the first real
-    multi-chip deployment must re-run the bench's full-scale
-    finiteness + windowed-agreement checks before trusting factors."""
+    multi-chip deployment must check full-scale finiteness and
+    agreement with the windowed path before trusting factors."""
     from predictionio_tpu.ops import dense as dense_ops
     from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
@@ -720,8 +720,6 @@ class StagedDenseTrain:
     static_kwargs: dict
     n_users: int
     n_items: int
-    host_prep_sec: float
-    transfer_sec: float
 
     def run(self) -> tuple[jax.Array, jax.Array]:
         if self.static_kwargs.get("mesh") is not None:
@@ -848,7 +846,7 @@ def stage_dense(
         int8_scale,
     )
 
-    with _spans.span("als.stage.host_prep") as prep_sp:
+    with _spans.span("als.stage.host_prep"):
         rows = np.asarray(rows, dtype=np.int32)
         cols = np.asarray(cols, dtype=np.int32)
         vals = np.asarray(vals, dtype=np.float32)
@@ -937,7 +935,7 @@ def stage_dense(
         xfer_sp.attrs["bytes"] = sum(
             a.nbytes for a in coo + rest if a is not None
         )
-    with _spans.span("als.stage.densify") as densify_sp:
+    with _spans.span("als.stage.densify"):
         r = densify(
             *coo, n_rows_p=n_u_p, n_cols_p=n_i_p, dense_dtype=dense_dtype,
             scale=scale,
@@ -969,9 +967,6 @@ def stage_dense(
         ),
         n_users=n_users,
         n_items=n_items,
-        host_prep_sec=prep_sp.duration,
-        # R made resident: the COO transfer and the on-device densify
-        transfer_sec=xfer_sp.duration + densify_sp.duration,
     )
 
 
@@ -1599,16 +1594,12 @@ class StagedWindowedTrain:
     """A windowed-path train with all edge data staged on device.
 
     Built once per training set by `stage_windowed`; `run()` re-executes
-    the compiled alternating loop with no further host→device traffic —
-    the unit bench.py times to report device throughput without host-prep
-    or transfer noise."""
+    the compiled alternating loop with no further host→device traffic."""
 
     device_args: tuple
     static_kwargs: dict
     n_users: int
     n_items: int
-    host_prep_sec: float
-    transfer_sec: float
 
     def run(self) -> tuple[jax.Array, jax.Array]:
         """One full train; returns window-padded device factor arrays."""
@@ -1635,7 +1626,7 @@ def stage_windowed(
     each process stages only its contiguous slice of parts — the
     HBPEvents.scala:84-90 partitioned-read role). Degrees/init factors
     are replicated; mp row-sharding is applied inside the jit."""
-    with _spans.span("als.stage.host_prep") as prep_sp:
+    with _spans.span("als.stage.host_prep"):
         # single gate for both staging sharding and mesh pass-through: a
         # model-parallel-only mesh (dp=1, mp>1) still stages replicated
         # arrays but must reach the jit so mp row-sharding applies (ADVICE r4)
@@ -1755,8 +1746,6 @@ def stage_windowed(
         ),
         n_users=n_users,
         n_items=n_items,
-        host_prep_sec=prep_sp.duration,
-        transfer_sec=xfer_sp.duration,
     )
 
 

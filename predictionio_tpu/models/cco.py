@@ -1,8 +1,7 @@
 """Correlated cross-occurrence (CCO) with log-likelihood-ratio scoring.
 
-The compute core of the Universal Recommender (BASELINE.json configs #5;
-external template actionml/template-scala-parallel-universal-recommendation,
-which delegates to Mahout's SimilarityAnalysis.cooccurrences on Spark).
+The compute core of the Universal Recommender (external template
+actionml/template-scala-parallel-universal-recommendation, which delegates to Mahout's SimilarityAnalysis.cooccurrences on Spark).
 
 TPU-first design: the cross-occurrence count matrix between a primary
 interaction matrix P (users × items) and a secondary indicator matrix S

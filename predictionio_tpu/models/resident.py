@@ -1,0 +1,290 @@
+"""`ResidentServing`: the one owner of a model's device-resident serving
+state — which tier serves it, how that state is staged, how a fold-in
+tick carries it, what it reports and how it is released.
+
+Two tiers hold factor state across queries: one chip's `ServingFactors`
+(models/als.py, the `*_serving` verbs) and, with `shard=True` and two or
+more visible devices, a `fleet.ShardedRuntime` row-sharded over the
+serving mesh. The engines call the three verbs here and keep what is
+theirs: vocab look-ups, exclusion semantics, bucket padding, decode.
+`online/foldin.py` carries the state through `adopt`; the server's fleet
+status and the tenant cache read `info()` and `device_bytes()` through
+the models' one-line hooks.
+
+`shard` is an argument, not a field: the engines read `shard_serving`
+from the deployed algorithm's params at predict time. `fleet.runtime`
+imports lazily (it builds meshes; models that never shard never load
+it).
+"""
+
+from __future__ import annotations
+
+import logging
+import threading
+from typing import Optional
+
+import numpy as np
+
+from predictionio_tpu.models import als
+
+log = logging.getLogger(__name__)
+
+
+class ResidentServing:
+    """Lazily staged serving state for `factors` (an `als.ALSFactors`,
+    or anything with its five fields). `item_only` stages the item side
+    alone on one chip (similarproduct: its verbs never read a user row);
+    the sharded tier always takes both sides. Pickles as its constructor
+    arguments — never the staged state or the lock."""
+
+    def __init__(
+        self, factors, serve_dtype: str = "f32", item_only: bool = False
+    ):
+        self.factors = factors
+        self.serve_dtype = serve_dtype
+        self.item_only = item_only
+        # locked: the pipelined dispatcher runs concurrent batches for
+        # one model, and a double staging would transiently double the
+        # device footprint
+        self._lock = threading.Lock()
+        self._single = None  # als.ServingFactors when staged
+        self._sharded = None  # fleet.ShardedRuntime when staged
+        # the probe's "fewer than two devices" outcome, kept so the hot
+        # path never asks jax.devices() again under the lock
+        self._one_device = False
+
+    def __reduce__(self):
+        return (
+            type(self), (self.factors, self.serve_dtype, self.item_only)
+        )
+
+    # -- staging -------------------------------------------------------------
+    def _runtime(self, shard: bool):
+        """The sharded tier where it serves — `shard` asked for and two
+        or more devices visible, PIO_SERVE_HBM_BYTES the budget of each
+        — staged on first use; else None."""
+        if not shard:
+            return None
+        with self._lock:
+            if self._sharded is None and not self._one_device:
+                import jax
+
+                if len(jax.devices()) < 2:
+                    self._one_device = True
+                else:
+                    from predictionio_tpu.fleet.runtime import ShardedRuntime
+                    from predictionio_tpu.utils.env import env_opt_float
+
+                    self._sharded = ShardedRuntime.from_factors(
+                        self.factors,
+                        device_budget_bytes=env_opt_float(
+                            "PIO_SERVE_HBM_BYTES"
+                        ),
+                        serve_dtype=self.serve_dtype,
+                    )
+            return self._sharded
+
+    def is_sharded(self, shard: bool) -> bool:
+        """Whether the sharded tier serves under `shard` (stages it if
+        so; never stages the one-chip state)."""
+        return self._runtime(shard) is not None
+
+    def get(self, shard: bool = False):
+        """The staged state of the tier that serves: a `ShardedRuntime`,
+        else the one chip's `ServingFactors` — pad-aligned for the fused
+        kernel, quantized when serve_dtype opts in, resident across
+        calls."""
+        srt = self._runtime(shard)
+        if srt is not None:
+            return srt
+        with self._lock:
+            if self._single is None:
+                self._check_fits_one_device()
+                if self.item_only:
+                    self._single = als.stage_item_serving(
+                        self.factors.item_factors,
+                        serve_dtype=self.serve_dtype,
+                    )
+                else:
+                    self._single = als.stage_serving(
+                        self.factors, serve_dtype=self.serve_dtype
+                    )
+            return self._single
+
+    def _check_fits_one_device(self) -> None:
+        """`OversizedModelError`, naming the sharded tier, where the
+        factor state is over one device's budget — PIO_SERVE_HBM_BYTES,
+        else the memory the device reports (a CPU reports none: no
+        gate) — instead of a death in the allocator mid-staging."""
+        import jax
+
+        from predictionio_tpu.utils.env import env_opt_float
+
+        budget = env_opt_float("PIO_SERVE_HBM_BYTES")
+        if budget is None:
+            stats = jax.devices()[0].memory_stats() or {}
+            budget = stats.get("bytes_limit")
+        if budget is None:
+            return
+        from predictionio_tpu.fleet.runtime import check_single_device_budget
+
+        uf, itf = self.factors.user_factors, self.factors.item_factors
+        check_single_device_budget(
+            0 if self.item_only else uf.shape[0],
+            itf.shape[0],
+            uf.shape[1],
+            float(budget),
+            serve_dtype=self.serve_dtype,
+        )
+
+    # -- the three verbs, one signature on either tier -----------------------
+    def recommend(
+        self, rows, k: int, exclude_mask=None, exclude_rows=None,
+        *, shard: bool = False,
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Top-k items per user row. Sharded: local top-k per shard +
+        global merge, factor state row-sharded in HBM. One chip: the
+        fused one-pass kernel where the lowering runs, int8/bf16 when
+        the model opts in. Either way the exclusion ships as a row list
+        or packed bit words — never an f32 mask."""
+        state = self.get(shard)
+        if isinstance(state, als.ServingFactors):
+            return als.recommend_serving(
+                state, rows, k,
+                exclude_mask=exclude_mask, exclude_rows=exclude_rows,
+            )
+        return state.recommend(
+            rows, k, exclude_mask=exclude_mask, exclude_rows=exclude_rows
+        )
+
+    def similar_items(
+        self, rows, k: int, exclude_self: bool = True,
+        *, shard: bool = False,
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Cosine top-k for a batch of item rows off the resident item
+        slab."""
+        state = self.get(shard)
+        if isinstance(state, als.ServingFactors):
+            return als.similar_serving(
+                state, rows, k, exclude_self=exclude_self
+            )
+        return state.similar_items(rows, k, exclude_self=exclude_self)
+
+    def similar_vectors(
+        self, vecs, k: int, exclude_mask=None, *, shard: bool = False
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Cosine top-k against arbitrary f32 query vectors (a basket
+        mean). Sharded: each shard scores its slab, only the (B, k)
+        candidates ride the merge."""
+        state = self.get(shard)
+        if isinstance(state, als.ServingFactors):
+            return als.similar_vectors_serving(
+                state, vecs, k, exclude_mask=exclude_mask
+            )
+        return state.similar_vectors(vecs, k, exclude_mask=exclude_mask)
+
+    # -- fold-in carry -------------------------------------------------------
+    def adopt(
+        self, old: "ResidentServing", dirty_users=None, dirty_items=None
+    ) -> None:
+        """Fold-in publish (online/foldin.py:_clone_model): carry the
+        predecessor's staged state by publishing ONLY the tick's dirty
+        rows — `(rows, f32 values)` a side, None for a side that did
+        not change — instead of re-staging a factor matrix per tick.
+        Any failure leaves the tier unstaged; the next query restages
+        from the folded factors."""
+        self._one_device = old._one_device
+        n_users = self.factors.user_factors.shape[0]
+        n_items = self.factors.item_factors.shape[0]
+        if old._single is not None:
+            self._single = _publish_single(
+                old._single, dirty_users, dirty_items, n_users, n_items
+            )
+        if old._sharded is not None:
+            self._sharded = _publish_sharded(
+                old._sharded, dirty_users, dirty_items, n_users, n_items
+            )
+
+    # -- accounting and release ----------------------------------------------
+    def info(self) -> Optional[dict]:
+        """Shard layout for the server's fleet status (None when the
+        sharded tier is not staged)."""
+        srt = self._sharded
+        return srt.info() if srt is not None else None
+
+    def device_bytes(self) -> Optional[float]:
+        """Per-device bytes of what is staged: one SHARD when serving
+        sharded — the point of that tier is that no chip holds the
+        catalog — else the one chip's staged (possibly int8) state;
+        None when nothing is staged."""
+        srt, sv = self._sharded, self._single
+        if srt is not None:
+            return float(srt.device_bytes()["per_shard"])
+        return sv.device_nbytes() if sv is not None else None
+
+    def drop(self) -> None:
+        """Release both tiers' staged state: the device buffers go when
+        the last in-flight batch lets go of them, and the next query
+        restages."""
+        with self._lock:
+            self._single = self._sharded = None
+
+
+def _publish_single(old_state, dirty_users, dirty_items, n_users, n_items):
+    """Dirty rows into one chip's state, device-side (quantize-at-fold-in
+    for int8): copy-on-write off shared buffers, donated into grown
+    private ones. Returns the successor state, or None to restage."""
+    # a side that changed without row attribution cannot be expressed
+    # as row writes — leave unstaged (lazy restage)
+    if dirty_users is None and n_users != old_state.n_users:
+        return None
+    if dirty_items is None and n_items != old_state.n_items:
+        return None
+    ur, uv = dirty_users or (None, None)
+    ir, iv = dirty_items or (None, None)
+    try:
+        return als.serving_publish_rows(
+            old_state,
+            user_rows=ur, user_vals=uv,
+            item_rows=ir, item_vals=iv,
+            n_users=n_users, n_items=n_items,
+        )
+    except Exception:
+        log.exception(
+            "dirty-row publish into the staged state failed — dropping "
+            "the carry so the next query restages"
+        )
+        return None
+
+
+def _publish_sharded(runtime, dirty_users, dirty_items, n_users, n_items):
+    """Dirty rows into the RESIDENT sharded slabs through
+    `ShardedRuntime.update_*_rows` — re-quantizing just those rows and
+    donating the slab once in-flight readers drain. Rows beyond the
+    padded shard extent (vocab growth) drop the carry; the next query
+    rebuilds lazily (the amortized-growth contract). Returns the runtime,
+    or None to restage."""
+    # validate BOTH sides BEFORE mutating either: the runtime is shared
+    # in place with the still-serving predecessor, so a user-side write
+    # followed by an item-side growth refusal would leave the LIVE state
+    # half-updated with no rollback
+    for side, dirty in (("user", dirty_users), ("item", dirty_items)):
+        if dirty is not None and not runtime.rows_within_extent(
+            side, dirty[0]
+        ):
+            return None
+    try:
+        # within-pad growth must raise the live extent or the grown rows
+        # stay masked dead (the one-chip publish's n_users/n_items twin)
+        if dirty_users is not None and len(dirty_users[0]):
+            runtime.update_user_rows(*dirty_users, n_users=n_users)
+        if dirty_items is not None and len(dirty_items[0]):
+            runtime.update_item_rows(*dirty_items, n_items=n_items)
+        return runtime
+    except Exception:
+        log.exception(
+            "sharded dirty-row publish failed mid-carry; the runtime may "
+            "be half-updated — dropping the carry so the next query "
+            "restages from the folded factors"
+        )
+        return None

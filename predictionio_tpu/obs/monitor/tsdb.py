@@ -733,7 +733,7 @@ def sample_families(tsdb: TSDB, families: Iterable[MetricFamily],
                     now: Optional[float] = None) -> int:
     """Snapshot metric families into the TSDB; returns points written.
     Shared by the in-process sampler (extra_labels None) and anything
-    that wants to stamp a whole registry at once (tests, bench).
+    that wants to stamp a whole registry at once (tests).
 
     Duplicate (name, labels) series within one pass write ONCE (first
     family wins): several servers in a process each mount the same

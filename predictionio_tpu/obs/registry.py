@@ -389,8 +389,8 @@ class MetricsRegistry:
 
     def snapshot(self) -> dict:
         """JSON-able view: counters/gauges → value, histograms → count,
-        sum, mean, p50/p95/p99 per label set. This is what bench.py embeds
-        in BENCH_*.json and what `pio status`/status_html render."""
+        sum, mean, p50/p95/p99 per label set. This is what `pio status`
+        and status_html render."""
         out: dict[str, Any] = {}
         for fam in sorted(self.families(), key=lambda f: f.name):
             rows = []

@@ -82,9 +82,10 @@ def _grown(arr: np.ndarray, n_rows: int, chunk: int) -> np.ndarray:
 
 
 class ALSFoldIn:
-    """Applies dirty-entity batches to an ALS-shaped model (anything with
-    `.factors` carrying user/item factors + vocabs, i.e. the
-    recommendation/similarproduct family's `ALSModel`)."""
+    """Applies dirty-entity batches to an ALS-shaped model: anything with
+    `.factors` carrying user/item factors + vocabs and a
+    `with_factors(factors, carry)` that builds its successor (the
+    recommendation/similarproduct/ecommerce family's models)."""
 
     def __init__(self, config: Optional[FoldInConfig] = None):
         self.config = config or FoldInConfig()
@@ -113,7 +114,7 @@ class ALSFoldIn:
         (duck-typed: no engine imports on this control path)."""
         for i, m in enumerate(getattr(runtime, "models", ()) or ()):
             f = getattr(m, "factors", None)
-            if f is None:
+            if f is None or not hasattr(m, "with_factors"):
                 continue
             if all(
                 hasattr(f, a)
@@ -390,64 +391,20 @@ class ALSFoldIn:
         model, new_factors, items_changed: bool, users_changed: bool = True,
         dirty_users=None, dirty_items=None,
     ):
-        """New model object around the folded factors. The staged
-        serving state carries over through `adopt_serving` (ISSUE 11):
-        the tick's dirty rows publish device-side (COW off shared
-        buffers, donated into grown private ones), so a tick
-        re-transfers its dirty rows, never a factor matrix.
-
-        Fleet (ISSUE 14, direction-1 item (c)): a staged
-        `_sharded_runtime` now carries over the same way — the tick's
-        dirty rows publish into the RESIDENT sharded slabs through
-        `adopt_sharded` → `ShardedRuntime.update_*_rows` (re-quantizing
-        only the dirty rows; the slab donates into the row write once
-        in-flight readers drain), never an f32 restage. A changed side
-        without row attribution — or vocab growth past the padded shard
-        extent — drops the carry and the next query restages lazily."""
-        cls = type(model)
-        cats = getattr(model, "item_categories", None)
-        if cats is not None and len(cats) < new_factors.item_factors.shape[0]:
-            cats = list(cats) + [frozenset()] * (
-                new_factors.item_factors.shape[0] - len(cats)
-            )
-        kwargs = {}
-        if getattr(model, "serve_dtype", None):
-            # a clone must keep the model's serving dtype — an int8
-            # tenant's fold tick must not silently republish as f32
-            kwargs["serve_dtype"] = model.serve_dtype
-        try:
-            new_model = cls(new_factors, item_categories=cats, **kwargs)
-        except TypeError:
-            try:
-                new_model = cls(new_factors, item_categories=cats)
-            except TypeError:
-                new_model = cls(new_factors)
-        # pylint: disable=protected-access
-        # staged serving state (ISSUE 11): publish the tick's dirty rows
-        # into the predecessor's resident state device-side — quantize
-        # only the dirty rows, never a full restage. Carried ONLY when
-        # every changed side has row attribution (a side changed
-        # without rows cannot be expressed as row writes — the clone
-        # restages lazily instead of serving stale factors).
+        """New model around the folded factors: `model.with_factors`,
+        which also carries the model's staged serving state
+        (`ResidentServing.adopt`, either tier) by publishing the tick's
+        dirty rows device-side — a tick re-transfers its dirty rows,
+        never a factor matrix. Carried ONLY when every changed side has
+        row attribution: a side changed without rows cannot be
+        expressed as row writes, and the clone restages lazily instead
+        of serving stale factors."""
         users_safe = not users_changed or dirty_users is not None
         items_safe = not items_changed or dirty_items is not None
-        if hasattr(new_model, "adopt_serving") and users_safe and items_safe:
-            new_model.adopt_serving(
-                getattr(model, "_serving_state", None),
-                dirty_users=dirty_users if users_changed else None,
-                dirty_items=dirty_items if items_changed else None,
+        carry = None
+        if users_safe and items_safe:
+            carry = (
+                dirty_users if users_changed else None,
+                dirty_items if items_changed else None,
             )
-        # sharded tier (ISSUE 14): same dirty-row contract against the
-        # resident sharded slabs — the False "single device" sentinel
-        # and an unstaged None both skip
-        srt = getattr(model, "_sharded_runtime", None)
-        if (
-            srt and hasattr(new_model, "adopt_sharded")
-            and users_safe and items_safe
-        ):
-            new_model.adopt_sharded(
-                srt,
-                dirty_users=dirty_users if users_changed else None,
-                dirty_items=dirty_items if items_changed else None,
-            )
-        return new_model
+        return model.with_factors(new_factors, carry)

@@ -58,8 +58,7 @@ def _dt(dense_dtype: str):
     return jnp.float32 if dense_dtype == "f32" else jnp.bfloat16
 
 
-#: bytes per dense-R cell, by storage mode — the single source the
-#: staging gate and the bench's HBM model both read
+#: bytes per dense-R cell, by storage mode — the staging gate reads it
 BYTES_PER_CELL = {"f32": 4, "bf16": 2, "int8": 1}
 
 

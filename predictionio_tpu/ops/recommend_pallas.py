@@ -130,9 +130,15 @@ def pick_item_tile(n_items_padded: int) -> int:
     return 0
 
 
-def pad_items(n_items: int) -> int:
-    """Padded item-row count the staging side must allocate."""
-    return -(-max(n_items, 1) // ITEM_PAD) * ITEM_PAD
+def pad_items(n_items: int, shards: int = 1, fused: bool = True) -> int:
+    """Padded item-row count the staging side must allocate, on either
+    tier: every shard's slab a multiple of ITEM_PAD where a fused mode
+    resolved, so a tile of the ladder always divides it — or, on the XLA
+    path of the sharded tier, of 32, so the packed-mask words
+    column-shard cleanly. Pad rows are zero and die under the live
+    count."""
+    quantum = shards * (ITEM_PAD if fused else 32)
+    return -(-max(n_items, 1) // quantum) * quantum
 
 
 # ---------------------------------------------------------------------------

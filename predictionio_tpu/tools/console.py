@@ -1607,7 +1607,7 @@ def cmd_gateway(args) -> int:
         if args.autoscale:
             # policy without a manager: decisions are logged + counted
             # (gateway_scale_events_total) for an external actuator to
-            # consume; the subprocess manager is a test/bench tool
+            # consume; the subprocess manager is a test tool
             autoscaler = Autoscaler(None, AutoscalerConfig(
                 min_replicas=args.min_replicas,
                 max_replicas=args.max_replicas,

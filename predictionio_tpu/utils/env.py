@@ -348,17 +348,6 @@ _k("PIO_CAS_SETTLE_MIN_S", "float", 0.02,
 _k("PIO_CAS_SETTLE_MAX_S", "float", 2.0,
    "Ceiling (s) of the adaptive CAS claim settle window.")
 
-# -- bench harness -----------------------------------------------------------
-_k("PIO_BENCH_SCALE", "enum", "",
-   "Set small for the CI-sized bench shapes (100K-scale).")
-_k("PIO_BENCH_HBM_PEAK", "float", None,
-   "HBM roof (bytes/s) override for bench.py; unset = the devprof peak "
-   "table row for the device_kind (no row, no number).")
-_k("PIO_BENCH_PEAK_FLOPS", "float", None,
-   "FLOP/s roof override for bench.py; unset = the devprof peak table "
-   "row for the device_kind (no row, no number).")
-
-
 def knob_registry() -> list[Knob]:
     """Declared knobs, sorted by name (the `pio lint --knobs` view)."""
     return [KNOBS[n] for n in sorted(KNOBS)]

@@ -22,7 +22,7 @@ DEFAULT_CACHE_DIR = os.path.join(_ROOT, ".jax_cache")
 def ensure_compile_cache() -> str:
     """Point jax's persistent compilation cache somewhere stable and
     return the directory in use. Call once per process BEFORE its first
-    compile (train, deploy, the scheduler's train worker, bench.py).
+    compile (train, deploy, the scheduler's train worker).
 
     Where JAX_COMPILATION_CACHE_DIR is set jax reads it itself and this
     sets nothing — the directory is the operator's to place (e.g. in an

@@ -85,7 +85,8 @@ class QueryServerConfig:
     # expires — the old windowed drain could close a bucket at the
     # window bound and then sit blocked on the semaphore while new
     # arrivals queued behind it unbatched. "windowed" restores the
-    # PR-2 adaptive-window behavior (bench.py A/Bs the two under load).
+    # PR-2 adaptive-window behavior (ROADMAP D4: nothing measures the
+    # two against each other any more).
     batching: str = "continuous"
     # adaptive continuous-batching admission (ISSUE 14 satellite,
     # carried serving-kernel follow-up): while a bucket ASSEMBLES in
@@ -1096,7 +1097,7 @@ class _BatchDispatcher:
             #   max_window/1.2×batch-time bound survives only as a
             #   wedged-batch backstop.
             # - windowed: linger up to that bound for more arrivals
-            #   (the PR-2 behavior, kept for the bench A/B). With
+            #   (the PR-2 behavior; ROADMAP D4). With
             #   tenants active, the tenant_drain knob ends the linger
             #   as soon as every still-backlogged tenant is represented
             #   in the bucket — one group per tenant per round beats a

@@ -280,7 +280,7 @@ class UnserializableEngineFactory(EngineFactory):
 # A jax-free engine with a real eval surface: configurable folds, a
 # train_grid hook that stamps how many points shared its device program,
 # and a deterministic score peaked at weight=0.37 so grid winners are
-# known in advance. Evalfleet chaos/parity tests and bench.py use it.
+# known in advance. Evalfleet chaos/parity tests use it.
 
 
 @dataclass
@@ -319,8 +319,7 @@ class GridDataSource(DataSource):
 class GridAP:
     weight: float = 0.0
     # simulated device-program cost: train_grid pays it ONCE for the
-    # whole params group (one program), train() pays it per point —
-    # bench.py's grid-group speedup measures exactly this difference
+    # whole params group (one program), train() pays it per point
     train_cost_s: float = 0.0
 
 

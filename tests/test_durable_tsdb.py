@@ -318,7 +318,7 @@ class TestTierSelection:
         db.stop()
 
     def test_three_day_query_latency(self, tmp_path):
-        """BENCH acceptance shape: p50 of a 3-day increase query must
+        """Acceptance shape: p50 of a 3-day increase query must
         be far under 100ms once tiered."""
         db = _mk(tmp_path, capacity=60)
         now = T0 + 3 * 86400

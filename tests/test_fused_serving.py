@@ -410,15 +410,10 @@ def test_foldin_clone_carries_sharded_runtime():
     rng = np.random.RandomState(35)
     f = _factors(rng, u=40, i=570, k=8)
     model = ALSModel(f, serve_dtype="int8")
-    model.params_shard = True
-    srt = None
-    # stage the sharded runtime through the model's own hook
     from predictionio_tpu.fleet.runtime import ShardedRuntime
 
-    model._sharded_runtime = ShardedRuntime(
-        f.user_factors, f.item_factors, serve_dtype="int8"
-    )
-    srt = model._sharded_runtime
+    srt = model.resident.get(shard=True)
+    assert isinstance(srt, ShardedRuntime)
     solved = rng.standard_normal((2, 8)).astype(np.float32)
     new_uf = f.user_factors.copy()
     new_uf[[1, 2]] = solved
@@ -427,7 +422,7 @@ def test_foldin_clone_carries_sharded_runtime():
         model, nf, items_changed=False,
         dirty_users=([1, 2], solved),
     )
-    assert clone._sharded_runtime is srt
+    assert clone.resident.get(shard=True) is srt
     # the resident runtime serves the folded rows
     ref = ShardedRuntime(
         new_uf, f.item_factors, serve_dtype="int8"
@@ -437,7 +432,7 @@ def test_foldin_clone_carries_sharded_runtime():
     assert np.array_equal(a[1], b[1])
     # a changed side without rows drops the carry
     clone2 = ALSFoldIn._clone_model(model, nf, items_changed=False)
-    assert clone2._sharded_runtime is None
+    assert clone2.resident.info() is None
 
 
 # ---------------------------------------------------------------------------
@@ -508,7 +503,7 @@ def test_itemsim_int8_staged_serving_ranks_sanely():
     got = algo.predict(model, Query(items=["i1"], num=5))
     assert got.item_scores
     assert all(s.item != "i1" for s in got.item_scores)
-    assert model.item_serving().dtype == "int8"
+    assert model.resident.get().dtype == "int8"
 
 
 def test_similarproduct_staged_basket_matches_host_scores():

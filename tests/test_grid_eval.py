@@ -131,13 +131,14 @@ class TestALSGrid:
             )
 
     def test_grid_beats_sequential(self):
-        """Shared staging + one batched program must beat 4 sequential
-        trains. On the CPU test platform the device work dominates and
-        wall-clock is noisy, so the bar here is only 'strictly faster';
-        the real bar lives in bench.py (als_grid_speedup_4pt, TPU): the
-        same 4-point grid at 1M edges measures 4.3x on v5e (grid 2.26s
-        vs 9.76s sequential — VERDICT r3 #6's ≥2x done-bar)."""
+        """What makes the grid cheaper than sequential trains, as
+        behaviour: a 4-point (λ) grid is ONE device program a call — the
+        grid executable's invocation count rises by one across
+        `train_grid`, the single-point programs' by none
+        (`test_grid_matches_sequential` holds the factors equal). How
+        much faster that is: not measured on the chip."""
         from predictionio_tpu.models import als
+        from predictionio_tpu.obs import devprof
 
         rows, cols, vals, nu, ni = self._edges(
             n_users=400, n_items=200, n_edges=40_000
@@ -146,24 +147,28 @@ class TestALSGrid:
             als.ALSParams(rank=8, iterations=4, lambda_=lam)
             for lam in (0.003, 0.01, 0.1, 1.0)
         ]
-        # warm both compile caches so the comparison is run-time only
-        als.train_grid(rows, cols, vals, nu, ni, params_list)
-        als.train(rows, cols, vals, nu, ni, params_list[0])
+        grid_names = ("als.train_dense_grid", "als.train_windowed_grid")
+        single_names = (
+            "als.train_dense", "als.train_windowed", "als.train_edge"
+        )
 
-        t0 = time.perf_counter()
-        als.train_grid(rows, cols, vals, nu, ni, params_list)
-        t_grid = time.perf_counter() - t0
-        t0 = time.perf_counter()
+        def invocations(names):
+            prof = devprof.get_profiler()
+            return sum(
+                (prof.executable(n) or {"invocations": 0})["invocations"]
+                for n in names
+            )
+
+        grid0, single0 = invocations(grid_names), invocations(single_names)
+        grid = als.train_grid(rows, cols, vals, nu, ni, params_list)
+        assert len(grid) == len(params_list)
+        assert invocations(grid_names) - grid0 == 1
+        assert invocations(single_names) - single0 == 0
+        # the sequential way is one program a point
         for p in params_list:
             als.train(rows, cols, vals, nu, ni, p)
-        t_seq = time.perf_counter() - t0
-        # 10% tolerance: strict wall-clock inequality on a shared CI host
-        # is flake-prone (ADVICE r4); the real ≥2x bar is measured on TPU
-        # in bench.py (als_grid_speedup_4pt)
-        assert t_grid < 1.1 * t_seq, (
-            f"grid {t_grid:.3f}s vs sequential {t_seq:.3f}s "
-            f"({t_seq / t_grid:.2f}x)"
-        )
+        assert invocations(single_names) - single0 == len(params_list)
+        assert invocations(grid_names) - grid0 == 1
 
 
 # -- engine-level grid batching ---------------------------------------------

@@ -133,6 +133,27 @@ def test_pick_item_tile_always_divides():
         assert t > 0 and n_p % t == 0
 
 
+@pytest.mark.parametrize("n_items, shards, fused, padded, tile", [
+    (5_700_000, 1, True, 5_700_096, 512),  # serve-steady's catalogue
+    (19_700_000, 4, True, 19_700_224, 128),  # serve-sharded's, a shard's
+    (19_700_000, 4, False, 19_700_096, None),  # XLA path: 32 a shard
+    (0, 1, True, 128, 128),
+    (0, 8, False, 256, None),
+])
+def test_pad_items_is_the_pad_rule_of_both_tiers(
+        n_items, shards, fused, padded, tile):
+    """One function beside the tile ladder says how many item rows either
+    tier stages (`als._stage_arrays`, `ShardedRuntime.__init__`): every
+    shard's slab a multiple of ITEM_PAD where a fused mode resolved, of
+    32 (whole packed-mask words) on the sharded XLA path."""
+    got = pad_items(n_items, shards, fused=fused)
+    assert got == padded and got % shards == 0
+    slab = got // shards
+    assert slab % 32 == 0
+    if fused:
+        assert pick_item_tile(slab) == tile
+
+
 # ---------------------------------------------------------------------------
 # int8 quantized serving
 # ---------------------------------------------------------------------------
@@ -301,7 +322,7 @@ def test_vocab_growth_within_pad_does_not_retrace_serving():
 
 def test_fold_in_clone_carries_serving_state_via_row_publish():
     """online/foldin.py:_clone_model threads dirty rows into
-    ALSModel.adopt_serving: the clone's staged state reflects the fold
+    ResidentServing.adopt: the clone's staged state reflects the fold
     WITHOUT a restage, keeps the serve dtype, and drops the carry when
     a changed side has no row attribution."""
     from predictionio_tpu.engines.recommendation.engine import ALSModel
@@ -310,7 +331,7 @@ def test_fold_in_clone_carries_serving_state_via_row_publish():
     rng = np.random.RandomState(10)
     f = _factors(rng)
     model = ALSModel(f, serve_dtype="int8")
-    sv = model.serving_state()
+    sv = model.resident.get()
     assert sv.dtype == "int8"
     new_uf = f.user_factors.copy()
     solved = rng.standard_normal((2, 10)).astype(np.float32)
@@ -323,9 +344,9 @@ def test_fold_in_clone_carries_serving_state_via_row_publish():
         dirty_users=([1, 2], solved),
     )
     assert clone.serve_dtype == "int8"
-    assert clone._serving_state is not None
+    assert clone.resident.device_bytes() is not None  # carried, staged
     # the clone's staged state serves the folded rows (quantized)
-    v_new, _ = als.recommend_serving(clone._serving_state, [1], 5)
+    v_new, _ = als.recommend_serving(clone.resident.get(), [1], 5)
     v_model = als.recommend_serving(
         als.stage_serving(nf, serve_dtype="int8"), [1], 5
     )[0]
@@ -334,7 +355,7 @@ def test_fold_in_clone_carries_serving_state_via_row_publish():
     clone2 = ALSFoldIn._clone_model(
         model, nf, items_changed=False
     )
-    assert clone2._serving_state is None
+    assert clone2.resident.device_bytes() is None
 
 
 # ---------------------------------------------------------------------------

@@ -223,7 +223,7 @@ def test_recommendation_serve_dtype_int8_end_to_end():
     assert len(out[0].item_scores) == 5
     assert {s.item for s in out[1].item_scores}.isdisjoint({"i0", "i1"})
     # the staged state really is int8 and the cache charge halves
-    sv = model.serving_state()
+    sv = model.resident.get()
     assert sv.dtype == "int8" and str(sv.items.dtype) == "int8"
     f32_bytes = f.user_factors.nbytes + f.item_factors.nbytes
     assert model.resident_device_bytes() < f32_bytes
@@ -415,9 +415,10 @@ def test_itemsim_sharded_model_pickles_without_runtime():
         item_vocab=BiMap({f"i{n}": n for n in range(6)}),
         top_n=3, item_vectors=m,
     )
-    model.sharded_runtime()  # may stage (multi-device) or cache False
+    model.resident.get(shard=True)  # stages a tier (sharded on a mesh)
+    assert model.resident.device_bytes() is not None
     clone = pickle.loads(pickle.dumps(model))
-    assert getattr(clone, "_sharded_runtime", None) is None
+    assert clone.resident.device_bytes() is None  # nothing staged rides
     assert np.array_equal(clone.item_vectors, m)
 
 

@@ -447,8 +447,8 @@ def test_a_state_over_one_devices_budget_names_the_sharded_tier(
     need = (N_USERS + N_ITEMS) * RANK * 4
     monkeypatch.setenv("PIO_SERVE_HBM_BYTES", str(need - 1))
     with pytest.raises(OversizedModelError, match="shard_serving"):
-        ALSModel(fs).serving_state()
+        ALSModel(fs).resident.get()
     # int8 slabs are a quarter of the bytes: the same budget holds them
-    assert ALSModel(fs, serve_dtype="int8").serving_state() is not None
+    assert ALSModel(fs, serve_dtype="int8").resident.get() is not None
     monkeypatch.setenv("PIO_SERVE_HBM_BYTES", str(need))
-    assert ALSModel(fs).serving_state() is not None
+    assert ALSModel(fs).resident.get() is not None

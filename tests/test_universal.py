@@ -297,8 +297,8 @@ class TestDeviceBatchServing:
 
     def test_catalog_scale_qps(self):
         """10^5-item catalog: the batched program must sustain real
-        throughput (measured on the CPU test backend; the JSON-visible
-        bench numbers come from bench.py on the chip)."""
+        throughput (measured on the CPU test backend; on the chip: not
+        measured)."""
         import time
 
         from predictionio_tpu.models import cco
