@@ -140,7 +140,9 @@ class ItemSimModel:
             self.__post_init__()
 
     def sharded_info(self):
-        return self.resident.info() if self.resident is not None else None
+        if self.resident is None:
+            return None
+        return self.resident.sharded_info()
 
 
 class ItemSimAlgorithm(Algorithm):

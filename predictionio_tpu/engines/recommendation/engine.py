@@ -329,7 +329,7 @@ class ALSModel:
     )
 
     def sharded_info(self) -> Optional[dict]:
-        return self.resident.info()
+        return self.resident.sharded_info()
 
     def resident_device_bytes(self) -> float:
         """Per-device HBM footprint for the tenant cache's budget

@@ -182,7 +182,7 @@ class SimilarModel:
         return self._normed
 
     def sharded_info(self):
-        return self.resident.info()
+        return self.resident.sharded_info()
 
 
 class _SimilarBase(Algorithm):

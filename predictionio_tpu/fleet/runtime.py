@@ -35,7 +35,8 @@ ISSUE 14 additions:
 - **fused local pass for every verb**: with a resolved serve_mode the
   shard-local score+select runs ops/recommend_pallas.py's one-pass
   kernel (per-shard live counts ride its traced SMEM scalar; item rows
-  pre-pad to shards × ITEM_PAD so every slab is tile-divisible).
+  pre-pad by `pad_items`, every slab to a multiple of the widest kernel
+  tile the catalogue's size affords).
 - **bit-packed exclusion masks**: the (B, I) bool mask input is gone —
   a dense exclusion ships as (B, I_p/32) packed words column-sharded
   over the mesh (1/32 the f32-equivalent bytes), expanded in registers
@@ -709,7 +710,9 @@ class ShardedRuntime:
         # norms, int8 quantization), then the sharded puts — the item
         # pad rides them — until every slab is resident
         with _spans.span(
-            "sharded.stage", shards=self.n_shards, dtype=serve_dtype
+            "sharded.stage", shards=self.n_shards, dtype=serve_dtype,
+            item_rows_padded=i_p,
+            item_tile=_rp.pick_item_tile(i_p // self.n_shards),
         ):
             with _spans.span("sharded.stage.pad"):
                 # inverse norms (from the f32 rows) serve the cosine
@@ -752,9 +755,10 @@ class ShardedRuntime:
     def _staged_bytes_estimate(self, uf: np.ndarray, itf: np.ndarray) -> int:
         """LOGICAL staged bytes for the budget gate: dtype cells plus
         scale/inverse-norm vectors, excluding the tile-pad quantum —
-        pad waste is bounded by shards × ITEM_PAD rows (noise at the
-        catalog scales the budget gate exists for) and must not refuse
-        a tiny catalog that plainly fits."""
+        pad waste is bounded by `pad_items`: under ITEM_PAD rows a
+        shard, or PAD_WASTE (1.6 %) of a slab where a wider tile was
+        taken (noise at the catalog scales the budget gate exists for)
+        — and must not refuse a tiny catalog that plainly fits."""
         u_p = pad_rows_to_shards(self.n_users, self.n_shards)
         i_p = pad_rows_to_shards(self.n_items, self.n_shards)
         cell = SERVE_DTYPE_BYTES[self.serve_dtype]
@@ -1176,7 +1180,10 @@ class ShardedRuntime:
         }
 
     def info(self) -> dict[str, Any]:
+        from predictionio_tpu.ops.recommend_pallas import pick_item_tile
+
         b = self.device_bytes()
+        i_p = int(self._state.itf.shape[0])
         return {
             "shards": self.n_shards,
             "devices": [
@@ -1187,6 +1194,11 @@ class ShardedRuntime:
             "rank": self.rank,
             "serve_dtype": self.serve_dtype,
             "serve_mode": self.serve_mode or "xla",
+            # the staged item rows, pad included, and the kernel tile
+            # that divides a shard's slab (0 on the XLA path's 32-row
+            # quantum where none does)
+            "item_rows_padded": i_p,
+            "item_tile": pick_item_tile(i_p // self.n_shards),
             "resident_bytes_total": b["total"],
             "resident_bytes_per_shard": b["per_shard"],
         }

@@ -1877,12 +1877,12 @@ class ServingFactors:
     traffic is the (B,) row ids and, when filters apply, the packed
     mask words or exclusion row list).
 
-    `items` is row-padded to `ops.recommend_pallas.ITEM_PAD` so the
-    fused kernel always finds a dividing tile; `n_items` is the live
-    extent (pad rows are masked dead inside the kernel and sliced off
-    on the XLA fallback). dtype "int8" holds BOTH matrices per-row
-    symmetric-quantized with their scale vectors (users (U, 1),
-    items (1, I_p)) — scoring is int8xint8->int32 with the scale outer
+    `items` is row-padded by `ops.recommend_pallas.pad_items`, to the
+    widest kernel tile the catalogue's size affords (`item_tile`);
+    `n_items` is the live extent (pad rows are masked dead inside the
+    kernel and sliced off on the XLA fallback). dtype "int8" holds
+    BOTH matrices per-row symmetric-quantized with their scale vectors
+    (users (U, 1), items (1, I_p)) — scoring is int8xint8->int32 with the scale outer
     product dequantizing in registers; "bf16" (ISSUE 14, the middle
     ground) halves the factor stream with bf16xbf16->f32 scoring and
     no scale vectors. `item_inv_norm` carries the items' f32-row
@@ -1902,6 +1902,13 @@ class ServingFactors:
     @property
     def n_users(self) -> int:
         return int(self.users.shape[0])
+
+    @property
+    def item_tile(self) -> int:
+        """The kernel tile this table's padded rows take."""
+        from predictionio_tpu.ops.recommend_pallas import pick_item_tile
+
+        return pick_item_tile(int(self.items.shape[0]))
 
     def device_nbytes(self) -> float:
         total = float(self.users.nbytes + self.items.nbytes)
@@ -1945,6 +1952,22 @@ def stage_item_serving(
 
 
 def _stage_arrays(
+    uf: np.ndarray, itf: np.ndarray, serve_dtype: str, mode: str
+) -> ServingFactors:
+    """`_stage_unspanned` as the span `als.serve.stage`: the staging
+    until every table is resident, and what the pad rule decided."""
+    with _spans.span("als.serve.stage", dtype=serve_dtype) as sp:
+        staged = _stage_unspanned(uf, itf, serve_dtype, mode)
+        jax.block_until_ready((
+            staged.users, staged.items, staged.user_scale,
+            staged.item_scale, staged.item_inv_norm,
+        ))
+        sp.attrs["item_rows_padded"] = int(staged.items.shape[0])
+        sp.attrs["item_tile"] = staged.item_tile
+        return staged
+
+
+def _stage_unspanned(
     uf: np.ndarray, itf: np.ndarray, serve_dtype: str, mode: str
 ) -> ServingFactors:
     from predictionio_tpu.ops import recommend_pallas as _rp

@@ -8,8 +8,8 @@ more visible devices, a `fleet.ShardedRuntime` row-sharded over the
 serving mesh. The engines call the three verbs here and keep what is
 theirs: vocab look-ups, exclusion semantics, bucket padding, decode.
 `online/foldin.py` carries the state through `adopt`; the server's fleet
-status and the tenant cache read `info()` and `device_bytes()` through
-the models' one-line hooks.
+status and the tenant cache read `sharded_info()` and `device_bytes()`
+through the models' one-line hooks; `info()` is either tier's layout.
 
 `shard` is an argument, not a field: the engines read `shard_serving`
 from the deployed algorithm's params at predict time. `fleet.runtime`
@@ -206,11 +206,33 @@ class ResidentServing:
             )
 
     # -- accounting and release ----------------------------------------------
-    def info(self) -> Optional[dict]:
+    def sharded_info(self) -> Optional[dict]:
         """Shard layout for the server's fleet status (None when the
         sharded tier is not staged)."""
         srt = self._sharded
         return srt.info() if srt is not None else None
+
+    def info(self) -> Optional[dict]:
+        """What is staged, on the tier that serves: the sharded layout,
+        else the one chip's (`shards` 1); None when nothing is staged.
+        Either names the item rows staged, pad included, and the kernel
+        tile they take — what `pad_items` decided from the catalogue's
+        size — so a run can say it without a trace."""
+        srt, sv = self._sharded, self._single
+        if srt is not None:
+            return srt.info()
+        if sv is None:
+            return None
+        return {
+            "shards": 1,
+            "n_users": sv.n_users,
+            "n_items": sv.n_items,
+            "serve_dtype": sv.dtype,
+            "serve_mode": sv.mode or "xla",
+            "item_rows_padded": int(sv.items.shape[0]),
+            "item_tile": sv.item_tile,
+            "resident_bytes_total": sv.device_nbytes(),
+        }
 
     def device_bytes(self) -> Optional[float]:
         """Per-device bytes of what is staged: one SHARD when serving

@@ -105,12 +105,19 @@ import jax.numpy as jnp
 
 from predictionio_tpu.ops.topk import NEG_INF
 
-#: item-tile ladder — first divisor of the padded item count wins; the
-#: staging pad quantum (ITEM_PAD) guarantees at least one always does
+#: item-tile ladder — the widest tile that divides the padded item count
+#: wins (`pick_item_tile`); `pad_items` stages the table so that a wide
+#: one does wherever the catalogue is large enough to afford its pad
 ITEM_TILES = (2048, 1024, 512, 256, 128)
-#: pad item rows to this multiple at staging so a tile always divides
-#: (multiple of 32 so bit-packed mask words always cover whole tiles)
+#: floor of the staging pad quantum: the narrowest tile, which always
+#: qualifies (a multiple of 32, so bit-packed mask words always cover
+#: whole tiles)
 ITEM_PAD = 128
+#: what the staging pad may add to a shard's slab to reach a wider tile:
+#: at most this fraction of its rows (1.6 %). A constant beside the
+#: ladder, not a knob — the kernel's pass over 4.9 M rank-128 rows is
+#: 27 ms at tile 128 and 5.8 at 2,048 (v5e, B = 64, PERF.md PR 29)
+PAD_WASTE = 1 / 64
 
 #: widest (B, E) exclusion row list the kernel unrolls per tile; longer
 #: exclusion sets must ship as bit-packed mask words instead (the
@@ -132,13 +139,27 @@ def pick_item_tile(n_items_padded: int) -> int:
 
 def pad_items(n_items: int, shards: int = 1, fused: bool = True) -> int:
     """Padded item-row count the staging side must allocate, on either
-    tier: every shard's slab a multiple of ITEM_PAD where a fused mode
-    resolved, so a tile of the ladder always divides it — or, on the XLA
-    path of the sharded tier, of 32, so the packed-mask words
+    tier: `shards` equal slabs. Where a fused mode resolved, a slab is a
+    multiple of the WIDEST tile of the ladder whose pad adds at most
+    `PAD_WASTE` of the slab's rows (ITEM_PAD, the narrowest, always
+    qualifies), so the tile follows the catalogue's size and not its
+    remainder modulo 2,048: under 8 k rows a shard the count is the
+    next multiple of 128, 26,744 rows take tile 512, and from 131 k
+    rows a shard every catalogue takes 2,048. On the XLA path of the
+    sharded tier a slab is a multiple of 32, so the packed-mask words
     column-shard cleanly. Pad rows are zero and die under the live
     count."""
-    quantum = shards * (ITEM_PAD if fused else 32)
-    return -(-max(n_items, 1) // quantum) * quantum
+    rows = -(-max(n_items, 1) // shards)
+
+    def slab(quantum: int) -> int:
+        return -(-rows // quantum) * quantum
+
+    if not fused:
+        return slab(32) * shards
+    wide = [
+        slab(t) for t in ITEM_TILES if slab(t) - rows <= rows * PAD_WASTE
+    ]
+    return (wide[0] if wide else slab(ITEM_PAD)) * shards
 
 
 # ---------------------------------------------------------------------------

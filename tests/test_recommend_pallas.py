@@ -19,6 +19,9 @@ import jax.numpy as jnp  # noqa: E402
 from predictionio_tpu.data.store.bimap import BiMap  # noqa: E402
 from predictionio_tpu.models import als  # noqa: E402
 from predictionio_tpu.ops.recommend_pallas import (  # noqa: E402
+    ITEM_PAD,
+    ITEM_TILES,
+    PAD_WASTE,
     fused_recommend_topk,
     pad_items,
     pick_item_tile,
@@ -134,24 +137,61 @@ def test_pick_item_tile_always_divides():
 
 
 @pytest.mark.parametrize("n_items, shards, fused, padded, tile", [
-    (5_700_000, 1, True, 5_700_096, 512),  # serve-steady's catalogue
-    (19_700_000, 4, True, 19_700_224, 128),  # serve-sharded's, a shard's
+    (5_700_000, 1, True, 5_701_632, 2048),  # serve-steady's catalogue
+    (19_700_000, 4, True, 19_701_760, 2048),  # serve-sharded's: 4,925,440 a shard
     (19_700_000, 4, False, 19_700_096, None),  # XLA path: 32 a shard
     (0, 1, True, 128, 128),
     (0, 8, False, 256, None),
+    # small: the next multiple of 128 a shard, as before the rule knew sizes
+    (300, 1, True, 384, 128),
+    (1_321, 4, True, 1_536, 128),  # 384 a shard: alive on every shard
+    (8_000, 1, True, 8_064, 128),
+    # in between the tile rises with the size
+    (26_744, 1, True, 27_136, 512),  # ML-20M's catalogue
+    (26_744, 4, True, 27_136, 128),  # 6,784 a shard
+    (100_000, 1, True, 100_352, 2048),  # the UR catalogue
+    (131_073, 1, True, 133_120, 2048),  # from here every size takes 2,048
 ])
 def test_pad_items_is_the_pad_rule_of_both_tiers(
         n_items, shards, fused, padded, tile):
     """One function beside the tile ladder says how many item rows either
     tier stages (`als._stage_arrays`, `ShardedRuntime.__init__`): every
-    shard's slab a multiple of ITEM_PAD where a fused mode resolved, of
-    32 (whole packed-mask words) on the sharded XLA path."""
+    shard's slab a multiple of the widest tile whose pad is at most
+    PAD_WASTE of its rows where a fused mode resolved, of 32 (whole
+    packed-mask words) on the sharded XLA path."""
     got = pad_items(n_items, shards, fused=fused)
     assert got == padded and got % shards == 0
     slab = got // shards
     assert slab % 32 == 0
     if fused:
         assert pick_item_tile(slab) == tile
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4, 8])
+def test_pad_items_holds_its_bounds_over_a_sweep_of_sizes(shards):
+    """A tile divides every slab; a slab is whole packed-mask words; the
+    pad is under ITEM_PAD rows a shard or within PAD_WASTE of the slab's
+    rows; the count never falls below the old rule's (the next multiple
+    of shards x ITEM_PAD) nor as the catalogue grows; and a shard is
+    never all pad where the old rule left it live rows."""
+    rng = np.random.RandomState(shards)
+    sizes = np.unique(np.concatenate([
+        np.arange(0, 600), 2 ** np.arange(7, 25), 2 ** np.arange(7, 25) + 1,
+        (10 ** rng.uniform(2, 7.5, 2000)).astype(np.int64),
+    ]))
+    before = 0
+    for n in map(int, sizes):
+        got = pad_items(n, shards)
+        slab, rows = got // shards, -(-max(n, 1) // shards)
+        old = -(-max(n, 1) // (shards * ITEM_PAD)) * shards * ITEM_PAD
+        assert got % shards == 0 and slab % 32 == 0
+        assert pick_item_tile(slab) in ITEM_TILES
+        assert slab - rows < ITEM_PAD or slab - rows <= rows * PAD_WASTE
+        assert got >= old and got >= before
+        # the last shard keeps live rows wherever the old rule's did
+        if n > old - old // shards:
+            assert n > got - slab
+        before = got
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import pickle
+import time
 import weakref
 
 import jax
@@ -138,7 +139,9 @@ def test_shard_on_one_visible_device_serves_one_chip_and_probes_once(
     resident = ResidentServing(_factors())
     state = resident.get(shard=True)
     assert isinstance(state, als.ServingFactors)
-    assert not resident.is_sharded(True) and resident.info() is None
+    assert not resident.is_sharded(True)
+    # the fleet status sees no sharded tier; the layout is the one chip's
+    assert resident.sharded_info() is None and resident.info()["shards"] == 1
     asked = len(probes)
     assert asked >= 1
     v, ix = resident.recommend([1, 2], 5, shard=True)
@@ -146,6 +149,33 @@ def test_shard_on_one_visible_device_serves_one_chip_and_probes_once(
     assert len(probes) == asked  # the outcome is kept, not asked again
     ref = als.recommend_serving(als.stage_serving(_factors()), [1, 2], 5)
     np.testing.assert_array_equal(ix, ref[1])
+
+
+@pytest.mark.parametrize("shard", [False, True])
+def test_info_and_the_staging_span_name_the_rows_staged_and_their_tile(
+        shard, mesh_devices):
+    """What `pad_items` decided from the catalogue's size is on the
+    staging span and in `info()` on either tier (ISSUE 31): 300 rows pad
+    to 384 on one chip, tile 128 at this size (2,048 from 131 k rows a
+    shard); on the mesh no kernel mode resolves on a CPU, so the 38 rows
+    a shard pad to the XLA path's 64 and no tile is named."""
+    from predictionio_tpu.obs import spans
+
+    t0 = time.time()
+    resident = ResidentServing(_factors())
+    assert resident.info() is None  # nothing staged
+    resident.get(shard=shard)
+    shards = mesh_devices if shard else 1
+    info = resident.info()
+    assert info["shards"] == shards and info["n_items"] == N_ITEMS
+    assert info["item_rows_padded"] == (shards * 64 if shard else 384)
+    assert info["item_tile"] == (0 if shard else 128)
+    assert (resident.sharded_info() is not None) == shard
+    name = "sharded.stage" if shard else "als.serve.stage"
+    (span,) = [s for s in spans.get_default_recorder().recent(t0)
+               if s.name == name]
+    assert span.attrs["item_rows_padded"] == info["item_rows_padded"]
+    assert span.attrs["item_tile"] == info["item_tile"]
 
 
 def test_shard_on_a_mesh_stages_the_sharded_tier_only(mesh_devices):

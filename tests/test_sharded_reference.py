@@ -235,6 +235,101 @@ def test_a_row_list_crosses_as_ids_and_answers_as_words_and_one_chip_do(
             assert got["score_gap"] < 1e-5 and got["rank_gap"] < 1e-5
 
 
+#: a catalogue large enough that `pad_items` takes a tile above 128
+#: (ISSUE 31): 16,498 rows a shard pad to 65 tiles of 256, 16,640, where
+#: the next multiple of 128 was 16,512 — every shard boundary moves, and
+#: the last shard holds 16,070 live rows under 570 of pad
+WIDE_ITEMS, WIDE_SLAB, WIDE_RANK = 65_990, 16_640, 8
+WIDE_EDGES = [s * WIDE_SLAB for s in range(1, SHARDS)]
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """Tables whose answers the new boundaries decide: every item entry is
+    positive, so user 0 (all positive) is served the largest rows and user
+    1 (all negative) the smallest, every one of its live scores under the
+    0 a pad row would score; the rows on each side of every shard boundary
+    and the last live row are the largest, the next ones out the smallest.
+    Users 2.. are plain."""
+    from predictionio_tpu.fleet import ShardedRuntime
+
+    rng = np.random.default_rng(31)
+    scale = np.float32(1.0 / np.sqrt(WIDE_RANK))
+    uf = rng.standard_normal((64, WIDE_RANK), dtype=np.float32) * scale
+    uf[0], uf[1] = np.abs(uf[0]), -np.abs(uf[1])
+    itf = np.abs(
+        rng.standard_normal((WIDE_ITEMS, WIDE_RANK), dtype=np.float32)
+    ) * scale
+    large = [r for e in WIDE_EDGES for r in (e - 1, e)] + [WIDE_ITEMS - 1]
+    small = [r for e in WIDE_EDGES for r in (e - 2, e + 1)] + [WIDE_ITEMS - 2]
+    itf[large] = 4.0 * scale * np.linspace(1.0, 1.3, len(large))[:, None]
+    itf[small] = 0.01 * scale * np.linspace(1.0, 1.3, len(small))[:, None]
+    srt = ShardedRuntime(
+        uf, itf, mesh=serving_mesh(SHARDS), serve_mode="interpret")
+    return uf, itf, srt, large, small
+
+
+def test_the_pad_rule_takes_a_wider_tile_and_moves_the_boundaries(wide):
+    from predictionio_tpu.ops import recommend_pallas as rp
+
+    uf, itf, srt, large, small = wide
+    info = srt.info()
+    assert info["item_rows_padded"] == SHARDS * WIDE_SLAB
+    assert info["item_tile"] == 256 == rp.pick_item_tile(WIDE_SLAB)
+    assert int(srt._state.itf.shape[0]) == SHARDS * WIDE_SLAB
+    # unfiltered, the planted rows ARE the answers: the test below decides
+    # something. User 1's live scores are all negative; a pad row scores 0
+    scores, items = srt.recommend(np.array([0, 1]), 7)
+    assert sorted(items[0].tolist()) == sorted(large)
+    assert sorted(items[1].tolist()) == sorted(small)
+    assert (scores[1] < 0).all()
+
+
+@pytest.mark.parametrize("form", ["rows", "rows64", "words", "mask"])
+@pytest.mark.parametrize("batch", [1, 8, 64])
+def test_blacklists_across_the_new_boundaries_equal_the_unsharded_answer(
+        wide, monkeypatch, batch, form):
+    """Blacklisted ids on each side of every new shard boundary, in the
+    last live row and in the pad, in each wire form: the answers are the
+    one-chip tier's and the reference's, and no pad row is ever served —
+    not to the user whose every live score is below a pad row's 0."""
+    uf, itf, srt, large, small = wide
+    i_p = SHARDS * WIDE_SLAB
+    fs = als.ALSFactors(uf, itf, None, None, als.ALSParams(rank=WIDE_RANK))
+    one_chip = als.stage_serving(fs, mode="off")
+    pad_ids = [WIDE_ITEMS, i_p - 1]  # dead rows of the last shard: inert
+    users = np.arange(max(batch, 4))
+    black = [large + pad_ids[:1], small + pad_ids[1:]] + [
+        (large[n % 7:] + small[: n % 5])[:8] if n % 3 else []
+        for n in range(2, len(users))]
+    packed = spy_on_pack_rows(monkeypatch, srt)
+    for lo in range(0, len(users), batch):
+        rows, lists = users[lo: lo + batch], black[lo: lo + batch]
+        if form == "mask":
+            mask = np.zeros((len(rows), WIDE_ITEMS), bool)
+            for m, ids in zip(mask, lists):
+                m[[r for r in ids if r < WIDE_ITEMS]] = True
+            scores, items = srt.recommend(rows, 10, exclude_mask=mask)
+        else:
+            width = {"rows": 8, "rows64": 64, "words": 65}[form]
+            scores, items = srt.recommend(
+                rows, 10, exclude_rows=rowlist(lists, width))
+            assert bool(packed) == (form == "words")  # ids, but past 64
+            packed.clear()
+        o_scores, o_items = als.recommend_serving(
+            one_chip, rows, 10, exclude_rows=rowlist(lists))
+        np.testing.assert_array_equal(items, o_items)
+        np.testing.assert_allclose(scores, o_scores, rtol=1e-5, atol=1e-6)
+        for row, b in zip(items, lists):
+            assert len(set(row.tolist())) == 10
+            assert 0 <= row.min() and row.max() < WIDE_ITEMS  # never a pad row
+            assert not set(row.tolist()) & set(b)
+        got = gaps(rows, items, scores, lists, 10, uf, itf)
+        for name, limit in LIMITS.items():
+            assert got[name] <= limit, (name, got)
+        assert got["score_gap"] < 1e-5 and got["rank_gap"] < 1e-5
+
+
 def test_sharded_runtime_equals_the_reference_under_a_dense_mask(
         runtime, tables):
     """The mask form (a whitelist's complement) packs to the same words."""
@@ -433,6 +528,11 @@ def test_staging_records_the_stage_spans(tables):
     assert spans["sharded.stage.transfer"].attrs["bytes"] == \
         srt.device_bytes()["total"]
     assert stage.attrs["shards"] == SHARDS
+    # what the pad rule decided rides the span and `info()` (ISSUE 31)
+    info = srt.info()
+    assert stage.attrs["item_rows_padded"] == info["item_rows_padded"] == \
+        int(srt._state.itf.shape[0])
+    assert stage.attrs["item_tile"] == info["item_tile"]
 
 
 def test_a_state_over_one_devices_budget_names_the_sharded_tier(

@@ -39,7 +39,7 @@ from predictionio_tpu.ops import recommend_pallas as rp  # noqa: E402
 from predictionio_tpu.ops import windowed_pallas  # noqa: E402
 from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS  # noqa: E402
 
-#: ML-20M's padded serving catalog: 209 tiles of 128 (multi-tile)
+#: ML-20M's padded serving catalog: 53 tiles of 512 (multi-tile)
 I_P, RANK, TOPK = rp.pad_items(26_744), 10, 128
 
 
@@ -97,9 +97,32 @@ def test_fused_recommend_compiles(v5e, dtype, mask_kind, batch):
     ).compile()
 
 
+@pytest.mark.parametrize("batch", [1, 8, 64])
+@pytest.mark.parametrize("form", ["none", "rows8", "rows64", "bits"])
+@pytest.mark.parametrize("dtype", ["f32", "int8"])
+def test_fused_recommend_compiles_at_the_widest_tile(v5e, dtype, form, batch):
+    """Rank 128 over one shard's slab of the 19.7 M catalogue as
+    `pad_items` stages it since ISSUE 31: 2,405 tiles of 2,048 rows, a
+    1 MB f32 factor tile and a 512 KB (64, 2048) score tile a step."""
+    i_p = rp.pad_items(19_700_000, 4) // 4
+    assert rp.pick_item_tile(i_p) == 2048
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    dt = jnp.int8 if dtype == "int8" else jnp.float32
+    scales = (None, None)
+    if dtype == "int8":
+        scales = (sds((batch, 1), jnp.float32), sds((1, i_p), jnp.float32))
+    bits = sds((batch, i_p // 32), jnp.int32) if form == "bits" else None
+    rows = (sds((batch, int(form[4:])), jnp.int32)
+            if form.startswith("rows") else None)
+    rp.fused_recommend_topk.lower(
+        sds((batch, 128), dt), sds((i_p, 128), dt), *scales, bits, rows,
+        k=TOPK, n_items=sds((), jnp.int32),
+    ).compile()
+
+
 @pytest.mark.parametrize("mask_kind", ["bits", "rows"])
 def test_fused_masked_topk_compiles_at_ur_catalog(v5e, mask_kind):
-    """The CCO/universal tail at the 10^5-item catalog (tile 256)."""
+    """The CCO/universal tail at the 10^5-item catalog (tile 2,048)."""
     sds = _on(SingleDeviceSharding(v5e[0]))
     i_p = rp.pad_items(100_000)
     bits = sds((8, i_p // 32), jnp.int32) if mask_kind == "bits" else None
@@ -119,8 +142,8 @@ def test_sharded_recommend_compiles_on_four_shards(v5e, dtype, mask_kind):
     rows_sh = _on(NamedSharding(mesh, P(MODEL_AXIS, None)))
     cols_sh = _on(NamedSharding(mesh, P(None, MODEL_AXIS)))
     rep = _on(NamedSharding(mesh, P()))
-    # per-shard slab of ITEM_PAD-aligned rows, as ShardedRuntime stages
-    i_p = -(-26_744 // (4 * rp.ITEM_PAD)) * 4 * rp.ITEM_PAD
+    # as ShardedRuntime stages it: 6,784 rows a shard, 53 tiles of 128
+    i_p = rp.pad_items(26_744, 4)
     u_p = 138_496
     dt = jnp.int8 if dtype == "int8" else jnp.float32
     scales = (None, None)
