@@ -190,10 +190,9 @@ def _half_step_windowed(
 # Core solver — dense-W fast path (sub-1%-density rating matrices)
 # ---------------------------------------------------------------------------
 
-# auto-dispatch bound for the bf16 dense rating matrix (ML-20M needs
-# 7.45 GB of a 16 GB chip); PIO_DENSE_ALS=0 disables, =1 forces where it
-# fits, PIO_DENSE_ALS_BYTES overrides the budget
-DENSE_DEFAULT_BYTES = 9_000_000_000
+# auto-dispatch bound for the dense rating matrix: PIO_DENSE_ALS_BYTES,
+# whose registered default (utils/env.py) is 9e9 (ML-20M needs 7.45 GB of
+# a 16 GB chip in bf16); PIO_DENSE_ALS=0 disables, =1 forces where it fits
 # below this edge count the windowed path's staging is already cheap and
 # CPU test suites compare against f32-exact references — auto keeps them
 # on the windowed path unless PIO_DENSE_ALS=1 opts in
@@ -758,6 +757,64 @@ def dense_matrix_bytes(
     return n_u_p * n_i_p * BYTES_PER_CELL.get(dense_dtype, 2)
 
 
+# pairs counted per np.bincount call in _degrees
+_DEGREE_CHUNK = 1 << 20
+
+
+def _degrees(rows, cols, n_users: int, n_items: int):
+    """(user_deg, item_deg): how many pairs name each user and each item,
+    as float32 vectors of the table sizes — one `np.bincount` a side, in
+    chunks so the intp copy bincount makes of int32 ids stays in cache.
+    Exact below 2**24 pairs an id; past that float32 rounds the true
+    count, where accumulating 1.0 in float32 would stick at 16,777,216.
+    An id outside its table raises, as indexing the vector would."""
+    out = []
+    for ids, n in ((rows, n_users), (cols, n_items)):
+        ids = np.asarray(ids)
+        deg = np.zeros(n, np.int64)
+        for i in range(0, len(ids), _DEGREE_CHUNK):
+            part = np.bincount(ids[i : i + _DEGREE_CHUNK], minlength=n)
+            if len(part) != n:
+                raise IndexError(
+                    f"index {len(part) - 1} is out of bounds for a table "
+                    f"of {n} rows"
+                )
+            deg += part
+        out.append(deg.astype(np.float32))
+    return tuple(out)
+
+
+@dataclass(frozen=True)
+class DenseGate:
+    """`dense_eligible`'s answer and what it learned on the way, so that
+    `stage_dense` does not scan the ratings for their int8 scale again.
+    Truthy when the dense path may run."""
+
+    #: "eligible", or the condition that refused: env_off, rank,
+    #: multi_process, few_edges, bytes, explicit_zero, duplicate_pairs
+    verdict: str
+    #: storage dtype the byte budget was reckoned with; None when an
+    #: earlier condition refused
+    dense_dtype: Optional[str] = None
+    #: True when `int8_scale` holds `ops.dense.int8_scale(vals)` (None
+    #: there means "not exactly quantizable")
+    scale_known: bool = False
+    int8_scale: Optional[float] = None
+
+    def __bool__(self) -> bool:
+        return self.verdict == "eligible"
+
+
+def _unique_pairs(rows, cols, n_items: int) -> bool:
+    """No (user, item) pair occurs twice: one int64 key a pair, built and
+    sorted in ONE buffer, then a compare of neighbours."""
+    key = rows.astype(np.int64)
+    key *= n_items
+    key += cols
+    key.sort()
+    return not bool((key[1:] == key[:-1]).any())
+
+
 def dense_eligible(
     rows: np.ndarray,
     cols: np.ndarray,
@@ -767,8 +824,8 @@ def dense_eligible(
     params: "ALSParams",
     mesh=None,
     dense_dtype: str = "bf16",
-) -> bool:
-    """Gate for the dense-W fast path.
+) -> DenseGate:
+    """Gate for the dense-W fast path; a pure host predicate.
 
     Requires: env not opting out, rank within the gram-solver bound,
     single-process execution when a mesh is given (the shard_map'd dense
@@ -780,42 +837,59 @@ def dense_eligible(
     — no zero-valued ratings (a dense zero must mean "unobserved").
     Auto mode also requires DENSE_AUTO_MIN_EDGES so small (test-scale)
     trains keep their f32-exact windowed numerics unless PIO_DENSE_ALS=1
-    opts in."""
-    with _spans.span("als.train.dense_eligible"):
-        env = env_str("PIO_DENSE_ALS").strip()
-        if env == "0":
-            return False
-        if params.rank > GRAM_SOLVER_MAX_RANK:
-            return False
-        if mesh is not None and jax.process_count() > 1:
-            return False
-        if env != "1" and len(rows) < DENSE_AUTO_MIN_EDGES:
-            return False
-        budget = env_int("PIO_DENSE_ALS_BYTES", DENSE_DEFAULT_BYTES)
-        if dense_dtype == "bf16":  # the default: predict what auto picks
-            from predictionio_tpu.ops.dense import int8_scale
+    opts in. The conditions are tested cheapest first: the pair keys,
+    the one sort of the job, are built only when nothing else refused."""
+    with _spans.span("als.train.dense_eligible") as sp:
+        gate = _dense_gate(
+            rows, cols, vals, n_users, n_items, params, mesh, dense_dtype
+        )
+        sp.attrs.update(
+            pairs=len(rows), verdict=gate.verdict,
+            dense_dtype=gate.dense_dtype, int8_scale=gate.int8_scale,
+        )
+    if gate.verdict == "duplicate_pairs":
+        logging.getLogger(__name__).info(
+            "dense ALS path skipped: duplicate (user, item) pairs"
+        )
+    return gate
 
-            if int8_scale(vals) is not None:
-                dense_dtype = "int8"
-        dp = mp = 1
-        if mesh is not None and getattr(mesh, "devices", None) is not None:
-            from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
-            dp = int(mesh.shape.get(DATA_AXIS, 1))
-            mp = int(mesh.shape.get(MODEL_AXIS, 1))
-        if dense_matrix_bytes(
-            n_users, n_items, dense_dtype, dp=dp, mp=mp
-        ) > budget:
-            return False
-        if not params.implicit_prefs and np.any(vals == 0.0):
-            return False
-        key = rows.astype(np.int64) * np.int64(n_items) + cols.astype(np.int64)
-        if np.unique(key).size != len(key):
-            logging.getLogger(__name__).info(
-                "dense ALS path skipped: duplicate (user, item) pairs"
-            )
-            return False
-        return True
+def _dense_gate(
+    rows, cols, vals, n_users, n_items, params, mesh, dense_dtype
+) -> DenseGate:
+    env = env_str("PIO_DENSE_ALS").strip()
+    if env == "0":
+        return DenseGate("env_off")
+    if params.rank > GRAM_SOLVER_MAX_RANK:
+        return DenseGate("rank")
+    if mesh is not None and jax.process_count() > 1:
+        return DenseGate("multi_process")
+    if env != "1" and len(rows) < DENSE_AUTO_MIN_EDGES:
+        return DenseGate("few_edges")
+    known = dict(dense_dtype=dense_dtype)
+    if dense_dtype == "bf16":  # the default: predict what auto picks
+        from predictionio_tpu.ops.dense import int8_scale
+
+        s_q = int8_scale(vals)
+        known = dict(
+            dense_dtype="bf16" if s_q is None else "int8",
+            scale_known=True, int8_scale=s_q,
+        )
+    dp = mp = 1
+    if mesh is not None and getattr(mesh, "devices", None) is not None:
+        from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+        dp = int(mesh.shape.get(DATA_AXIS, 1))
+        mp = int(mesh.shape.get(MODEL_AXIS, 1))
+    if dense_matrix_bytes(
+        n_users, n_items, known["dense_dtype"], dp=dp, mp=mp
+    ) > env_int("PIO_DENSE_ALS_BYTES"):
+        return DenseGate("bytes", **known)
+    if not params.implicit_prefs and np.any(vals == 0.0):
+        return DenseGate("explicit_zero", **known)
+    if not _unique_pairs(rows, cols, n_items):
+        return DenseGate("duplicate_pairs", **known)
+    return DenseGate("eligible", **known)
 
 
 def _dense_pallas_mode():
@@ -829,10 +903,15 @@ def stage_dense(
     user_deg=None, item_deg=None, init_factors=None,
     dense_dtype: str = "auto",
     mesh=None,
+    gate: Optional[DenseGate] = None,
 ) -> StagedDenseTrain:
     """Stage the dense-path train: pad dims to the block quanta, push the
     COO arrays once, densify ON DEVICE (the matrix never crosses the
     host link), and keep it resident.
+
+    `gate` is `dense_eligible`'s answer for these same ratings, and
+    `user_deg` / `item_deg` their degrees: what the caller has already
+    computed is taken over, what it has not is computed here.
 
     dense_dtype "auto" prefers int8 storage when every rating is exactly
     representable as round(r·s) for a small scale s (ML-style ratings
@@ -846,13 +925,15 @@ def stage_dense(
         int8_scale,
     )
 
-    with _spans.span("als.stage.host_prep"):
+    with _spans.span("als.stage.host_prep") as prep_sp:
         rows = np.asarray(rows, dtype=np.int32)
         cols = np.asarray(cols, dtype=np.int32)
         vals = np.asarray(vals, dtype=np.float32)
         scale = 1.0
+        scale_reused = False
         if dense_dtype in ("auto", "int8"):
-            s_q = int8_scale(vals)
+            scale_reused = gate is not None and gate.scale_known
+            s_q = gate.int8_scale if scale_reused else int8_scale(vals)
             if s_q is not None:
                 dense_dtype, scale = "int8", s_q
             elif dense_dtype == "int8":
@@ -873,12 +954,12 @@ def stage_dense(
         # pad likewise so every mp device owns whole COL_PAD column blocks
         n_u_p = -(-n_users // (ROW_BLOCK * dp)) * (ROW_BLOCK * dp)
         n_i_p = -(-n_items // (COL_PAD * mp)) * (COL_PAD * mp)
-        if user_deg is None:
-            user_deg = np.zeros(n_users, np.float32)
-            np.add.at(user_deg, rows, 1.0)
-        if item_deg is None:
-            item_deg = np.zeros(n_items, np.float32)
-            np.add.at(item_deg, cols, 1.0)
+        prep_sp.attrs.update(
+            int8_scale_reused=scale_reused,
+            degrees_reused=user_deg is not None and item_deg is not None,
+        )
+        if user_deg is None or item_deg is None:
+            user_deg, item_deg = _degrees(rows, cols, n_users, n_items)
 
         def pad_deg(deg, n_padded):
             out = np.full(n_padded, -1.0, np.float32)  # -1 marks padding
@@ -975,11 +1056,12 @@ def _train_dense(
     user_deg, item_deg, user_vocab, item_vocab, init_factors,
     dense_dtype: str = "auto",
     mesh=None,
+    gate: Optional[DenseGate] = None,
 ) -> "ALSFactors":
     staged = stage_dense(
         rows, cols, vals, n_users, n_items, params,
         user_deg=user_deg, item_deg=item_deg, init_factors=init_factors,
-        dense_dtype=dense_dtype, mesh=mesh,
+        dense_dtype=dense_dtype, mesh=mesh, gate=gate,
     )
     uf, itf = staged.factors(*staged.run())
     return ALSFactors(
@@ -1284,19 +1366,19 @@ def train_grid(
     # data-dependent eligibility (pair uniqueness, quantization, budget)
     # is identical for every group — check once against base, then only
     # the cheap per-group condition (explicit mode forbids zero ratings)
-    use_dense = dense_eligible(rows, cols, vals, n_users, n_items, base)
+    gate = dense_eligible(rows, cols, vals, n_users, n_items, base)
+    use_dense = bool(gate)
     if use_dense and not all(
         params_list[ix[0]].implicit_prefs for ix in groups.values()
     ):
-        has_zero = bool(np.any(vals == 0.0))
-        use_dense = not has_zero or all(
-            params_list[ix[0]].implicit_prefs for ix in groups.values()
-        )
+        use_dense = not np.any(vals == 0.0)
     staged_d = staged_w = None
     if use_dense:
         # ONE device rating matrix serves every grid point and every
         # rank group (vmap broadcasts; R has no rank axis)
-        staged_d = stage_dense(rows, cols, vals, n_users, n_items, base)
+        staged_d = stage_dense(
+            rows, cols, vals, n_users, n_items, base, gate=gate
+        )
     else:
         staged_w = stage_windowed(rows, cols, vals, n_users, n_items, base)
 
@@ -1485,16 +1567,14 @@ def train(
         rows = np.asarray(rows, dtype=np.int32)
         cols = np.asarray(cols, dtype=np.int32)
         vals = np.asarray(vals, dtype=np.float32)
-        user_deg = np.zeros(n_users, np.float32)
-        np.add.at(user_deg, rows, 1.0)
-        item_deg = np.zeros(n_items, np.float32)
-        np.add.at(item_deg, cols, 1.0)
+        user_deg, item_deg = _degrees(rows, cols, n_users, n_items)
 
-    if dense_eligible(rows, cols, vals, n_users, n_items, params, mesh):
+    gate = dense_eligible(rows, cols, vals, n_users, n_items, params, mesh)
+    if gate:
         return _train_dense(
             rows, cols, vals, n_users, n_items, params,
             user_deg, item_deg, user_vocab, item_vocab, init_factors,
-            mesh=mesh,
+            mesh=mesh, gate=gate,
         )
 
     if params.rank <= GRAM_SOLVER_MAX_RANK:
@@ -1626,7 +1706,7 @@ def stage_windowed(
     each process stages only its contiguous slice of parts — the
     HBPEvents.scala:84-90 partitioned-read role). Degrees/init factors
     are replicated; mp row-sharding is applied inside the jit."""
-    with _spans.span("als.stage.host_prep"):
+    with _spans.span("als.stage.host_prep") as prep_sp:
         # single gate for both staging sharding and mesh pass-through: a
         # model-parallel-only mesh (dp=1, mp>1) still stages replicated
         # arrays but must reach the jit so mp row-sharding applies (ADVICE r4)
@@ -1636,12 +1716,11 @@ def stage_windowed(
             from predictionio_tpu.parallel.mesh import DATA_AXIS
 
             n_parts = int(mesh.shape.get(DATA_AXIS, 1))
-        if user_deg is None:
-            user_deg = np.zeros(n_users, np.float32)
-            np.add.at(user_deg, rows, 1.0)
-        if item_deg is None:
-            item_deg = np.zeros(n_items, np.float32)
-            np.add.at(item_deg, cols, 1.0)
+        prep_sp.attrs["degrees_reused"] = (
+            user_deg is not None and item_deg is not None
+        )
+        if user_deg is None or item_deg is None:
+            user_deg, item_deg = _degrees(rows, cols, n_users, n_items)
         by_user = np.argsort(rows, kind="stable")
         by_item = np.argsort(cols, kind="stable")
         plan_u = plan_windows(rows[by_user], n_users, n_parts)

@@ -33,6 +33,7 @@ this is its below-1%-density dense reformulation, not a translation.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional
 
@@ -68,19 +69,43 @@ def storage_dtype(dense_dtype: str):
     return jnp.float32 if dense_dtype == "f32" else jnp.bfloat16
 
 
+# ratings tested per step of int8_scale: the chunk and its two temporaries
+# stay in cache, and a scale that fails is dropped at its first bad chunk
+_SCALE_CHUNK = 1 << 16
+
+
 def int8_scale(vals) -> Optional[float]:
     """Smallest power-of-two (or decimal) scale making every rating an
     exact int8, or None. ML-style ratings (half-star steps ≤ 5) get
     s=2; integer counts ≤ 127 get s=1. Exactness is required — the
-    dense path must train the SAME weights the sparse path would."""
+    dense path must train the SAME weights the sparse path would.
+
+    One pass for the largest magnitude, then one chunked pass a
+    candidate scale, with no temporary the size of the input. A power of
+    two scales a float exactly in its own dtype; a decimal scale is
+    tested in float64, where float32 · s is exact (24 + 7 bits)."""
     import numpy as np
 
-    m = float(np.max(np.abs(vals))) if len(vals) else 0.0
+    vals = np.asarray(vals).reshape(-1)
+    if vals.dtype.kind != "f":
+        vals = vals.astype(np.float64)
+    if vals.size == 0:
+        return 1.0
+    m = float(max(vals.max(), -vals.min()))
     if m == 0.0:
         return 1.0
     for s in (1.0, 2.0, 4.0, 8.0, 10.0, 16.0, 20.0, 32.0, 50.0, 64.0, 100.0):
-        scaled = np.asarray(vals, np.float64) * s
-        if m * s <= 127.0 and np.all(scaled == np.round(scaled)):
+        if not m * s <= 127.0:  # the scales ascend (and a NaN): none fits
+            return None
+        wide = math.frexp(s)[0] != 0.5  # not a power of two
+        for i in range(0, vals.size, _SCALE_CHUNK):
+            chunk = vals[i : i + _SCALE_CHUNK]
+            if wide:
+                chunk = chunk.astype(np.float64)
+            scaled = chunk if s == 1.0 else chunk * chunk.dtype.type(s)
+            if not np.array_equal(scaled, np.rint(scaled)):
+                break
+        else:
             return s
     return None
 

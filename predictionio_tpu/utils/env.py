@@ -300,7 +300,7 @@ _k("PIO_ALERT_JSON", "str", "",
 # -- kernels / numerics ------------------------------------------------------
 _k("PIO_DENSE_ALS", "flag", "",
    "Dense ALS solver: 1 forces on, 0 forces off, empty = auto.")
-_k("PIO_DENSE_ALS_BYTES", "int", 2 * 1024**3,
+_k("PIO_DENSE_ALS_BYTES", "int", 9_000_000_000,
    "Densified-matrix byte budget the dense-ALS auto mode respects.")
 _k("PIO_PALLAS_DENSE", "enum", "",
    "Dense-pass Pallas kernel mode: tpu | interpret | 0 (XLA).")
