@@ -21,7 +21,6 @@ Shape of the engine:
 from __future__ import annotations
 
 import logging
-import threading
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -41,9 +40,31 @@ from predictionio_tpu.core.self_cleaning import EventWindow, SelfCleaningDataSou
 from predictionio_tpu.data.store.bimap import BiMap
 from predictionio_tpu.data.store.event_store import EventStoreFacade
 from predictionio_tpu.models import cco
+from predictionio_tpu.models.resident import ResidentCorrelators
 from predictionio_tpu.obs import devprof as _devprof
+from predictionio_tpu.obs import spans as _spans
+from predictionio_tpu.obs.registry import get_default_registry
 
 log = logging.getLogger(__name__)
+
+# what the serving path counts, in the process-wide registry (the engine
+# is owned by no one server): batches by the exclusion's WIRE form
+# ("none"; "rows" = ids, at most ROWLIST_MAX a query; "mask" = packed
+# words, a wider list), the exclusion bytes shipped in either form, and
+# the history reads the event store failed
+_BATCHES = get_default_registry().counter(
+    "ur_batches_total",
+    "batches through cco.batch_score_topk, by exclusion wire form",
+    labelnames=("form",),  # label-bound: literal none|rows|mask
+)
+_EXCLUSION_BYTES = get_default_registry().counter(
+    "ur_exclusion_bytes_total",
+    "bytes of exclusion (row lists or packed words) shipped",
+)
+_HISTORY_READ_FAILURES = get_default_registry().counter(
+    "ur_history_read_failures_total",
+    "serving-time history reads the event store failed (served as empty)",
+)
 
 
 @dataclass
@@ -166,10 +187,14 @@ class URModel:
         self.item_vocab = item_vocab  # primary target vocab = item space
         self.indicator_models = indicator_models
         self.primary_indicator = primary_indicator
-        self._device_tables = None
-        self._stage_lock = threading.Lock()
+        # the device-resident correlator tables: staged once, on the
+        # first batch (warm-up), reused by every dispatch
+        self.resident = ResidentCorrelators([
+            (m.correlator_idx, m.correlator_scores, len(m.target_vocab))
+            for m in indicator_models
+        ])
 
-    # device caches + lock are serving state, not part of the pickled model
+    # the staged tables are serving state, not part of the pickled model
     def __getstate__(self):
         return {
             "item_vocab": self.item_vocab,
@@ -184,25 +209,18 @@ class URModel:
             state["primary_indicator"],
         )
 
-    def device_tables(self) -> list:
-        """HBM-resident correlator tables [(idx, scores, J), …] — staged
-        once, reused by every batched serving dispatch. Locked: the
-        pipelined dispatcher (server.py pipeline_depth) may run two
-        batches for the same model concurrently, and double-staging the
-        tables would transiently double their HBM footprint."""
-        with self._stage_lock:
-            if self._device_tables is None:
-                import jax.numpy as jnp
-
-                self._device_tables = [
-                    (
-                        jnp.asarray(m.correlator_idx.astype("int32")),
-                        jnp.asarray(m.correlator_scores.astype("float32")),
-                        len(m.target_vocab),
-                    )
-                    for m in self.indicator_models
-                ]
-            return self._device_tables
+    def resident_device_bytes(self) -> float:
+        """Per-device HBM footprint for the tenant cache's budget
+        (tenancy/cache.py walks to this hook): the staged tables, else
+        the host tables once (staging mirrors them 1:1 but for the
+        block pad)."""
+        staged = self.resident.device_bytes()
+        if staged is not None:
+            return staged
+        return float(sum(
+            m.correlator_idx.nbytes + m.correlator_scores.nbytes
+            for m in self.indicator_models
+        ))
 
 
 class URAlgorithm(Algorithm):
@@ -245,17 +263,6 @@ class URAlgorithm(Algorithm):
         )
 
     # -- serving -----------------------------------------------------------
-    def _user_history(
-        self,
-        ctx: RuntimeContext,
-        user: str,
-        event_name: str,
-        target_vocab: BiMap,
-    ) -> np.ndarray:
-        return self._user_histories(
-            ctx, [user], event_name, target_vocab
-        )[0]
-
     def _user_histories(
         self,
         ctx: RuntimeContext,
@@ -266,7 +273,8 @@ class URAlgorithm(Algorithm):
         """Per-user history rows for a WHOLE serving micro-batch in ONE
         store round trip (VERDICT r4 #4 — the per-query loop cost one
         store call per (query, indicator); a remote/sharded store paid a
-        network RTT each)."""
+        network RTT each). A read the store fails is logged, counted
+        (`ur_history_read_failures_total`) and served as no history."""
         empty = np.empty(0, dtype=np.int64)
         if ctx.storage is None:
             return [empty for _ in users]
@@ -282,6 +290,7 @@ class URAlgorithm(Algorithm):
             )
         except Exception:
             log.exception("history lookup failed for %s", event_name)
+            _HISTORY_READ_FAILURES.inc()
             return [empty for _ in users]
         out = []
         for u in users:
@@ -293,27 +302,43 @@ class URAlgorithm(Algorithm):
             out.append(np.asarray(rows, dtype=np.int64))
         return out
 
+    #: a blacklist a form long, for warm-up: nothing, the narrow row
+    #: list, the wide one, packed words (`cco.exclusion_of`)
+    _WARMUP_BLACKLISTS = (0, 1, 9, 65)
+
     def warmup(self, model: URModel) -> None:
-        """Pre-compile the batched serving programs + stage correlator
-        tables into HBM. Shapes are static per params (batch buckets
-        {1,8,64}, fixed history depth, fixed exclusion width, k floor), so
-        warming these covers live traffic; only a query with num above the
-        k floor would compile a further shape."""
+        """Stage the correlator tables into HBM and pre-compile the
+        batched serving programs. Shapes are static per params: the
+        batch buckets {1, 8, 64} x the four exclusion forms a batch's
+        ids can pick, and a bucket's add-only program for a plan that
+        outgrows one call; fixed history depth, the k floor — so warming
+        these covers live traffic; only a query with num above the k
+        floor would compile a further shape."""
         if not model.indicator_models or len(model.item_vocab) == 0:
             return
+        from predictionio_tpu.utils.bucket import topk_bucket
+
+        inv = model.item_vocab.inverse()
+        n_items = len(model.item_vocab)
         for batch in (1, 8, 64):
-            self._predict_batch(
-                self.serving_context, model,
-                [Query(user="__warmup__")] * batch,
+            for n_black in self._WARMUP_BLACKLISTS:
+                black = [inv(i) for i in range(min(n_black, n_items))]
+                self._predict_batch(
+                    self.serving_context, model,
+                    [Query(user="__warmup__", blacklist=black)] * batch,
+                )
+            # "__warmup__" has no history, so the batches above made one
+            # call each: a plan one window longer than a call's runs the
+            # leading, add-only program of this bucket too
+            cco.batch_score_topk(
+                model.resident.get(),
+                np.zeros((cco.call_windows(batch) + 1, 3), np.int64),
+                cco.Exclusion("none", None), batch,
+                topk_bucket(min(10, n_items), n_items, floor=64),
             )
 
-    def _exclusion_width(self) -> int:
-        # static per params: the seen-history is capped by max_query_events
-        # and blacklists get 64 slots; a longer list is truncated (logged)
-        # rather than compiling a new device shape per batch
-        return 1 << (self.params.max_query_events + 64 - 1).bit_length()
-
-    _DISPATCH_CHUNK = 64  # device micro-batch; eval-sized inputs chunk
+    # device micro-batch; eval-sized inputs chunk
+    _DISPATCH_CHUNK = cco.MAX_BATCH
 
     def _predict_batch(
         self, ctx: RuntimeContext, model: URModel, queries: list[Query]
@@ -336,83 +361,85 @@ class URAlgorithm(Algorithm):
         n_items = len(model.item_vocab)
         if n_items == 0 or not model.indicator_models:
             return [PredictedResult() for _ in queries]
-        bsz = batch_bucket(n_real)
-        h_max = self.params.max_query_events
-
-        users = [q.user for q in queries]
-        histories = []
-        for ind in model.indicator_models:
-            h = np.full((bsz, h_max), -1, np.int32)
-            per_user = self._user_histories(
-                ctx, users, ind.name, ind.target_vocab
+        # the three host/device phases of a batch are spans, as the ALS
+        # path's are: what the host does before the device pass (the
+        # live history read, its own span, is most of it), the pass
+        # until the answers are host arrays, the decode back to item ids
+        with _spans.span("ur.predict.prepare") as sp:
+            bsz = batch_bucket(n_real)
+            sp.attrs["live"] = n_real
+            sp.attrs["bucket"] = bsz
+            h_max = self.params.max_query_events
+            users = [q.user for q in queries]
+            with _spans.span("ur.history_read") as hr:
+                per_indicator = {
+                    ind.name: self._user_histories(
+                        ctx, users, ind.name, ind.target_vocab
+                    )
+                    for ind in model.indicator_models
+                }
+                # seen-filter works in the PRIMARY item space; the
+                # primary indicator's read serves it where the model
+                # keeps that indicator (same store call, same vocab)
+                seen = per_indicator.get(model.primary_indicator)
+                if seen is None and any(q.exclude_seen for q in queries):
+                    seen = self._user_histories(
+                        ctx, users, model.primary_indicator,
+                        model.item_vocab,
+                    )
+                hr.attrs["events"] = int(sum(
+                    len(h) for hs in per_indicator.values() for h in hs
+                ))
+            histories = []
+            for ind in model.indicator_models:
+                h = np.full((bsz, h_max), -1, np.int32)
+                for qi, hist in enumerate(per_indicator[ind.name]):
+                    h[qi, : len(hist)] = hist[:h_max]
+                histories.append(h)
+            lists = []
+            for qi, q in enumerate(queries):
+                ex = [int(ix) for ix in seen[qi]] if q.exclude_seen else []
+                for it in q.blacklist or []:
+                    ix = model.item_vocab.get(it)
+                    if ix is not None:
+                        ex.append(ix)
+                lists.append(ex)
+            staged = model.resident.get()
+            # which postings the batch reads is host arithmetic on the
+            # staged offsets: part of prepare, not of the device pass
+            plan = cco.plan_windows(staged, histories)
+            sp.attrs["windows"] = len(plan)
+            # the form follows the ids this batch carries: nothing, a
+            # row list, or words beyond ROWLIST_MAX ids a query — no
+            # static worst-case width, no host mask
+            exclude = cco.exclusion_of(lists, bsz, staged.rows_padded)
+            sp.attrs["form"] = exclude.form
+            _BATCHES.inc(form=exclude.form)
+            _EXCLUSION_BYTES.inc(float(exclude.nbytes))
+            k_req = min(max((q.num for q in queries), default=10), n_items)
+            k = topk_bucket(k_req, n_items, floor=64)
+        with _spans.span("ur.predict.device"):
+            # padding-waste accounting (ISSUE 3) at the pad site: n_real
+            # live queries ran in a bsz-shaped device program
+            prof0 = _devprof.snapshot()
+            vals, idx = cco.batch_score_topk(staged, plan, exclude, bsz, k)
+            _devprof.record_batch_padding(
+                n_real, bsz, flops=_devprof.snapshot().flops - prof0.flops
             )
-            for qi, hist in enumerate(per_user):
-                h[qi, : len(hist)] = hist[:h_max]
-            histories.append(h)
-        # seen-filter works in the PRIMARY item space, even when the
-        # algorithm keeps only secondary indicators
-        e_max = self._exclusion_width()
-        exclude = np.full((bsz, e_max), -1, np.int32)
-        # one batched primary-history fetch for every exclude_seen query
-        seen_users = [q.user for q in queries if q.exclude_seen]
-        seen_by_user = (
-            dict(zip(
-                seen_users,
-                self._user_histories(
-                    ctx, seen_users, model.primary_indicator,
-                    model.item_vocab,
-                ),
-            ))
-            if seen_users
-            else {}
-        )
-        # exclusions beyond the static device width are NOT dropped
-        # (ADVICE r3): the overflow is applied host-side after top-k,
-        # with k widened so filtered rows still fill q.num results
-        overflow: dict[int, set] = {}
-        for qi, q in enumerate(queries):
-            ex: list[int] = []
-            if q.exclude_seen:
-                seen = seen_by_user[q.user]
-                ex.extend(int(ix) for ix in seen)
-            for it in q.blacklist or []:
-                ix = model.item_vocab.get(it)
-                if ix is not None:
-                    ex.append(ix)
-            if len(ex) > e_max:
-                overflow[qi] = set(ex[e_max:])
-                log.info(
-                    "query exclusion list %d > device width %d: overflow "
-                    "filtered host-side", len(ex), e_max,
-                )
-            exclude[qi, : len(ex)] = ex[:e_max]
-
-        k_req = min(max((q.num for q in queries), default=10), n_items)
-        max_over = max((len(s) for s in overflow.values()), default=0)
-        k = topk_bucket(min(k_req + max_over, n_items), n_items, floor=64)
-        # padding-waste accounting (ISSUE 3) at the pad site: n_real live
-        # queries ran in a bsz-shaped device program
-        prof0 = _devprof.snapshot()
-        vals, idx = cco.batch_score_topk(
-            model.device_tables(), histories, exclude, k
-        )
-        _devprof.record_batch_padding(
-            n_real, bsz, flops=_devprof.snapshot().flops - prof0.flops
-        )
-        inv = model.item_vocab.inverse()
-        out = []
-        for qi, q in enumerate(queries[:n_real]):
-            scores = []
-            skip = overflow.get(qi)
-            for v, ix in zip(vals[qi], idx[qi]):
-                if len(scores) >= q.num:
-                    break
-                if v <= 0.0:  # positive_only: no LLR evidence, or excluded
-                    continue
-                if skip is not None and int(ix) in skip:
-                    continue
-                scores.append(ItemScore(item=inv(int(ix)), score=float(v)))
-            out.append(PredictedResult(item_scores=scores))
+        with _spans.span("ur.predict.decode"):
+            inv = model.item_vocab.inverse()
+            out = []
+            for qi, q in enumerate(queries):
+                scores = []
+                for v, ix in zip(vals[qi], idx[qi]):
+                    if len(scores) >= q.num:
+                        break
+                    if v <= 0.0:  # positive_only: no LLR evidence, or
+                        break  # excluded — and the rest is no higher
+                    scores.append(
+                        ItemScore(item=inv(int(ix)), score=float(v))
+                    )
+                out.append(PredictedResult(item_scores=scores))
         return out
 
     def predict(self, model: URModel, query: Query) -> PredictedResult:

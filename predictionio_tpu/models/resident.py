@@ -15,6 +15,12 @@ through the models' one-line hooks; `info()` is either tier's layout.
 from the deployed algorithm's params at predict time. `fleet.runtime`
 imports lazily (it builds meshes; models that never shard never load
 it).
+
+`ResidentCorrelators` is the same ownership for a co-occurrence model
+(the Universal Recommender): its correlator tables staged once, padded
+at staging, under the same one-device budget and byte count. It has the
+one-chip tier only, and `models/cco.py` loads when it stages — the
+factor engines never import it.
 """
 
 from __future__ import annotations
@@ -28,6 +34,20 @@ import numpy as np
 from predictionio_tpu.models import als
 
 log = logging.getLogger(__name__)
+
+
+def _device_budget() -> Optional[float]:
+    """One device's serving budget in bytes: PIO_SERVE_HBM_BYTES, else
+    the memory the device reports (a CPU reports none: no gate)."""
+    import jax
+
+    from predictionio_tpu.utils.env import env_opt_float
+
+    budget = env_opt_float("PIO_SERVE_HBM_BYTES")
+    if budget is None:
+        stats = jax.devices()[0].memory_stats() or {}
+        budget = stats.get("bytes_limit")
+    return None if budget is None else float(budget)
 
 
 class ResidentServing:
@@ -116,14 +136,7 @@ class ResidentServing:
         factor state is over one device's budget — PIO_SERVE_HBM_BYTES,
         else the memory the device reports (a CPU reports none: no
         gate) — instead of a death in the allocator mid-staging."""
-        import jax
-
-        from predictionio_tpu.utils.env import env_opt_float
-
-        budget = env_opt_float("PIO_SERVE_HBM_BYTES")
-        if budget is None:
-            stats = jax.devices()[0].memory_stats() or {}
-            budget = stats.get("bytes_limit")
+        budget = _device_budget()
         if budget is None:
             return
         from predictionio_tpu.fleet.runtime import check_single_device_budget
@@ -133,7 +146,7 @@ class ResidentServing:
             0 if self.item_only else uf.shape[0],
             itf.shape[0],
             uf.shape[1],
-            float(budget),
+            budget,
             serve_dtype=self.serve_dtype,
         )
 
@@ -250,6 +263,89 @@ class ResidentServing:
         restages."""
         with self._lock:
             self._single = self._sharded = None
+
+
+class ResidentCorrelators:
+    """Lazily staged correlator tables of a co-occurrence model:
+    `tables` is `[(corr_idx (I, T), corr_scores (I, T), J), …]`, one
+    entry an indicator, the host arrays the model keeps. `get()` stages
+    them once (`cco.stage_correlators`: inverted on the device into
+    postings sorted by thing, as the scoring program reads them) after
+    the one-device budget check, as the span `ur.stage`. Never pickled:
+    the model rebuilds it from its tables."""
+
+    def __init__(self, tables: list):
+        self.tables = tables
+        # locked: the pipelined dispatcher runs concurrent batches for
+        # one model, and a double staging would transiently double the
+        # device footprint
+        self._lock = threading.Lock()
+        self._staged = None  # cco.StagedCorrelators when staged
+
+    def get(self):
+        """The resident `cco.StagedCorrelators`, staged on first use.
+        `OversizedModelError`, naming the table set and the three terms
+        of its peak (`cco.device_peak_bytes`: the resident postings, the
+        staging sort's arrays beside them, the widest bucket's total),
+        where that peak is over one device's budget — instead of a
+        death in the allocator mid-staging or in the first full batch
+        (this model has no sharded tier to name)."""
+        from predictionio_tpu.models import cco
+        from predictionio_tpu.obs import spans as _spans
+
+        with self._lock:
+            if self._staged is None:
+                resident, sort, total = cco.device_peak_bytes(self.tables)
+                budget = _device_budget()
+                if budget is not None and resident + sort + total > budget:
+                    from predictionio_tpu.fleet.runtime import (
+                        OversizedModelError,
+                    )
+
+                    rows = int(np.shape(self.tables[0][0])[0])
+                    raise OversizedModelError(
+                        f"correlator tables ({len(self.tables)} indicators "
+                        f"x {rows} items) need "
+                        f"{(resident + sort + total) / 1e9:.2f} GB at "
+                        f"their peak — {resident / 1e9:.2f} resident, "
+                        f"{sort / 1e9:.2f} for the staging sort of one "
+                        f"indicator beside them, {total / 1e9:.2f} for a "
+                        f"{cco.MAX_BATCH}-query batch's total — but the "
+                        f"single-device budget is {budget / 1e9:.2f} GB; "
+                        "co-occurrence serving has no sharded tier"
+                    )
+                with _spans.span(
+                    "ur.stage", indicators=len(self.tables)
+                ) as sp:
+                    self._staged = cco.stage_correlators(self.tables)
+                    sp.attrs["bytes"] = self._staged.nbytes
+                    sp.attrs["item_rows_padded"] = self._staged.rows_padded
+            return self._staged
+
+    def info(self) -> Optional[dict]:
+        """What is staged (None when nothing is): the live and the
+        padded item rows and the resident bytes."""
+        st = self._staged
+        if st is None:
+            return None
+        return {
+            "shards": 1,
+            "n_items": st.n_items,
+            "indicators": len(st.offsets),
+            "item_rows_padded": st.rows_padded,
+            "resident_bytes_total": st.nbytes,
+        }
+
+    def device_bytes(self) -> Optional[float]:
+        st = self._staged
+        return float(st.nbytes) if st is not None else None
+
+    def drop(self) -> None:
+        """Release the staged tables: the device buffers go when the
+        last in-flight batch lets go of them, and the next query
+        restages."""
+        with self._lock:
+            self._staged = None
 
 
 def _publish_single(old_state, dirty_users, dirty_items, n_users, n_items):

@@ -221,12 +221,18 @@ def check_cco(mode: str):
             np.abs(rng.standard_normal((500, 20))).astype(np.float32), j,
         ))
         hists.append(rng.randint(-1, j, (8, 16)).astype(np.int32))
+    staged = cco.stage_correlators(tables)
+    plan = cco.plan_windows(staged, hists)
     for width in (32, 128):
         ex = np.full((8, width), -1, np.int32)
+        n_ids = 12 if width == 32 else 70  # beyond ROWLIST_MAX: words
         for b in range(8):
-            ex[b, :12] = rng.choice(500, 12, replace=False)
-        v0, i0 = cco.batch_score_topk(tables, hists, ex, 17, mode="off")
-        v1, i1 = cco.batch_score_topk(tables, hists, ex, 17, mode=mode)
+            ex[b, :n_ids] = rng.choice(500, n_ids, replace=False)
+        exclude = cco.exclusion_of(
+            [[int(i) for i in row if i >= 0] for row in ex], 8,
+            staged.rows_padded)
+        v0, i0 = cco.batch_score_topk(staged, plan, exclude, 8, 17, mode="off")
+        v1, i1 = cco.batch_score_topk(staged, plan, exclude, 8, 17, mode=mode)
         record(f"cco tail width={width} kernel vs XLA",
                np.array_equal(i0, i1) and np.allclose(v0, v1, rtol=1e-6),
                idx_equal=round(float(np.mean(i0 == i1)), 4),

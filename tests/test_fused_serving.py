@@ -162,6 +162,19 @@ def test_bf16_serving_halves_factor_bytes_and_is_mode_invariant():
 # ---------------------------------------------------------------------------
 
 
+def _cco_score(tables, hists, ex, k, mode):
+    """`cco.batch_score_topk` as the engine drives it: the tables staged,
+    the plan made on the host, the exclusion in the form its ids pick."""
+    staged = tables if isinstance(tables, cco.StagedCorrelators) else (
+        cco.stage_correlators(tables))
+    bsz = hists[0].shape[0]
+    exclude = cco.exclusion_of(
+        [[int(i) for i in row if i >= 0] for row in ex], bsz,
+        staged.rows_padded)
+    return cco.batch_score_topk(
+        staged, cco.plan_windows(staged, hists), exclude, bsz, k, mode=mode)
+
+
 def _cco_tables(rng, I=500, T=20, js=(120, 80)):
     tables, hists = [], []
     for J in js:
@@ -180,10 +193,11 @@ def test_cco_fused_matches_xla_exactly(width):
     rng = np.random.RandomState(25)
     tables, hists = _cco_tables(rng)
     ex = np.full((8, width), -1, np.int32)
+    n_ids = 12 if width == 32 else 70  # beyond ROWLIST_MAX: packed words
     for b in range(8):
-        ex[b, :12] = rng.choice(500, 12, replace=False)
-    v0, i0 = cco.batch_score_topk(tables, hists, ex, 17, mode="off")
-    v1, i1 = cco.batch_score_topk(tables, hists, ex, 17, mode="interpret")
+        ex[b, :n_ids] = rng.choice(500, n_ids, replace=False)
+    v0, i0 = _cco_score(tables, hists, ex, 17, "off")
+    v1, i1 = _cco_score(tables, hists, ex, 17, "interpret")
     assert np.array_equal(i0, i1)
     np.testing.assert_allclose(v0, v1, rtol=1e-6)
 
@@ -200,12 +214,8 @@ def test_cco_fused_ties_and_k_edge():
     ).astype(np.float32)
     hist = rng.randint(-1, J, (4, 8)).astype(np.int32)
     ex = np.full((4, 8), -1, np.int32)
-    v0, i0 = cco.batch_score_topk(
-        [(idx, sc, J)], [hist], ex, I, mode="off"
-    )
-    v1, i1 = cco.batch_score_topk(
-        [(idx, sc, J)], [hist], ex, I, mode="interpret"
-    )
+    v0, i0 = _cco_score([(idx, sc, J)], [hist], ex, I, "off")
+    v1, i1 = _cco_score([(idx, sc, J)], [hist], ex, I, "interpret")
     assert np.array_equal(i0, i1)
     np.testing.assert_allclose(v0, v1, rtol=1e-6)
 
@@ -216,9 +226,7 @@ def test_cco_host_reference_agreement_fused():
     rng = np.random.RandomState(27)
     tables, hists = _cco_tables(rng, I=200, js=(60,))
     ex = np.full((8, 16), -1, np.int32)
-    vals, idx = cco.batch_score_topk(
-        tables, hists, ex, 5, mode="interpret"
-    )
+    vals, idx = _cco_score(tables, hists, ex, 5, "interpret")
     for b in range(3):
         hist = hists[0][b]
         ref = cco.score_history(

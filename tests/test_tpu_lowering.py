@@ -133,6 +133,49 @@ def test_fused_masked_topk_compiles_at_ur_catalog(v5e, mask_kind):
     ).compile()
 
 
+def _jitted(program):
+    """The jit object under the devprof wrapper (its donation and static
+    arguments as the program declares them)."""
+    return program.__wrapped__
+
+
+UR_ITEMS, UR_TOP_N, UR_INDICATORS = 4_162_024, 50, 4
+
+
+@pytest.mark.parametrize("batch,form,width", [
+    (1, "mask", None), (8, "rows", 8), (8, "rows", 64), (64, "none", None)])
+def test_ur_scoring_program_fits_the_chip_at_the_taobao_catalogue(
+    v5e, batch, form, width
+):
+    """The UR serving program at the benchmark cell's shape — four
+    indicators' inverted tables of 4,162,024 items x 50, a call's windows
+    of postings scattered into the (B, I_p) total, the fused tail in each
+    exclusion form: it compiles, takes the resident tables as they lie (no
+    per-batch copy of one) and fits the chip beside them."""
+    from predictionio_tpu.models import cco
+
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    rows = rp.pad_items(UR_ITEMS)
+    assert rows == 4_163_584 and rows % 2048 == 0
+    postings = (UR_INDICATORS * UR_ITEMS * UR_TOP_N + cco._WINDOW,)
+    resident = postings[0] * 8
+    windows = cco.call_windows(batch)
+    excluded = {"none": 0, "rows": batch * (width or 0),
+                "mask": batch * (rows // 32)}[form]
+    mem = _jitted(cco._score_topk_jit).lower(
+        sds((batch * rows,), jnp.float32),
+        sds(postings, jnp.int32), sds(postings, jnp.float32),
+        sds((windows * 3 + excluded,), jnp.int32), sds((), jnp.int32),
+        bsz=batch, windows=windows, rows_padded=rows, k=64, mode="tpu",
+        form=form,
+    ).compile().memory_analysis()
+    total = batch * rows * 4
+    assert resident <= mem.argument_size_in_bytes - total <= 1.01 * resident
+    assert mem.temp_size_in_bytes < 0.5 * resident
+    assert mem.alias_size_in_bytes >= total  # the scatter adds in place
+    assert resident + mem.temp_size_in_bytes + 2 * total < 15.75e9
+
+
 @pytest.mark.parametrize("mask_kind", ["none", "bits", "rows"])
 @pytest.mark.parametrize("dtype", ["f32", "int8"])
 def test_sharded_recommend_compiles_on_four_shards(v5e, dtype, mask_kind):

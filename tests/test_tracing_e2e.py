@@ -220,10 +220,19 @@ def test_trace_spans_cross_process_query(keep_all_traces):
         rpcs = by_name["storage.rpc"]
         fetch = [s for s in rpcs if s["attrs"]["dao"] == "events"]
         assert fetch, rpcs
-        # (since ISSUE 25 through the batch's own batch.predict span)
+        # (since ISSUE 25 through the batch's own batch.predict span, and
+        # since ISSUE 35 through the engine's own: the history read is a
+        # span of its own inside the batch's prepare phase)
         predict = by_name["batch.predict"][0]
         assert predict["parent_span_id"] == device["span_id"]
-        assert all(s["parent_span_id"] == predict["span_id"] for s in fetch)
+        prepare = by_name["ur.predict.prepare"][0]
+        assert prepare["parent_span_id"] == predict["span_id"]
+        read = by_name["ur.history_read"][0]
+        assert read["parent_span_id"] == prepare["span_id"]
+        assert read["attrs"]["events"] == 4  # u0's four buys
+        assert all(s["parent_span_id"] == read["span_id"] for s in fetch)
+        for name in ("ur.predict.device", "ur.predict.decode"):
+            assert by_name[name][0]["parent_span_id"] == predict["span_id"]
         # the request's own phases stand beside the dispatcher's spans
         for name in ("query.decode", "query.wait", "query.encode"):
             assert by_name[name][0]["parent_span_id"] == root["span_id"]

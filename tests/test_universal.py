@@ -283,7 +283,12 @@ class TestDeviceBatchServing:
             hists.append(h)
         exclude = np.full((B, 8), -1, np.int32)
         exclude[0, :3] = [1, 2, 3]
-        vals, idx = cco.batch_score_topk(tables, hists, exclude, k=n_items)
+        staged = cco.stage_correlators(tables)
+        vals, idx = cco.batch_score_topk(
+            staged, cco.plan_windows(staged, hists),
+            cco.exclusion_of([[1, 2, 3]], B, staged.rows_padded), B,
+            k=n_items,
+        )
 
         for b in range(B):
             expect = np.zeros(n_items, np.float32)
@@ -303,25 +308,24 @@ class TestDeviceBatchServing:
 
         from predictionio_tpu.models import cco
 
-        import jax.numpy as jnp
-
         rng = np.random.RandomState(9)
         n_items = 100_000
         cidx, csc = self._tables(rng, n_items, 80_000, 50)
-        # device-resident tables, as URModel.device_tables stages them —
+        # device-resident tables, as URModel.resident stages them —
         # re-uploading 20 MB of correlators per batch is NOT the product
         # configuration
-        tables = [(jnp.asarray(cidx), jnp.asarray(csc), 80_000)]
+        tables = cco.stage_correlators([(cidx, csc, 80_000)])
         B, H = 64, 100
         hist = np.full((B, H), -1, np.int32)
         for b in range(B):
             hist[b] = rng.randint(0, 80_000, H)
-        exclude = np.full((B, 8), -1, np.int32)
-        vals, idx = cco.batch_score_topk(tables, [hist], exclude, k=64)  # warm
+        exclude = cco.Exclusion("none", None)
+        plan = cco.plan_windows(tables, [hist])
+        vals, idx = cco.batch_score_topk(tables, plan, exclude, B, k=64)  # warm
         t0 = time.perf_counter()
         n_reps = 3
         for _ in range(n_reps):
-            vals, idx = cco.batch_score_topk(tables, [hist], exclude, k=64)
+            vals, idx = cco.batch_score_topk(tables, plan, exclude, B, k=64)
         dt = (time.perf_counter() - t0) / n_reps
         qps = B / dt
         assert vals.shape == (B, 64)
