@@ -739,6 +739,18 @@ class StagedDenseTrain:
         return _copy_back(uf, itf, self.n_users, self.n_items)
 
 
+def _padded_dims(n_users: int, n_items: int, dp: int = 1, mp: int = 1):
+    """(rows, columns) of the dense matrix: user rows pad to whole
+    ROW_BLOCKs of every dp device's slab, item columns to whole COL_PAD
+    column blocks of every mp device (ISSUE 10)."""
+    from predictionio_tpu.ops.dense import COL_PAD, ROW_BLOCK
+
+    return (
+        -(-n_users // (ROW_BLOCK * dp)) * (ROW_BLOCK * dp),
+        -(-n_items // (COL_PAD * mp)) * (COL_PAD * mp),
+    )
+
+
 def dense_matrix_bytes(
     n_users: int, n_items: int, dense_dtype: str = "bf16", dp: int = 1,
     mp: int = 1,
@@ -746,14 +758,9 @@ def dense_matrix_bytes(
     """Padded dense-R footprint — the auto-dispatch gate's input.
     `dp` > 1 pads rows (and `mp` > 1 columns) to whole per-device slabs
     (stage_dense does)."""
-    from predictionio_tpu.ops.dense import (
-        BYTES_PER_CELL,
-        COL_PAD,
-        ROW_BLOCK,
-    )
+    from predictionio_tpu.ops.dense import BYTES_PER_CELL
 
-    n_u_p = -(-n_users // (ROW_BLOCK * dp)) * (ROW_BLOCK * dp)
-    n_i_p = -(-n_items // (COL_PAD * mp)) * (COL_PAD * mp)
+    n_u_p, n_i_p = _padded_dims(n_users, n_items, dp, mp)
     return n_u_p * n_i_p * BYTES_PER_CELL.get(dense_dtype, 2)
 
 
@@ -785,10 +792,28 @@ def _degrees(rows, cols, n_users: int, n_items: int):
 
 
 @dataclass(frozen=True)
+class GroupedPairs:
+    """A job's unique pairs in the order `ops.dense.densify` builds the
+    matrix from: grouped by row block, ascending by cell inside a block.
+    What `_group_unique_pairs` keeps of the job's one sort."""
+
+    #: (E,) int32 — a pair's cell inside its row block,
+    #: (row mod ROW_BLOCK) · n_cols_p + col
+    offsets: np.ndarray
+    #: (E,) storage dtype — what the matrix holds at that cell
+    values: np.ndarray
+    #: (n_blocks + 1,) int32 — block b's pairs are [starts[b], starts[b+1])
+    starts: np.ndarray
+    #: (n_rows_p, n_cols_p, dense_dtype, scale) the pairs were grouped
+    #: for: a staging that pads, stores or scales otherwise groups its own
+    grouped_for: tuple
+
+
+@dataclass(frozen=True)
 class DenseGate:
     """`dense_eligible`'s answer and what it learned on the way, so that
-    `stage_dense` does not scan the ratings for their int8 scale again.
-    Truthy when the dense path may run."""
+    `stage_dense` neither scans the ratings for their int8 scale nor
+    sorts the pairs again. Truthy when the dense path may run."""
 
     #: "eligible", or the condition that refused: env_off, rank,
     #: multi_process, few_edges, bytes, explicit_zero, duplicate_pairs
@@ -800,19 +825,91 @@ class DenseGate:
     #: there means "not exactly quantizable")
     scale_known: bool = False
     int8_scale: Optional[float] = None
+    #: the pairs as the uniqueness sort left them; set when eligible
+    pairs: Optional[GroupedPairs] = field(
+        default=None, compare=False, repr=False
+    )
 
     def __bool__(self) -> bool:
         return self.verdict == "eligible"
 
 
-def _unique_pairs(rows, cols, n_items: int) -> bool:
-    """No (user, item) pair occurs twice: one int64 key a pair, built and
-    sorted in ONE buffer, then a compare of neighbours."""
-    key = rows.astype(np.int64)
-    key *= n_items
-    key += cols
+# pairs a step of _group_unique_pairs' linear passes, so that a step's
+# temporaries stay in cache
+_PAIR_CHUNK = 1 << 18
+
+
+def _group_unique_pairs(
+    rows, cols, vals, n_rows_p: int, n_cols_p: int, dense_dtype: str,
+    scale: float,
+) -> Optional[GroupedPairs]:
+    """The job's one sort. One int64 key a pair, built and sorted in ONE
+    buffer: the high bits are the pair's place in row-major order of the
+    matrix — its row block, then its cell inside the block — and the low
+    bits what the device stores there: the int8 code (8 bits), the bf16
+    bits (16) or, for f32 storage, the pair's own index, by which the
+    values are gathered. Sorted, the keys are grouped by row block and
+    two pairs of one cell are neighbours: None when there are such, else
+    the pairs as `ops.dense.densify` takes them."""
+    from predictionio_tpu.ops.dense import (
+        MAX_DENSE_COLS,
+        ROW_BLOCK,
+        storage_dtype,
+    )
+
+    n = len(rows)
+    st = np.dtype(storage_dtype(dense_dtype))
+    by_index = st.itemsize == 4
+    n_blocks = n_rows_p // ROW_BLOCK
+    row_shift = ROW_BLOCK.bit_length() - 1
+    cells = ROW_BLOCK * n_cols_p  # of a block
+    off_bits = (cells - 1).bit_length()
+    low_bits = max(1, (n - 1).bit_length()) if by_index else 8 * st.itemsize
+    if (
+        n_cols_p >= MAX_DENSE_COLS
+        or n_blocks.bit_length() + off_bits + low_bits > 63
+    ):
+        raise ValueError(
+            f"a {n_rows_p} x {n_cols_p} matrix of {n} pairs does not fit "
+            "the dense path's pair key"
+        )
+    key = np.empty(n, np.int64)
+    for i in range(0, n, _PAIR_CHUNK):
+        j = min(i + _PAIR_CHUNK, n)
+        k = key[i:j]
+        row = rows[i:j] & (ROW_BLOCK - 1)  # ROW_BLOCK is a power of two
+        row *= n_cols_p
+        row += cols[i:j]
+        k[:] = rows[i:j] >> row_shift
+        k <<= off_bits
+        k |= row
+        k <<= low_bits
+        if by_index:
+            k |= np.arange(i, j)
+        elif st.itemsize == 1:
+            k |= np.rint(vals[i:j] * np.float32(scale)).astype(st).view(np.uint8)
+        else:
+            k |= vals[i:j].astype(st).view(np.uint16)
     key.sort()
-    return not bool((key[1:] == key[:-1]).any())
+    offsets = np.empty(n, np.int32)
+    low = np.empty(n, np.int64 if by_index else f"u{st.itemsize}")
+    off_mask, low_mask = (1 << off_bits) - 1, (1 << low_bits) - 1
+    last = -1
+    for i in range(0, n, _PAIR_CHUNK):
+        k = key[i : i + _PAIR_CHUNK]
+        cell = k >> low_bits
+        if cell[0] == last or (cell[1:] == cell[:-1]).any():
+            return None
+        last = cell[-1]
+        offsets[i : i + _PAIR_CHUNK] = cell & off_mask
+        low[i : i + _PAIR_CHUNK] = k & low_mask
+    starts = np.searchsorted(
+        key, np.arange(n_blocks + 1, dtype=np.int64) << (off_bits + low_bits)
+    ).astype(np.int32)
+    return GroupedPairs(
+        offsets, vals[low] if by_index else low.view(st), starts,
+        (n_rows_p, n_cols_p, dense_dtype, float(scale)),
+    )
 
 
 def dense_eligible(
@@ -825,7 +922,7 @@ def dense_eligible(
     mesh=None,
     dense_dtype: str = "bf16",
 ) -> DenseGate:
-    """Gate for the dense-W fast path; a pure host predicate.
+    """Gate for the dense-W fast path, on the host.
 
     Requires: env not opting out, rank within the gram-solver bound,
     single-process execution when a mesh is given (the shard_map'd dense
@@ -838,7 +935,9 @@ def dense_eligible(
     Auto mode also requires DENSE_AUTO_MIN_EDGES so small (test-scale)
     trains keep their f32-exact windowed numerics unless PIO_DENSE_ALS=1
     opts in. The conditions are tested cheapest first: the pair keys,
-    the one sort of the job, are built only when nothing else refused."""
+    the one sort of the job, are built only when nothing else refused,
+    and an eligible gate keeps what the sort grouped (`gate.pairs`) for
+    `stage_dense`."""
     with _spans.span("als.train.dense_eligible") as sp:
         gate = _dense_gate(
             rows, cols, vals, n_users, n_items, params, mesh, dense_dtype
@@ -846,6 +945,7 @@ def dense_eligible(
         sp.attrs.update(
             pairs=len(rows), verdict=gate.verdict,
             dense_dtype=gate.dense_dtype, int8_scale=gate.int8_scale,
+            sort_kept=gate.pairs is not None,
         )
     if gate.verdict == "duplicate_pairs":
         logging.getLogger(__name__).info(
@@ -857,6 +957,8 @@ def dense_eligible(
 def _dense_gate(
     rows, cols, vals, n_users, n_items, params, mesh, dense_dtype
 ) -> DenseGate:
+    from predictionio_tpu.ops.dense import MAX_DENSE_COLS, int8_scale
+
     env = env_str("PIO_DENSE_ALS").strip()
     if env == "0":
         return DenseGate("env_off")
@@ -868,8 +970,6 @@ def _dense_gate(
         return DenseGate("few_edges")
     known = dict(dense_dtype=dense_dtype)
     if dense_dtype == "bf16":  # the default: predict what auto picks
-        from predictionio_tpu.ops.dense import int8_scale
-
         s_q = int8_scale(vals)
         known = dict(
             dense_dtype="bf16" if s_q is None else "int8",
@@ -881,15 +981,21 @@ def _dense_gate(
 
         dp = int(mesh.shape.get(DATA_AXIS, 1))
         mp = int(mesh.shape.get(MODEL_AXIS, 1))
+    n_u_p, n_i_p = _padded_dims(n_users, n_items, dp, mp)
+    # the second condition: densify addresses a row block's cells in int32
     if dense_matrix_bytes(
         n_users, n_items, known["dense_dtype"], dp=dp, mp=mp
-    ) > env_int("PIO_DENSE_ALS_BYTES"):
+    ) > env_int("PIO_DENSE_ALS_BYTES") or n_i_p >= MAX_DENSE_COLS:
         return DenseGate("bytes", **known)
     if not params.implicit_prefs and np.any(vals == 0.0):
         return DenseGate("explicit_zero", **known)
-    if not _unique_pairs(rows, cols, n_items):
+    pairs = _group_unique_pairs(
+        rows, cols, vals, n_u_p, n_i_p,
+        known["dense_dtype"], known.get("int8_scale") or 1.0,
+    )
+    if pairs is None:
         return DenseGate("duplicate_pairs", **known)
-    return DenseGate("eligible", **known)
+    return DenseGate("eligible", pairs=pairs, **known)
 
 
 def _dense_pallas_mode():
@@ -905,25 +1011,23 @@ def stage_dense(
     mesh=None,
     gate: Optional[DenseGate] = None,
 ) -> StagedDenseTrain:
-    """Stage the dense-path train: pad dims to the block quanta, push the
-    COO arrays once, densify ON DEVICE (the matrix never crosses the
-    host link), and keep it resident.
+    """Stage the dense-path train: pad dims to the block quanta, group
+    the pairs by row block, push them once (an int32 offset and a stored
+    value a pair), build the matrix ON DEVICE a row block at a time (it
+    never crosses the host link), and keep it resident.
 
     `gate` is `dense_eligible`'s answer for these same ratings, and
     `user_deg` / `item_deg` their degrees: what the caller has already
-    computed is taken over, what it has not is computed here.
+    computed — the int8 scale, the pairs its sort grouped — is taken
+    over, what it has not is computed here by the same functions. The
+    pairs must be unique.
 
     dense_dtype "auto" prefers int8 storage when every rating is exactly
     representable as round(r·s) for a small scale s (ML-style ratings
     are) — half the footprint and HBM stream of bf16, with block-local
     dequantization; otherwise bf16. "f32" is the exactness mode tests
     compare against the windowed path with."""
-    from predictionio_tpu.ops.dense import (
-        COL_PAD,
-        ROW_BLOCK,
-        densify,
-        int8_scale,
-    )
+    from predictionio_tpu.ops import dense as dense_ops
 
     with _spans.span("als.stage.host_prep") as prep_sp:
         rows = np.asarray(rows, dtype=np.int32)
@@ -933,7 +1037,10 @@ def stage_dense(
         scale_reused = False
         if dense_dtype in ("auto", "int8"):
             scale_reused = gate is not None and gate.scale_known
-            s_q = gate.int8_scale if scale_reused else int8_scale(vals)
+            s_q = (
+                gate.int8_scale if scale_reused
+                else dense_ops.int8_scale(vals)
+            )
             if s_q is not None:
                 dense_dtype, scale = "int8", s_q
             elif dense_dtype == "int8":
@@ -949,13 +1056,23 @@ def stage_dense(
 
             dp = int(mesh.shape.get(DATA_AXIS, 1))
             mp = int(mesh.shape.get(MODEL_AXIS, 1))
-        # user rows pad to a slab multiple so every dp device scans whole
-        # row blocks of its own slab; with mp > 1 (ISSUE 10) item columns
-        # pad likewise so every mp device owns whole COL_PAD column blocks
-        n_u_p = -(-n_users // (ROW_BLOCK * dp)) * (ROW_BLOCK * dp)
-        n_i_p = -(-n_items // (COL_PAD * mp)) * (COL_PAD * mp)
+        n_u_p, n_i_p = _padded_dims(n_users, n_items, dp, mp)
+        grouped_for = (n_u_p, n_i_p, dense_dtype, float(scale))
+        pairs = gate.pairs if gate is not None else None
+        pairs_reused = (
+            pairs is not None
+            and len(pairs.offsets) == len(rows)
+            and pairs.grouped_for == grouped_for
+        )
+        if not pairs_reused:
+            pairs = _group_unique_pairs(rows, cols, vals, *grouped_for)
+            if pairs is None:
+                raise ValueError(
+                    "the dense path needs unique (user, item) pairs"
+                )
         prep_sp.attrs.update(
             int8_scale_reused=scale_reused,
+            pairs_grouped_reused=pairs_reused,
             degrees_reused=user_deg is not None and item_deg is not None,
         )
         if user_deg is None or item_deg is None:
@@ -1005,22 +1122,27 @@ def stage_dense(
     # staging is asynchronous: each span waits for what it started, so
     # transfer and densify are their own times and not part of the train's
     with _spans.span("als.stage.transfer") as xfer_sp:
-        coo = (jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals))
+        grouped = tuple(
+            jnp.asarray(a)
+            for a in (pairs.offsets, pairs.values, pairs.starts)
+        )
         rest = (
             jax.device_put(pad_deg(user_deg, n_u_p), vec_sh),
             jax.device_put(pad_deg(item_deg, n_i_p), ideg_sh),
             jax.device_put(uf0, row_sh) if uf0 is not None else None,
             jax.device_put(itf0, itf_sh) if itf0 is not None else None,
         )
-        jax.block_until_ready((coo, rest))
+        jax.block_until_ready((grouped, rest))
         xfer_sp.attrs["bytes"] = sum(
-            a.nbytes for a in coo + rest if a is not None
+            a.nbytes for a in grouped + rest if a is not None
         )
-    with _spans.span("als.stage.densify"):
-        r = densify(
-            *coo, n_rows_p=n_u_p, n_cols_p=n_i_p, dense_dtype=dense_dtype,
-            scale=scale,
+    with _spans.span("als.stage.densify") as dens_sp:
+        dead = dense_ops.dead_slots(pairs.starts)
+        dens_sp.attrs.update(
+            blocks=len(pairs.starts) - 1, pairs=len(pairs.offsets),
+            dead_slots=round(dead / max(1, dead + len(pairs.offsets)), 4),
         )
+        r = dense_ops.densify(*grouped, n_rows_p=n_u_p, n_cols_p=n_i_p)
         if sharded:
             r = jax.device_put(r, r_sh)
         jax.block_until_ready(r)

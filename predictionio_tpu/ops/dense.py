@@ -26,6 +26,11 @@ half-step over R's COLUMNS (solving items) contracts the same row blocks
 against the matching user-factor blocks and accumulates — R is stored
 once, row-major, and both directions stream it exactly once per pass.
 
+R is built on the device in the blocks it is read in (`densify`): the
+training set's unique pairs arrive grouped by row block — the order the
+staging gate's uniqueness sort leaves them in — and each block is built
+from its own pairs and written as one contiguous slab.
+
 Role in the reference: the MLlib-ALS hot loop
 (examples/scala-parallel-recommendation/*/ALSAlgorithm.scala:50-57);
 this is its below-1%-density dense reformulation, not a translation.
@@ -44,12 +49,17 @@ from predictionio_tpu.obs import devprof as _devprof
 
 # rows of R processed per scan step; block weight derivations live in
 # (ROW_BLOCK, n_cols) intermediates (~220 MB bf16 at ML-20M) instead of
-# full-matrix ones
+# full-matrix ones. A power of two: the host's pair key splits a row by
+# shift and mask (models/als.py _group_unique_pairs)
 ROW_BLOCK = 2048
 # lane quantum for the contraction axis
 COL_PAD = 256
-# edges scattered per densify step (see densify)
-DENSIFY_EDGE_CHUNK = 1 << 20
+# slots of a block's pairs densify scatters a step; a block's last chunk
+# is filled up with dead slots
+_SLOT_CHUNK = 1 << 16
+# densify addresses a row block's cells in int32 (the offsets it is handed
+# are): the staging gate refuses a matrix this wide or wider
+MAX_DENSE_COLS = (1 << 31) // ROW_BLOCK
 
 
 def _dt(dense_dtype: str):
@@ -266,54 +276,72 @@ def dense_col_pass(
 dense_col_pass = _devprof.instrument("ops.dense_col_pass", dense_col_pass)
 
 
-@partial(jax.jit, static_argnames=("n_rows_p", "n_cols_p", "dense_dtype"))
+def dead_slots(starts) -> int:
+    """Slots densify runs beyond the pairs, for these block starts: every
+    block walks its pairs in whole chunks. What raggedness costs."""
+    import numpy as np
+
+    per_block = np.diff(np.asarray(starts, np.int64))
+    return int((-per_block % _SLOT_CHUNK).sum())
+
+
+@partial(jax.jit, static_argnames=("n_rows_p", "n_cols_p"))
 def densify(
-    rows: jax.Array,  # (E,) int32
-    cols: jax.Array,  # (E,) int32
-    vals: jax.Array,  # (E,) f32
+    offsets: jax.Array,  # (E,) int32 — cell inside the pair's row block
+    values: jax.Array,  # (E,) storage dtype — what that cell holds
+    starts: jax.Array,  # (n_blocks + 1,) int32 — block b is [b], [b + 1]
     *,
     n_rows_p: int,
     n_cols_p: int,
-    dense_dtype: str = "bf16",
-    scale: float = 1.0,
 ) -> jax.Array:
-    """Scatter the COO edge list into the dense padded rating matrix —
-    ONCE per training set, on device (the matrix never crosses the host
-    link). int8 mode stores round(r·scale) (exactness gated by int8_scale
-    at staging). Requires unique (row, col) pairs — the staging gate
-    checks.
+    """Build the dense padded rating matrix from its unique pairs — ONCE
+    per training set, on device (the matrix never crosses the host
+    link). The pairs come grouped by ROW_BLOCK of rows and ascending by
+    cell inside a block (models/als.py `_group_unique_pairs`: the order
+    the staging gate's uniqueness sort leaves them in), the values
+    already as stored (int8 codes round(r·scale), exactness gated by
+    int8_scale at staging).
 
-    The scatter runs over DENSIFY_EDGE_CHUNK edges at a time into the
-    loop-carried matrix: XLA lays a 2-D scatter's (E, 2) index operand
-    out lane-padded to (E, 128) int32, which at ML-20M (20M edges) is a
-    10.2 GB temporary beside the 3.7 GB matrix by the compiler's memory
-    analysis; on a v5e the one-shot program reserved 9.69 GB and failed
-    RESOURCE_EXHAUSTED once 7 GB of the chip was held (PR 21). Chunked,
-    the temporary is chunk·512 B (0.7 GB in all at ML-20M)."""
-    st = storage_dtype(dense_dtype)
-    if st == jnp.int8:
-        q = jnp.round(vals * jnp.float32(scale)).astype(jnp.int8)
-    else:
-        q = vals.astype(st)
-    n_edges = rows.shape[0]
-    r = jnp.zeros((n_rows_p, n_cols_p), st)
-    if n_edges == 0:
-        return r
-    chunk = min(DENSIFY_EDGE_CHUNK, n_edges)
-    n_chunks = -(-n_edges // chunk)
-    pad = n_chunks * chunk - n_edges
-    # pad edges land one past the last row and are dropped
-    rows = jnp.pad(rows, (0, pad), constant_values=n_rows_p)
-    cols = jnp.pad(cols, (0, pad))
-    q = jnp.pad(q, (0, pad))
+    A scan over the row blocks: a step zeroes one flat block of
+    ROW_BLOCK · n_cols_p cells, scatters its own pairs into it — 1-D,
+    _SLOT_CHUNK slots a scatter, as many chunks as the block has pairs
+    for — and emits it as one contiguous slab; the stacked slabs ARE the
+    matrix. A chunk's slots past the block's end aim past the block (so
+    a chunk's indices still ascend, and XLA is told: it sorts them
+    otherwise) and are dropped: a ragged block costs dead slots
+    (`dead_slots`), and a block that holds most of the pairs takes more
+    chunks, nothing else.
+    The temporaries are one block and one chunk whatever the pair count:
+    a 2-D scatter's (E, 2) index operand, lane-padded to (E, 128) int32,
+    was 10.2 GB at ML-20M (PR 21)."""
+    cells = ROW_BLOCK * n_cols_p
+    # a block's last chunk may reach past the last pair
+    offsets = jnp.pad(offsets, (0, _SLOT_CHUNK))
+    values = jnp.pad(values, (0, _SLOT_CHUNK))
+    lane = jnp.arange(_SLOT_CHUNK, dtype=jnp.int32)
 
-    def body(i, r):
-        def sl(a):
-            return jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk)
+    def block(_, b):
+        start, end = starts[b], starts[b + 1]
 
-        return r.at[sl(rows), sl(cols)].set(sl(q), mode="drop")
+        def chunk(i, blk):
+            at = start + i * _SLOT_CHUNK
+            off = jax.lax.dynamic_slice_in_dim(offsets, at, _SLOT_CHUNK)
+            val = jax.lax.dynamic_slice_in_dim(values, at, _SLOT_CHUNK)
+            off = jnp.where(at + lane < end, off, cells)
+            return blk.at[off].set(
+                val, mode="drop", indices_are_sorted=True
+            )
 
-    return jax.lax.fori_loop(0, n_chunks, body, r)
+        blk = jax.lax.fori_loop(
+            0, -(-(end - start) // _SLOT_CHUNK), chunk,
+            jnp.zeros(cells, values.dtype),
+        )
+        return None, blk.reshape(ROW_BLOCK, n_cols_p)
+
+    _, r = jax.lax.scan(
+        block, None, jnp.arange(n_rows_p // ROW_BLOCK, dtype=jnp.int32)
+    )
+    return r.reshape(n_rows_p, n_cols_p)
 
 
 densify = _devprof.instrument("ops.densify", densify)

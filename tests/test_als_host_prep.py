@@ -261,7 +261,9 @@ def test_gate_verdict_and_span_attrs(monkeypatch, case, verdict, dense_dtype, sc
     assert sp.attrs == {
         "pairs": len(rows), "verdict": verdict,
         "dense_dtype": dense_dtype, "int8_scale": scale,
+        "sort_kept": verdict == "eligible",
     }
+    assert (gate.pairs is not None) is (verdict == "eligible")
 
 
 def test_gate_leaves_its_inputs_as_they_were(monkeypatch):
@@ -316,6 +318,7 @@ def test_a_dense_train_job_scans_for_its_scale_and_degrees_once(passes):
     assert passes == {"int8_scale": 1, "degrees": 1}
     [sp] = spans_since("als.stage.host_prep", before)
     assert sp.attrs["int8_scale_reused"] is True
+    assert sp.attrs["pairs_grouped_reused"] is True
     assert sp.attrs["degrees_reused"] is True
     assert np.all(np.isfinite(m.user_factors))
 
@@ -344,6 +347,7 @@ def test_train_grid_stages_with_no_degrees_handed_in(passes, monkeypatch, path):
     assert sp.attrs["degrees_reused"] is False
     if path == "dense":
         assert sp.attrs["int8_scale_reused"] is True
+        assert sp.attrs["pairs_grouped_reused"] is True
 
 
 @pytest.mark.parametrize("dense_dtype", ["auto", "int8", "f32"])
@@ -360,7 +364,10 @@ def test_a_bare_stage_dense_finds_its_own_scale_and_degrees(passes, dense_dtype)
     )
     assert staged.static_kwargs["scale"] == (1.0 if dense_dtype == "f32" else 2.0)
     [sp] = spans_since("als.stage.host_prep", before)
-    assert sp.attrs == {"int8_scale_reused": False, "degrees_reused": False}
+    assert sp.attrs == {
+        "int8_scale_reused": False, "pairs_grouped_reused": False,
+        "degrees_reused": False,
+    }
 
 
 def test_stage_dense_takes_the_gates_scale_as_it_would_find_it(passes):

@@ -35,39 +35,124 @@ def _pad_dims(n_users, n_items):
     return nup, nip
 
 
+def _whole_matrix_scatter(rows, cols, vals, nup, nip, dense_dtype, scale):
+    """`densify` as it was before PR 36, the oracle of the block build: one
+    2-D scatter of the pairs in the order given, the values rounded on the
+    device."""
+    import jax.numpy as jnp
+
+    st = dense_ops.storage_dtype(dense_dtype)
+    vals = jnp.asarray(vals)
+    if dense_dtype == "int8":
+        q = jnp.round(vals * jnp.float32(scale)).astype(jnp.int8)
+    else:
+        q = vals.astype(st)
+    return jnp.zeros((nup, nip), st).at[
+        jnp.asarray(rows), jnp.asarray(cols)
+    ].set(q)
+
+
+#: (n_users, n_items, pairs, the row blocks that hold pairs and their
+#: weights, slots a scatter or None for the module's own)
+_BUILD_CASES = {
+    # three row blocks, the pairs in shuffled order, several chunks a block
+    "shuffled_three_blocks": (5000, 70, 3001, None, 128),
+    "an_empty_row_block": (6000, 70, 2000, {0: 0.5, 2: 0.5}, 128),
+    "one_block_holds_most": (6000, 70, 4000, {0: 0.03, 1: 0.94, 2: 0.03}, 128),
+    "pairs_no_multiple_of_the_chunk": (100, 70, 901, None, 128),
+    "fewer_pairs_than_a_chunk": (100, 70, 50, None, 128),
+    "no_pairs": (100, 70, 0, None, 128),
+    "the_modules_own_chunk": (5000, 300, 70_000, None, None),
+}
+
+
+def _build_case(name):
+    nu, ni, n, blocks, chunk = _BUILD_CASES[name]
+    rng = np.random.RandomState(len(name))
+    if blocks is None:
+        key = rng.choice(nu * ni, n, replace=False)
+    else:
+        parts = []
+        for b, share in blocks.items():
+            lo = b * dense_ops.ROW_BLOCK
+            hi = min(nu, lo + dense_ops.ROW_BLOCK)
+            parts.append(
+                lo * ni + rng.choice((hi - lo) * ni, int(n * share), replace=False)
+            )
+        key = rng.permutation(np.concatenate(parts))
+    rows = (key // ni).astype(np.int32)
+    cols = (key % ni).astype(np.int32)
+    # signed half-star steps: exact in int8 at scale 2 and in bf16
+    vals = (rng.randint(1, 11, len(key)) / 2.0).astype(np.float32)
+    vals *= rng.choice([-1.0, 1.0], len(key)).astype(np.float32)
+    if name == "one_block_holds_most":
+        assert np.mean(rows // dense_ops.ROW_BLOCK == 1) > 0.9
+    return nu, ni, rows, cols, vals, chunk
+
+
+def _densify(rows, cols, vals, nup, nip, dense_dtype="f32", scale=1.0):
+    """The dense matrix of these pairs, as `stage_dense` builds it."""
+    import jax.numpy as jnp
+
+    pairs = als._group_unique_pairs(
+        rows, cols, vals, nup, nip, dense_dtype, scale
+    )
+    return dense_ops.densify(
+        jnp.asarray(pairs.offsets), jnp.asarray(pairs.values),
+        jnp.asarray(pairs.starts), n_rows_p=nup, n_cols_p=nip,
+    )
+
+
 class TestDensePasses:
     """Pass-level exactness (f32 mode) against a per-edge numpy fold."""
 
-    @pytest.mark.parametrize("dense_dtype,scale", [("int8", 2.0), ("f32", 1.0)])
-    def test_densify_in_edge_chunks_matches_numpy(
-        self, monkeypatch, dense_dtype, scale
+    @pytest.mark.parametrize(
+        "dense_dtype,scale", [("int8", 2.0), ("bf16", 1.0), ("f32", 1.0)]
+    )
+    @pytest.mark.parametrize("case", sorted(_BUILD_CASES))
+    def test_block_build_equals_numpy_and_the_whole_matrix_scatter(
+        self, monkeypatch, case, dense_dtype, scale
     ):
-        """densify scatters DENSIFY_EDGE_CHUNK edges per step (PR 21: the
-        one-shot scatter's index temporary was 10 GB at ML-20M); several
-        chunks with a ragged last one must build the same matrix."""
+        """The matrix built a row block at a time is `ref[rows, cols] =
+        vals · scale`, and to the bit the one the whole-matrix scatter it
+        replaced gave: the pairs are unique, so the order they are
+        written in cannot show."""
         import jax
         import jax.numpy as jnp
 
-        nu, ni = 100, 70
-        rows, cols, vals = _coo(nu, ni, 900, seed=4, signed=False)
-        vals = np.round(vals * 2) / 2  # half-star steps: exact at scale 2
+        nu, ni, rows, cols, vals, chunk = _build_case(case)
         nup, nip = _pad_dims(nu, ni)
+        build = dense_ops.densify
+        if chunk is not None:
+            monkeypatch.setattr(dense_ops, "_SLOT_CHUNK", chunk)
+            # the jit cache keys on shapes, not on the patched constant:
+            # trace the undecorated function afresh
+            build = jax.jit(
+                dense_ops.densify.__wrapped__.__wrapped__,
+                static_argnames=("n_rows_p", "n_cols_p"),
+            )
+        pairs = als._group_unique_pairs(
+            rows, cols, vals, nup, nip, dense_dtype, scale
+        )
+        r = np.asarray(build(
+            jnp.asarray(pairs.offsets), jnp.asarray(pairs.values),
+            jnp.asarray(pairs.starts), n_rows_p=nup, n_cols_p=nip,
+        ))
+        st = dense_ops.storage_dtype(dense_dtype)
+        assert r.dtype == st and r.shape == (nup, nip)
         ref = np.zeros((nup, nip), np.float32)
-        ref[rows, cols] = vals * scale
-        monkeypatch.setattr(dense_ops, "DENSIFY_EDGE_CHUNK", 128)
-        # the jit cache keys on shapes, not on the patched constant:
-        # trace the undecorated function afresh
-        fresh = jax.jit(
-            dense_ops.densify.__wrapped__.__wrapped__,
-            static_argnames=("n_rows_p", "n_cols_p", "dense_dtype"),
+        ref[rows, cols] = (vals * scale).astype(st)
+        np.testing.assert_array_equal(r.astype(np.float32), ref)
+        old = np.asarray(_whole_matrix_scatter(
+            rows, cols, vals, nup, nip, dense_dtype, scale
+        ))
+        bits = f"u{r.dtype.itemsize}"
+        np.testing.assert_array_equal(r.view(bits), old.view(bits))
+        per_block = np.bincount(rows // dense_ops.ROW_BLOCK, minlength=1)
+        step = dense_ops._SLOT_CHUNK
+        assert dense_ops.dead_slots(pairs.starts) == sum(
+            -(-n // step) * step - n for n in per_block.tolist()
         )
-        assert len(rows) % 128 != 0 and len(rows) > 3 * 128
-        r = fresh(
-            jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals),
-            n_rows_p=nup, n_cols_p=nip, dense_dtype=dense_dtype,
-            scale=scale,
-        )
-        np.testing.assert_array_equal(np.asarray(r, np.float32), ref)
 
     @pytest.mark.parametrize("implicit", [True, False])
     @pytest.mark.parametrize("signed", [False, True])
@@ -86,10 +171,7 @@ class TestDensePasses:
         yp[:ni] = y
         xp = np.zeros((nup, k), np.float32)
         xp[:nu] = x
-        r = dense_ops.densify(
-            jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals),
-            n_rows_p=nup, n_cols_p=nip, dense_dtype="f32",
-        )
+        r = _densify(rows, cols, vals, nup, nip)
 
         def w(v):
             if implicit:
@@ -342,6 +424,128 @@ class TestDenseSharded:
             m.user_factors.ravel(), m1.user_factors.ravel()
         )[0, 1]
         assert c > 0.999
+
+
+class TestGroupedPairs:
+    """models/als.py `_group_unique_pairs`: the job's one sort answers
+    uniqueness and keeps the pairs grouped by row block for `densify`."""
+
+    @pytest.mark.parametrize(
+        "dense_dtype,scale", [("int8", 2.0), ("bf16", 1.0), ("f32", 1.0)]
+    )
+    def test_sorted_pairs_are_grouped_by_row_block(self, dense_dtype, scale):
+        nu, ni, rows, cols, vals, _ = _build_case("an_empty_row_block")
+        nup, nip = _pad_dims(nu, ni)
+        kept = rows.copy(), cols.copy(), vals.copy()
+        pairs = als._group_unique_pairs(
+            rows, cols, vals, nup, nip, dense_dtype, scale
+        )
+        for now, then in zip((rows, cols, vals), kept):
+            np.testing.assert_array_equal(now, then)
+        per_block = np.bincount(
+            rows // dense_ops.ROW_BLOCK, minlength=nup // dense_ops.ROW_BLOCK
+        )
+        assert per_block[1] == 0
+        assert pairs.starts.dtype == pairs.offsets.dtype == np.int32
+        np.testing.assert_array_equal(
+            pairs.starts, np.concatenate([[0], np.cumsum(per_block)])
+        )
+        st = dense_ops.storage_dtype(dense_dtype)
+        assert pairs.values.dtype == st
+        assert pairs.grouped_for == (nup, nip, dense_dtype, scale)
+        # a block's offsets ascend, and the pairs are the ones handed in
+        block = np.repeat(np.arange(len(per_block)), per_block)
+        cell = block.astype(np.int64) * dense_ops.ROW_BLOCK * nip + pairs.offsets
+        assert np.all(np.diff(cell) > 0)
+        order = np.argsort(rows.astype(np.int64) * nip + cols)
+        np.testing.assert_array_equal(cell // nip, rows[order])
+        np.testing.assert_array_equal(cell % nip, cols[order])
+        np.testing.assert_array_equal(
+            pairs.values.astype(np.float32),
+            (vals[order] * scale).astype(st).astype(np.float32),
+        )
+
+    @pytest.mark.parametrize("dense_dtype", ["int8", "bf16", "f32"])
+    def test_a_duplicated_pair_is_no_grouping(self, monkeypatch, dense_dtype):
+        """Two pairs of one cell are neighbours after the sort whatever
+        they are worth: no grouping, verdict `duplicate_pairs`, and a bare
+        `stage_dense` refuses where it used to write one of the two."""
+        monkeypatch.setenv("PIO_DENSE_ALS", "1")
+        rows, cols, vals = _coo(seed=15)
+        rows[-1], cols[-1], vals[-1] = rows[7], cols[7], vals[7] + 1.0
+        nup, nip = _pad_dims(300, 180)
+        assert als._group_unique_pairs(
+            rows, cols, vals, nup, nip, dense_dtype, 2.0
+        ) is None
+        gate = als.dense_eligible(
+            rows, cols, vals, 300, 180, als.ALSParams(rank=6),
+            dense_dtype=dense_dtype,
+        )
+        assert gate.verdict == "duplicate_pairs" and gate.pairs is None
+        with pytest.raises(ValueError, match="unique"):
+            als.stage_dense(
+                rows, cols, vals, 300, 180, als.ALSParams(rank=6),
+                dense_dtype=dense_dtype,
+            )
+
+    def test_a_matrix_too_wide_for_the_block_build_is_refused(self, monkeypatch):
+        monkeypatch.setenv("PIO_DENSE_ALS", "1")
+        monkeypatch.setenv("PIO_DENSE_ALS_BYTES", str(1 << 40))
+        rows, cols, vals = _coo(seed=16)
+        n_items = dense_ops.MAX_DENSE_COLS
+        gate = als.dense_eligible(
+            rows, cols, vals, 300, n_items, als.ALSParams(rank=6)
+        )
+        assert gate.verdict == "bytes"
+        with pytest.raises(ValueError, match="pair key"):
+            als._group_unique_pairs(
+                rows, cols, vals, 2048, n_items, "int8", 2.0
+            )
+
+    @pytest.mark.parametrize("gate_dtype,reused", [("bf16", True), ("f32", False)])
+    def test_stage_dense_stages_the_same_arrays_with_a_gate_and_without(
+        self, monkeypatch, gate_dtype, reused
+    ):
+        """What the gate's sort grouped goes on to the device as a bare
+        call's own grouping would — unless it was grouped for another
+        storage, and then `stage_dense` groups its own."""
+        import time
+
+        from predictionio_tpu.obs.spans import get_default_recorder
+
+        monkeypatch.setenv("PIO_DENSE_ALS", "1")
+        rows, cols, vals = _coo(seed=17)
+        p = als.ALSParams(rank=6, iterations=2)
+        before = time.time()
+        gate = als.dense_eligible(
+            rows, cols, vals, 300, 180, p, dense_dtype=gate_dtype
+        )
+        assert gate and gate.pairs is not None
+        with monkeypatch.context() as m:
+            if reused:
+                m.setattr(
+                    als, "_group_unique_pairs",
+                    lambda *a: pytest.fail("sorted the pairs again"),
+                )
+            handed = als.stage_dense(rows, cols, vals, 300, 180, p, gate=gate)
+        bare = als.stage_dense(rows, cols, vals, 300, 180, p)
+        assert handed.static_kwargs == bare.static_kwargs
+        assert handed.static_kwargs["dense_dtype"] == "int8"
+        for a, b in zip(handed.device_args, bare.device_args):
+            if a is not None:
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        spans = {}
+        for sp in get_default_recorder().recent(before):
+            spans.setdefault(sp.name, []).append(sp.attrs)
+        assert spans["als.train.dense_eligible"][0]["sort_kept"] is True
+        assert [
+            a["pairs_grouped_reused"] for a in spans["als.stage.host_prep"]
+        ] == [reused, False]
+        nup, _ = _pad_dims(300, 180)
+        for attrs in spans["als.stage.densify"]:
+            assert attrs["blocks"] == nup // dense_ops.ROW_BLOCK
+            assert attrs["pairs"] == len(rows)
+            assert 0.0 < attrs["dead_slots"] < 1.0
 
 
 class TestFusedDenseKernel:
