@@ -57,20 +57,27 @@ def bridge_sharded_metrics(registry):
     "rows" = shipped as ids, a list of at most ROWLIST_MAX a query;
     "mask" = shipped as packed words, a dense filter or a wider list)
     and `sharded_exclusion_bytes_total`, the bytes of exclusion the host
-    shipped in either form. Returns the callback, for `unbridge`."""
+    shipped in either form. The two families are mounted by the first
+    such span — where a `ShardedRuntime` has been built and serves —
+    so a server whose engine has no sharded tier shows neither (ISSUE
+    37). Returns the callback, for `unbridge`."""
     from predictionio_tpu.obs import spans as _spans
 
-    batches = registry.counter(
-        "sharded_batches_total",
-        "batches through ShardedRuntime.recommend, by exclusion wire form",
-        labelnames=("form",),  # label-bound: literal none|rows|mask
-    )
-    nbytes = registry.counter(
-        "sharded_exclusion_bytes_total",
-        "bytes of exclusion (row lists or packed words) shipped",
-    )
+    mounted: list = []  # the two families, once the first span came
 
     def observe(sp):
+        if not mounted:
+            mounted.append(registry.counter(
+                "sharded_batches_total",
+                "batches through ShardedRuntime.recommend, by exclusion "
+                "wire form",
+                labelnames=("form",),  # label-bound: literal none|rows|mask
+            ))
+            mounted.append(registry.counter(
+                "sharded_exclusion_bytes_total",
+                "bytes of exclusion (row lists or packed words) shipped",
+            ))
+        batches, nbytes = mounted
         batches.inc(form=sp.attrs.get("form", "none"))
         nbytes.inc(float(sp.attrs.get("exclusion_bytes", 0)))
 

@@ -873,39 +873,49 @@ def _group_unique_pairs(
             f"a {n_rows_p} x {n_cols_p} matrix of {n} pairs does not fit "
             "the dense path's pair key"
         )
-    key = np.empty(n, np.int64)
-    for i in range(0, n, _PAIR_CHUNK):
-        j = min(i + _PAIR_CHUNK, n)
-        k = key[i:j]
-        row = rows[i:j] & (ROW_BLOCK - 1)  # ROW_BLOCK is a power of two
-        row *= n_cols_p
-        row += cols[i:j]
-        k[:] = rows[i:j] >> row_shift
-        k <<= off_bits
-        k |= row
-        k <<= low_bits
-        if by_index:
-            k |= np.arange(i, j)
-        elif st.itemsize == 1:
-            k |= np.rint(vals[i:j] * np.float32(scale)).astype(st).view(np.uint8)
-        else:
-            k |= vals[i:j].astype(st).view(np.uint16)
-    key.sort()
-    offsets = np.empty(n, np.int32)
-    low = np.empty(n, np.int64 if by_index else f"u{st.itemsize}")
-    off_mask, low_mask = (1 << off_bits) - 1, (1 << low_bits) - 1
-    last = -1
-    for i in range(0, n, _PAIR_CHUNK):
-        k = key[i : i + _PAIR_CHUNK]
-        cell = k >> low_bits
-        if cell[0] == last or (cell[1:] == cell[:-1]).any():
-            return None
-        last = cell[-1]
-        offsets[i : i + _PAIR_CHUNK] = cell & off_mask
-        low[i : i + _PAIR_CHUNK] = k & low_mask
-    starts = np.searchsorted(
-        key, np.arange(n_blocks + 1, dtype=np.int64) << (off_bits + low_bits)
-    ).astype(np.int32)
+    # the three phases are spans of their own (ISSUE 37), children of
+    # whichever span groups the pairs (`als.train.dense_eligible`; a bare
+    # `stage_dense`'s `als.stage.host_prep`): the key's build, the sort,
+    # the pass out of the sorted keys
+    with _spans.span("als.train.pair_key"):
+        key = np.empty(n, np.int64)
+        for i in range(0, n, _PAIR_CHUNK):
+            j = min(i + _PAIR_CHUNK, n)
+            k = key[i:j]
+            row = rows[i:j] & (ROW_BLOCK - 1)  # ROW_BLOCK is a power of two
+            row *= n_cols_p
+            row += cols[i:j]
+            k[:] = rows[i:j] >> row_shift
+            k <<= off_bits
+            k |= row
+            k <<= low_bits
+            if by_index:
+                k |= np.arange(i, j)
+            elif st.itemsize == 1:
+                k |= np.rint(
+                    vals[i:j] * np.float32(scale)
+                ).astype(st).view(np.uint8)
+            else:
+                k |= vals[i:j].astype(st).view(np.uint16)
+    with _spans.span("als.train.pair_sort"):
+        key.sort()
+    with _spans.span("als.train.pair_group"):
+        offsets = np.empty(n, np.int32)
+        low = np.empty(n, np.int64 if by_index else f"u{st.itemsize}")
+        off_mask, low_mask = (1 << off_bits) - 1, (1 << low_bits) - 1
+        last = -1
+        for i in range(0, n, _PAIR_CHUNK):
+            k = key[i : i + _PAIR_CHUNK]
+            cell = k >> low_bits
+            if cell[0] == last or (cell[1:] == cell[:-1]).any():
+                return None
+            last = cell[-1]
+            offsets[i : i + _PAIR_CHUNK] = cell & off_mask
+            low[i : i + _PAIR_CHUNK] = k & low_mask
+        starts = np.searchsorted(
+            key,
+            np.arange(n_blocks + 1, dtype=np.int64) << (off_bits + low_bits),
+        ).astype(np.int32)
     return GroupedPairs(
         offsets, vals[low] if by_index else low.view(st), starts,
         (n_rows_p, n_cols_p, dense_dtype, float(scale)),
@@ -970,7 +980,10 @@ def _dense_gate(
         return DenseGate("few_edges")
     known = dict(dense_dtype=dense_dtype)
     if dense_dtype == "bf16":  # the default: predict what auto picks
-        s_q = int8_scale(vals)
+        # a span of its own since the gate's span has children (ISSUE
+        # 37): what a parent does outside them counts as unattributed
+        with _spans.span("als.train.int8_scale"):
+            s_q = int8_scale(vals)
         known = dict(
             dense_dtype="bf16" if s_q is None else "int8",
             scale_known=True, int8_scale=s_q,
@@ -2426,17 +2439,27 @@ def recommend_serving(
         return (
             np.zeros((b, 0), np.float32), np.zeros((b, 0), np.int64),
         )
-    rows = jnp.asarray(np.asarray(user_indices, np.int32))
-    bits, ex = _exclusion_device_args(
-        serving, int(rows.shape[0]), exclude_mask, exclude_rows
-    )
+    # what crosses host->device before the program can be called is a
+    # span of its own (ISSUE 37: on the CPU the conversions are most of
+    # what `als.predict.device` holds outside the call, the wait and the
+    # copies back; the sharded tier's twin is `sharded.dispatch.put`)
+    with _spans.span("als.predict.put"):
+        rows = jnp.asarray(np.asarray(user_indices, np.int32))
+        bits, ex = _exclusion_device_args(
+            serving, int(rows.shape[0]), exclude_mask, exclude_rows
+        )
+        n_items = jnp.asarray(serving.n_items, jnp.int32)
     vals, idx = _serve_recommend_jit(
         rows, serving.users, serving.items, serving.user_scale,
-        serving.item_scale, bits, ex,
-        jnp.asarray(serving.n_items, jnp.int32),
+        serving.item_scale, bits, ex, n_items,
         k=k, mode=serving.mode,
     )
-    return np.asarray(vals), np.asarray(idx)
+    # the two copies back are a span of their own (ISSUE 37): the
+    # profiler's wrapper has blocked on the program already, so this is
+    # the device-to-host round trips alone (the sharded tier's twin is
+    # `sharded.copy_back`)
+    with _spans.span("als.predict.copy_back"):
+        return np.asarray(vals), np.asarray(idx)
 
 
 def similar_serving(

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from predictionio_tpu.obs import devprof as _devprof
+from predictionio_tpu.obs import spans as _spans
 from predictionio_tpu.ops.topk import NEG_INF, masked_top_k
 
 
@@ -568,22 +569,29 @@ def batch_score_topk(
     from predictionio_tpu.ops import recommend_pallas as _rp
 
     # the total starts as zeros made on the device (no round trip); all
-    # but the last call of the batch's plan only add into it
-    total = jnp.zeros((bsz * staged.rows_padded,), jnp.float32)
-    *leading, last = plan_calls(plan, bsz)
+    # but the last call of the batch's plan only add into it. Making it
+    # and handing over the batch's one packed input is a span of its own
+    # (ISSUE 37): what the host does for the device before the call
+    with _spans.span("ur.predict.put"):
+        total = jnp.zeros((bsz * staged.rows_padded,), jnp.float32)
+        *leading, last = plan_calls(plan, bsz)
+        packed = last.reshape(-1)
+        if exclude.array is not None:
+            packed = np.concatenate([packed, exclude.array.reshape(-1)])
+        packed = jnp.asarray(packed)
     for part in leading:
         total = _accumulate_jit(
             total, staged.items, staged.weights, jnp.asarray(part),
             rows_padded=staged.rows_padded,
         )
-    packed = last.reshape(-1)
-    if exclude.array is not None:
-        packed = np.concatenate([packed, exclude.array.reshape(-1)])
     out, _total = _score_topk_jit(
-        total, staged.items, staged.weights, jnp.asarray(packed),
+        total, staged.items, staged.weights, packed,
         staged.n_items_device,
         bsz=bsz, windows=len(last), rows_padded=staged.rows_padded,
         k=k, mode=_rp.resolve_mode(mode), form=exclude.form,
     )
-    out = np.asarray(out)
+    # the one copy back, a span of its own (ISSUE 37): the profiler's
+    # wrapper has blocked on the program, this is the round trip alone
+    with _spans.span("ur.predict.copy_back"):
+        out = np.asarray(out)
     return out[:, :k].view(np.float32), out[:, k:]

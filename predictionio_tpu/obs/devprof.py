@@ -20,9 +20,16 @@ records, per named executable:
 - compile seconds (diffed off jaxmon's compile listener around the
   first call per signature, which also keeps the first call's compile
   time OUT of the device-seconds accumulator);
-- invocation counts and cumulative device seconds (dispatch + result
-  ready — the wrapper blocks on the output, which every in-repo call
-  site consumes immediately anyway).
+- invocation counts and cumulative `device_seconds`: HOST wall time from
+  the call to its result being ready — the wrapper blocks on the output,
+  which every in-repo call site consumes immediately anyway — not time
+  on the device (the profiler's trace has that). It is the sum of
+  `launch_seconds` (the call itself: argument transfer and dispatch, a
+  first call's compile taken out) and `wait_seconds` (the block on the
+  output), the two intervals a call also opens as the spans
+  `device.launch` and `device.wait` with attr `program` = the wrapper's
+  name (ISSUE 37): children of the span the call runs under, state
+  spans (obs/spans.py) where it runs under none.
 
 From those it derives MFU (= executed FLOPs/s over the platform peak)
 and HBM %-of-roof against a per-generation peak table (env-overridable
@@ -58,6 +65,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, NamedTuple, Optional
 
 from predictionio_tpu.obs import jaxmon as _jaxmon
+from predictionio_tpu.obs import spans as _spans
 from predictionio_tpu.obs.registry import MetricsRegistry, get_default_registry
 from predictionio_tpu.utils.env import env_bool, env_opt_float, env_raw
 from predictionio_tpu.analysis import tsan as _tsan
@@ -229,7 +237,10 @@ class _Exec:
     scale_by: Optional[str] = None
     signatures: dict = field(default_factory=dict)  # sig key → _SigAnalysis
     invocations: int = 0
+    # host wall time, call to ready = launch_seconds + wait_seconds
     device_seconds: float = 0.0
+    launch_seconds: float = 0.0
+    wait_seconds: float = 0.0
     compile_seconds: float = 0.0
     flops_total: float = 0.0
     bytes_total: float = 0.0
@@ -259,7 +270,10 @@ def _leaf_sig(obj: Any) -> Any:
     shape = getattr(obj, "shape", None)
     dtype = getattr(obj, "dtype", None)
     if shape is not None and dtype is not None:
-        return ("arr", tuple(shape), str(dtype))
+        # the dtype object itself: it hashes and compares as its name
+        # does, and str() of one costs as much as the rest of a call's
+        # signature (the key is built on every profiled call)
+        return ("arr", tuple(shape), dtype)
     if isinstance(obj, float):
         # traced python-float scalars (λ, α sweeps) share one executable;
         # keying on the value would mint a spurious "signature" per sweep
@@ -357,6 +371,16 @@ def _under_trace() -> bool:
         return False
 
 
+def _phase_span(name: str, program: str):
+    """`device.launch` / `device.wait` of one call: a child of the span
+    the call runs under; a state span (in `stats()` and on the profiler's
+    plane, in no trace) where it runs under none — a warm-up, a bare
+    script — so that no call roots a trace of its own."""
+    if _spans.current_span_id() is not None:
+        return _spans.span(name, program=program)
+    return _spans.state_span(name, program=program)
+
+
 #: slot reservation for a signature whose first call is still in flight —
 #: exactly ONE caller runs the (possibly compile-paying) analysis; racing
 #: callers account their invocation with zero flops rather than also
@@ -414,7 +438,11 @@ class DeviceProfiler:
         except Exception:
             rec = None
         try:
-            out = fn(*args, **kwargs)
+            if rec is None:
+                out = fn(*args, **kwargs)
+            else:
+                with _phase_span("device.launch", wrapper.name):
+                    out = fn(*args, **kwargs)
         except BaseException:
             # the reserved slot must not poison the signature forever —
             # a later successful call should get to analyze it
@@ -426,13 +454,18 @@ class DeviceProfiler:
         if rec is None:
             return out
         try:
+            # the one more clock read that splits call-to-ready into the
+            # launch and the wait for the result
+            t1 = time.perf_counter()
             try:
                 import jax
 
-                out = jax.block_until_ready(out)
+                with _phase_span("device.wait", wrapper.name):
+                    out = jax.block_until_ready(out)
             except Exception:
                 pass
             dt = time.perf_counter() - t0
+            launch = t1 - t0
             compile_sec = 0.0
             analysis = None
             if new_sig or pending_race:
@@ -445,6 +478,8 @@ class DeviceProfiler:
                 # its compile) keep trace/lower/compile time out of the
                 # device-seconds accumulator so MFU reflects steady state
                 dt = max(0.0, dt - compile_sec)
+                # the compile ran inside the call, never in the wait
+                launch = min(dt, max(0.0, launch - compile_sec))
             if new_sig:
                 analysis = self._analyze(wrapper, fn, args, kwargs, out)
             scale = 1.0
@@ -470,6 +505,8 @@ class DeviceProfiler:
                 rec.invocations += 1
                 rec.last_sig = sig
                 rec.device_seconds += dt
+                rec.launch_seconds += launch
+                rec.wait_seconds += dt - launch
                 rec.flops_total += analysis.flops * scale
                 rec.bytes_total += analysis.bytes_accessed * scale
                 if analysis.dtype is not None:
@@ -606,6 +643,7 @@ class DeviceProfiler:
             if rec is None:
                 rec = self._execs[name] = _Exec(name)
             rec.device_seconds += max(0.0, seconds)
+            rec.wait_seconds += max(0.0, seconds)  # the caller's wait
             rec.invocations += invocations
 
     # -- reading ----------------------------------------------------------
@@ -655,6 +693,8 @@ class DeviceProfiler:
             "invocations": rec.invocations,
             "compile_seconds": round(rec.compile_seconds, 4),
             "device_seconds": round(rec.device_seconds, 6),
+            "launch_seconds": round(rec.launch_seconds, 6),
+            "wait_seconds": round(rec.wait_seconds, 6),
             "flops_per_call": latest.flops,
             "bytes_per_call": latest.bytes_accessed,
             "flops_total": rec.flops_total,
@@ -961,7 +1001,8 @@ def install_devprof_gauges(registry: MetricsRegistry) -> None:
     )
     registry.gauge_callback(
         "devprof_device_seconds_total",
-        "cumulative device seconds across profiled executables",
+        "cumulative host wall seconds, call to result ready, across "
+        "profiled executables (launch + wait; not time on the device)",
         lambda: _profiler.snapshot().device_seconds,
     )
     registry.gauge_callback(

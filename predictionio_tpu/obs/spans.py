@@ -49,6 +49,17 @@ module never imports jax itself — data-plane processes stay free of it.
   kept for `STATS_WINDOW_S`.
 - `collect()` gathers the same per-name seconds for one job (a train):
   every span recorded in the calling context while the block runs.
+- A STATE span (ISSUE 37) is a span of the process's own state, owned by
+  no request: the dispatcher collecting a batch, waiting for a slot,
+  holding no query. It carries the trace id `NO_TRACE`; `record()`
+  accounts it in `stats()` (and `state_span()` annotates it on the
+  profiler's plane) but it never enters the trace store — a root span of
+  its own would be tail-sampled into `/debug/traces`, several a batch,
+  and evict the requests the store is for.
+- `defer(spans)` holds a request's after-the-fact child spans for the
+  server's handler, which records them (`record_all`, one hold of the
+  lock) and then the root span once the reply's last byte is out: the
+  client does not wait for the recorder.
 
 Thread-safety: one lock guards the recorder's maps; span context lives
 in ContextVars, so keep-alive handler threads and the micro-batch
@@ -99,6 +110,10 @@ def set_current_span(span_id: Optional[str]) -> contextvars.Token:
 
 def reset_current_span(token: contextvars.Token) -> None:
     _current_span_id.reset(token)
+
+
+#: the trace id of a state span: accounted and annotated, never stored
+NO_TRACE = ""
 
 
 @dataclass
@@ -162,6 +177,36 @@ def _covered(intervals: list[tuple[float, float]], lo: float, hi: float) -> floa
             total += end - start
             reach = end
     return total
+
+
+# after-the-fact child spans of the request this thread serves, held back
+# until its reply is out (ISSUE 37): recording is work on the request's
+# thread, and the client need not wait for it
+_deferred: contextvars.ContextVar[Optional[list]] = contextvars.ContextVar(
+    "pio_span_deferred", default=None
+)
+
+
+def open_deferral(pending: list) -> contextvars.Token:
+    """From here on `defer()` in this context appends to `pending`; the
+    caller records it (`SpanRecorder.record_all`) before the root span
+    that finalizes the trace, and resets the token."""
+    return _deferred.set(pending)
+
+
+def close_deferral(token: contextvars.Token) -> None:
+    _deferred.reset(token)
+
+
+def defer(spans: list["Span"]) -> None:
+    """Completed child spans of the request being served: held for the
+    server's handler to record once the reply is written
+    (`utils/http.py`), recorded now where no handler holds them."""
+    pending = _deferred.get()
+    if pending is None:
+        get_default_recorder().record_all(spans)
+    else:
+        pending.extend(spans)
 
 
 @contextmanager
@@ -321,10 +366,58 @@ class SpanRecorder:
                 _tracing.reset_trace_id(trace_token)
             self.record(sp, finalize=local_parent is None)
 
+    @contextmanager
+    def state_span(self, name: str, **attrs: Any) -> Iterator[Span]:
+        """Open a state span (module docstring) on the thread whose state
+        it is: in `stats()` and, under a running profiler, on the trace's
+        host plane like any real span; in no trace. It neither reads nor
+        sets the ambient trace and span: nothing nests under it. A state
+        known only after the fact is recorded as
+        `record(Span(trace_id=NO_TRACE, ...))` — the same path."""
+        sp = Span(
+            trace_id=NO_TRACE, span_id=new_span_id(), name=name,
+            start=time.time(), attrs=dict(attrs),
+            start_mono=time.monotonic(),
+        )
+        annotation = _trace_annotation(name)
+        if annotation is not None:
+            annotation.__enter__()
+        try:
+            yield sp
+        except BaseException:
+            sp.error = True
+            raise
+        finally:
+            sp.duration = time.monotonic() - sp.start_mono
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            self.record(sp)
+
     def record(self, sp: Span, finalize: bool = False) -> None:
         """Record a completed span. `finalize=True` marks the end of this
         process's fragment of the trace: the tail-sampling decision runs
-        over everything recorded for the trace so far."""
+        over everything recorded for the trace so far. A state span
+        (`trace_id` `NO_TRACE`) is accounted and goes no further."""
+        self._observe(sp)
+        with self._lock:
+            self._file(sp, finalize)
+
+    def record_all(self, spans: list[Span]) -> None:
+        """`record()` of several completed spans, none of them finalizing,
+        under ONE hold of the lock: a request's after-the-fact child spans
+        (the dispatcher's seven a query). Several handler threads woken by
+        one batch record at the same instant, and every acquisition of a
+        contended lock is a chance to lose the interpreter to the next."""
+        for sp in spans:
+            self._observe(sp)
+        with self._lock:
+            for sp in spans:
+                self._file(sp, False)
+
+    def _observe(self, sp: Span) -> None:
+        """What `record` does with a span before it takes the lock: the
+        metric bridge, and the monotonic start of a span built from an
+        epoch one."""
         bridge = self._bridges.get(sp.name)
         if bridge is not None:
             try:
@@ -333,59 +426,62 @@ class SpanRecorder:
                 pass  # a metrics hiccup must never break the request
         if sp.start_mono == 0.0:
             sp.start_mono = time.monotonic() - (time.time() - sp.start)
-        with self._lock:
-            self._account(sp)
-            self._recent.append(sp)
-            kept = self._traces.get(sp.trace_id)
-            if kept is not None:
-                # trace already deemed interesting: merge late fragments
-                # (e.g. the client span completing after the remote
-                # server's fragment finalized) straight in — capped, and
-                # WITHOUT refreshing eviction age, so a client pinning
-                # one request id can neither grow it unbounded nor keep
-                # it alive forever
-                if len(kept["spans"]) < self.max_spans_per_trace:
-                    kept["spans"].append(sp)
-                return
-            frag = self._active.setdefault(sp.trace_id, [])
-            if len(frag) < self.max_spans_per_trace:
-                frag.append(sp)
-            if not finalize:
-                # orphan guard: fragments whose root never completes
-                # (handler crashed pre-response) must not grow unbounded
+
+    def _file(self, sp: Span, finalize: bool) -> None:  # lint: holds=_lock
+        self._account(sp)
+        if sp.trace_id == NO_TRACE:
+            return
+        self._recent.append(sp)
+        kept = self._traces.get(sp.trace_id)
+        if kept is not None:
+            # trace already deemed interesting: merge late fragments
+            # (e.g. the client span completing after the remote
+            # server's fragment finalized) straight in — capped, and
+            # WITHOUT refreshing eviction age, so a client pinning
+            # one request id can neither grow it unbounded nor keep
+            # it alive forever
+            if len(kept["spans"]) < self.max_spans_per_trace:
+                kept["spans"].append(sp)
+            return
+        frag = self._active.setdefault(sp.trace_id, [])
+        if len(frag) < self.max_spans_per_trace:
+            frag.append(sp)
+        if not finalize:
+            # orphan guard: fragments whose root never completes
+            # (handler crashed pre-response) must not grow unbounded
+            while len(self._active) > max(64, 4 * self.max_traces):
+                self._active.popitem(last=False)
+            return
+        spans = self._active.pop(sp.trace_id)
+        forced_cap = self._forced.pop(sp.trace_id, None)
+        reason = (
+            f"capture:{forced_cap}" if forced_cap
+            else self._keep_reason(spans)
+        )
+        if reason is None:
+            if sp.parent_span_id is not None:
+                # the finalizing span has a REMOTE parent: it roots
+                # only this process's fragment, not the trace. When
+                # two servers share a process (query server +
+                # storage daemon in tests / single-box deploys), the
+                # daemon's server span completes MID-request — a
+                # definitive drop here would amputate the outer
+                # request's already-recorded queue/assemble spans
+                # from its eventual slow/error trace. Defer: leave
+                # the fragment active for the true root's finalize
+                # to re-evaluate over the union. (The orphan guard
+                # below bounds fragments whose root never comes.)
+                self._active[sp.trace_id] = spans
                 while len(self._active) > max(64, 4 * self.max_traces):
                     self._active.popitem(last=False)
-                return
-            spans = self._active.pop(sp.trace_id)
-            forced_cap = self._forced.pop(sp.trace_id, None)
-            reason = (
-                f"capture:{forced_cap}" if forced_cap
-                else self._keep_reason(spans)
-            )
-            if reason is None:
-                if sp.parent_span_id is not None:
-                    # the finalizing span has a REMOTE parent: it roots
-                    # only this process's fragment, not the trace. When
-                    # two servers share a process (query server +
-                    # storage daemon in tests / single-box deploys), the
-                    # daemon's server span completes MID-request — a
-                    # definitive drop here would amputate the outer
-                    # request's already-recorded queue/assemble spans
-                    # from its eventual slow/error trace. Defer: leave
-                    # the fragment active for the true root's finalize
-                    # to re-evaluate over the union. (The orphan guard
-                    # below bounds fragments whose root never comes.)
-                    self._active[sp.trace_id] = spans
-                    while len(self._active) > max(64, 4 * self.max_traces):
-                        self._active.popitem(last=False)
-                return
-            self._traces[sp.trace_id] = {"spans": spans, "reason": reason}
-            if forced_cap is not None:
-                cap = self._captures.get(forced_cap)
-                if cap is not None and sp.trace_id not in cap["trace_ids"]:
-                    cap["trace_ids"].append(sp.trace_id)
-            while len(self._traces) > self.max_traces:
-                self._traces.popitem(last=False)
+            return
+        self._traces[sp.trace_id] = {"spans": spans, "reason": reason}
+        if forced_cap is not None:
+            cap = self._captures.get(forced_cap)
+            if cap is not None and sp.trace_id not in cap["trace_ids"]:
+                cap["trace_ids"].append(sp.trace_id)
+        while len(self._traces) > self.max_traces:
+            self._traces.popitem(last=False)
 
     def _account(self, sp: Span) -> None:  # lint: holds=_lock
         """Windowed statistics, before tail sampling: every span counts.
@@ -679,3 +775,8 @@ def get_default_recorder() -> SpanRecorder:
 def span(name: str, **kwargs: Any):
     """`with span("stage", key=val):` on the default recorder."""
     return get_default_recorder().span(name, **kwargs)
+
+
+def state_span(name: str, **attrs: Any):
+    """`with state_span("dispatch.collect"):` on the default recorder."""
+    return get_default_recorder().state_span(name, **attrs)

@@ -59,9 +59,16 @@ class JsonHandler(BaseHTTPRequestHandler):
         self._trace_token = None
         self._span_token = None
         self._deadline_token = None
+        self._root_span = None
+        # child spans built after the fact on this thread (the
+        # dispatcher's, a query) wait here for the reply to be out
+        self._deferred_spans: list = []
+        deferral = _obs_spans.open_deferral(self._deferred_spans)
         try:
             super().handle_one_request()
         finally:
+            _obs_spans.close_deferral(deferral)
+            self._record_spans()  # whatever a reply never sent left behind
             # keep-alive reuses this thread: clear the request's trace id,
             # span context and deadline so the next request (or idle
             # logging) can't inherit them
@@ -197,19 +204,32 @@ class JsonHandler(BaseHTTPRequestHandler):
         extra = getattr(self.server, "span_attrs", None)
         if extra:
             attrs.update(extra)
-        _obs_spans.get_default_recorder().record(
-            _obs_spans.Span(
-                trace_id=self._trace_id,
-                span_id=self._span_id,
-                parent_span_id=getattr(self, "_parent_span", None),
-                name="server.request",
-                start=getattr(self, "_start_wall", time.time()),
-                duration=duration,
-                attrs=attrs,
-                error=status >= 500,
-            ),
-            finalize=True,
+        # built here, where the request's duration is taken; recorded by
+        # `_record_spans` once the body is written
+        self._root_span = _obs_spans.Span(
+            trace_id=self._trace_id,
+            span_id=self._span_id,
+            parent_span_id=getattr(self, "_parent_span", None),
+            name="server.request",
+            start=getattr(self, "_start_wall", time.time()),
+            duration=duration,
+            attrs=attrs,
+            error=status >= 500,
         )
+
+    def _record_spans(self) -> None:
+        """The request's deferred child spans, then its root span, which
+        finalizes the trace — after the reply's last byte (ISSUE 37): the
+        recorder's lock and a dozen dict operations are work on this
+        thread that the client need not wait for."""
+        recorder = _obs_spans.get_default_recorder()
+        pending = getattr(self, "_deferred_spans", None)
+        if pending:
+            recorder.record_all(pending)
+            del pending[:]
+        root, self._root_span = getattr(self, "_root_span", None), None
+        if root is not None:
+            recorder.record(root, finalize=True)
 
     def _serve_metrics(self) -> None:
         """GET /metrics: this server's registry merged with the
@@ -552,9 +572,14 @@ class JsonHandler(BaseHTTPRequestHandler):
         # the write loses that race — observed as a missing
         # http_requests_total child on single-vCPU hosts). The final
         # body-write syscall falls outside the measured duration;
-        # headers are already on the wire by this point.
+        # headers are already on the wire by this point. The request's
+        # SPANS go into the recorder after the write: a reader of
+        # /debug/traces polls for a trace, as it always had to
         self._record_request(status)
-        self.wfile.write(data)
+        try:
+            self.wfile.write(data)
+        finally:
+            self._record_spans()
 
 
 class ThreadedServer(ThreadingHTTPServer):

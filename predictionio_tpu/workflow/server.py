@@ -697,11 +697,25 @@ class _Pending:
     on an answer nobody will read (ISSUE 4 satellite: the old tuple
     entries had no way to be withdrawn). `tenant` (ISSUE 6) tags the
     entry for the fair scheduler's per-tenant sub-queue and for the
-    dispatcher's device-seconds accounting."""
+    dispatcher's device-seconds accounting.
+
+    The entry also carries its own timeline (ISSUE 37), one
+    `time.perf_counter()` stamp a hand-off, written by the thread that
+    makes the hand-off and read by the handler thread once the future
+    is done (`_BatchDispatcher._record_query_spans`): `t_submit` (the
+    handler puts it), `t_taken` (the loop thread takes it), `t_closed`
+    and `closed_by` (its batch closes), `t_run` (`_run_group` starts on
+    a pool thread), `t_dispatched` (batch_predict is back), `t_serve` /
+    `t_served` (its turn in the serve loop), `t_resolved` (its future
+    is set). A stamp still None names a hand-off that never happened.
+    `held` is the dispatcher's count of it (`_hold` / `_release`)."""
 
     __slots__ = (
         "query", "runtime", "fut", "t_submit", "tctx", "deadline",
-        "cancelled", "tenant",
+        "cancelled", "tenant", "held", "t_taken", "t_closed", "closed_by",
+        "t_run", "batch_size", "dev_span_id", "t_dispatched",
+        "dispatch_error", "t_serve", "t_served", "serve_error",
+        "t_resolved",
     )
 
     def __init__(
@@ -715,6 +729,12 @@ class _Pending:
         self.deadline = deadline
         self.cancelled = False
         self.tenant = tenant
+        self.held = False
+        self.t_taken = self.t_closed = self.closed_by = None
+        self.t_run = self.batch_size = self.dev_span_id = None
+        self.t_dispatched = self.t_serve = self.t_served = None
+        self.dispatch_error = self.serve_error = False
+        self.t_resolved = None
 
 
 class _BatchDispatcher:
@@ -777,6 +797,13 @@ class _BatchDispatcher:
         self._queue = FairQueue(
             weight_of=getattr(owner, "tenant_weight", None)
         )
+        # the queries this dispatcher holds: submitted, not yet answered
+        # or abandoned. Each stretch at zero is the span
+        # `dispatch.no_work` (ISSUE 37): the share of an idle chip that
+        # is the traffic's, not the program's
+        self._held_lock = threading.Lock()
+        self._held = 0  # guarded-by: _held_lock
+        self._idle_since: Optional[float] = time.monotonic()  # guarded-by: _held_lock
         self._stop = threading.Event()
         self._thread = threading.Thread(
             target=self._loop, name="query-batcher", daemon=True
@@ -806,21 +833,162 @@ class _BatchDispatcher:
         p = _Pending(
             query, runtime, fut, time.perf_counter(), tctx, deadline, tenant
         )
+        self._hold(p)
         self._queue.put(p)
         wait = timeout
         if deadline is not None:
             wait = min(wait, max(0.0, deadline - _t.monotonic()))
-        # opened AFTER tctx was taken: the dispatcher's per-query spans
-        # stay children of the request's own span, beside this one
-        with _spans.span("query.wait", server="query"):
-            try:
-                return fut.result(timeout=wait)
-            except _FutTimeout:
-                p.cancelled = True  # drain must not burn device time on this
-                raise DeadlineExceeded(
-                    "query abandoned: deadline passed while queued for "
-                    "dispatch"
-                )
+        t_woke = None
+        try:
+            # opened AFTER tctx was taken: the dispatcher's per-query
+            # spans stay children of the request's own span, beside this
+            # one
+            with _spans.span("query.wait", server="query"):
+                try:
+                    return fut.result(timeout=wait)
+                except _FutTimeout:
+                    p.cancelled = True  # drain must not burn device time on this
+                    self._release(p)
+                    raise DeadlineExceeded(
+                        "query abandoned: deadline passed while queued for "
+                        "dispatch"
+                    )
+                finally:
+                    t_woke = time.perf_counter()  # inside query.wait
+        finally:
+            if not p.cancelled:
+                self._record_query_spans(p, t_woke)
+
+    def _record_query_spans(self, p: _Pending, t_woke: float) -> None:
+        """The query's wait, span by span, from the stamps its entry
+        gathered — built HERE, by the handler thread once its future is
+        done (ISSUE 37): on the request's own thread and in its own
+        trace before the root span finalizes it; off the batch's
+        critical path (a record a query on the worker, under the
+        recorder's lock, is paid by every query of the batch) and,
+        behind an HTTP handler, off the reply's too (`spans.defer`:
+        recorded after the last byte). All of them lie inside the
+        request's `query.wait`.
+
+        `batch.queue_wait` (submit -> `_run_group` starts; it feeds
+        batch_queue_wait_seconds through the recorder's bridge) is the
+        sum, to the clock's resolution, of `batch.pickup` (put -> the
+        loop thread takes the entry), `batch.assemble` (taken -> its
+        batch closes; `closed_by` says which branch of `_collect` closed
+        it) and `batch.slot_wait` (closed -> a pool thread runs it: the
+        `_inflight` semaphore and the pool). Then `batch.device_dispatch`
+        (all of batch_predict, under the span id the batch's storage
+        RPCs were parented to), `batch.result_transfer` (this query's
+        turn in the serve loop) and `query.wake` (future set -> this
+        thread running again)."""
+        tid, parent = p.tctx
+        if tid is None or p.t_run is None:
+            return
+        # one reading of the three clocks places every stamp on the
+        # epoch (the trace store's) and on time.monotonic() (stats()')
+        now_wall, now_mono, now = (
+            time.time(), time.monotonic(), time.perf_counter()
+        )
+        children: list = []
+
+        def child(name: str, start: float, end: float,
+                  span_id: Optional[str] = None, error: bool = False,
+                  **attrs: Any) -> None:
+            children.append(_spans.Span(
+                trace_id=tid,
+                span_id=span_id or _spans.new_span_id(),
+                parent_span_id=parent,
+                name=name, start=now_wall - (now - start),
+                start_mono=now_mono - (now - start), duration=end - start,
+                attrs={
+                    "server": "query", "batch_size": p.batch_size, **attrs
+                },
+                error=error,
+            ))
+
+        child("batch.queue_wait", p.t_submit, p.t_run)
+        if p.t_taken is not None and p.t_closed is not None:
+            child("batch.pickup", p.t_submit, p.t_taken)
+            child("batch.assemble", p.t_taken, p.t_closed,
+                  closed_by=p.closed_by)
+            child("batch.slot_wait", p.t_closed, p.t_run)
+        if p.t_dispatched is not None:
+            child("batch.device_dispatch", p.t_run, p.t_dispatched,
+                  span_id=p.dev_span_id, error=p.dispatch_error)
+        if p.t_served is not None:
+            child("batch.result_transfer", p.t_serve, p.t_served,
+                  error=p.serve_error)
+        if p.t_resolved is not None:
+            child("query.wake", p.t_resolved, t_woke)
+        # held for the HTTP handler to record after the reply's last
+        # byte, under one hold of the recorder's lock (recorded here and
+        # now where no handler serves): the handler threads of a batch
+        # wake together, and seven contended acquisitions a query before
+        # the reply cost serve-sharded's median 3.6 % (PERF.md, PR 37)
+        _spans.defer(children)
+
+    # -- the count of held queries (`dispatch.no_work`) ----------------------
+    def _hold(self, p: _Pending) -> None:
+        """Count a submitted entry, before it is queued; the entry that
+        ends a stretch with none held records the stretch."""
+        now = time.monotonic()
+        with self._held_lock:
+            idle_from, self._idle_since = self._idle_since, None
+            self._held += 1
+            p.held = True
+        if idle_from is not None:
+            self._record_no_work(idle_from, now)
+
+    def _release(self, p: _Pending) -> None:
+        """Let go of an entry that was answered or abandoned (once; an
+        entry put on the queue by hand was never counted)."""
+        with self._held_lock:
+            if not p.held:
+                return
+            p.held = False
+            self._held -= 1
+            if self._held == 0:
+                self._idle_since = time.monotonic()
+
+    def _flush_no_work(self) -> None:
+        """Record the stretch with no query held so far and start the
+        next piece at this instant: the idle loop thread does this every
+        0.2 s (and `stop()` once), so a piece lands in the second of
+        `stats()` it was spent in however long the server stays empty."""
+        now = time.monotonic()
+        with self._held_lock:
+            if self._idle_since is None:
+                return
+            idle_from, self._idle_since = self._idle_since, now
+        self._record_no_work(idle_from, now)
+
+    @staticmethod
+    def _record_no_work(start_mono: float, end_mono: float) -> None:
+        # after the fact (the stretch starts on a worker's thread and
+        # ends on a handler's, so no annotation can cover it), as a
+        # state span: in stats(), in no trace
+        if end_mono <= start_mono:
+            return
+        _spans.get_default_recorder().record(_spans.Span(
+            trace_id=_spans.NO_TRACE, span_id=_spans.new_span_id(),
+            name="dispatch.no_work",
+            start=time.time() - (time.monotonic() - start_mono),
+            duration=end_mono - start_mono, start_mono=start_mono,
+            attrs={"server": "query"},
+        ))
+
+    def _resolve(self, p: _Pending, result: Any = None,
+                 exc: Optional[BaseException] = None) -> None:
+        """Answer an entry, once: stamp the instant (its `query.wake`
+        starts here), let go of it, wake its handler."""
+        if p.fut.done():
+            return
+        p.t_resolved = time.perf_counter()
+        self._release(p)
+        if exc is not None:
+            p.fut.set_exception(exc)
+        else:
+            p.fut.set_result(result)
 
     def stop(self) -> None:
         self._stop.set()
@@ -835,8 +1003,8 @@ class _BatchDispatcher:
                 p = self._queue.get_nowait()
             except _q.Empty:
                 break
-            if not p.fut.done():
-                p.fut.set_exception(RuntimeError("query server stopped"))
+            self._resolve(p, exc=RuntimeError("query server stopped"))
+        self._flush_no_work()
 
     def _run_group(self, rt: "EngineRuntime", group: list) -> None:
         # last-chance shed: entries can be cancelled (or expire) while
@@ -847,7 +1015,6 @@ class _BatchDispatcher:
             return
         queries = [(i, p.query) for i, p in enumerate(group)]
         t0 = time.perf_counter()
-        now_wall = time.time()
         registry = getattr(self.owner, "metrics", None)
         recorder = _spans.get_default_recorder()
         # query-triggered capture (ISSUE 8 satellite): an armed
@@ -859,42 +1026,17 @@ class _BatchDispatcher:
             for p in group:
                 if p.tctx[0]:
                     recorder.force_keep(p.tctx[0], capture_id)
-        first_submit = min(p.t_submit for p in group)
         # pre-mint the per-query device span ids: storage RPCs issued
         # DURING batch_predict (e.g. UR history fetches) must parent
-        # under a device span, so its id has to exist before the call
+        # under a device span, so its id has to exist before the call.
+        # The per-query spans themselves are built from the entry's
+        # stamps by its handler thread (`_record_query_spans`): nothing
+        # is recorded a query on this thread, where the batch waits
         dev_ids = [
             _spans.new_span_id() if p.tctx[0] else None for p in group
         ]
-
-        def _child(i: int, name: str, start: float, dur: float,
-                   span_id: Optional[str] = None, error: bool = False,
-                   **attrs: Any) -> None:
-            tid, parent = group[i].tctx
-            if tid is None:
-                return
-            recorder.record(_spans.Span(
-                trace_id=tid,
-                span_id=span_id or _spans.new_span_id(),
-                parent_span_id=parent,
-                name=name, start=start, duration=dur,
-                attrs={"server": "query", "batch_size": len(group), **attrs},
-                error=error,
-            ))
-
-        for i, p in enumerate(group):
-            t_submit = p.t_submit
-            # queue-wait: submit() to device dispatch — the cost the
-            # adaptive window adds, isolated from device time so batching
-            # PRs can trade one against the other on measured numbers.
-            # The span feeds batch_queue_wait_seconds via the recorder's
-            # metric bridge (declared in QueryServer.__init__) — one
-            # observation per query, same as the old direct observe.
-            _child(i, "batch.queue_wait",
-                   now_wall - (t0 - t_submit), t0 - t_submit)
-            # batch-assemble: the drain window, first arrival to dispatch
-            _child(i, "batch.assemble",
-                   now_wall - (t0 - first_submit), t0 - first_submit)
+        for p, dev_id in zip(group, dev_ids):
+            p.t_run, p.batch_size, p.dev_span_id = t0, len(group), dev_id
         if registry is not None:
             registry.histogram(
                 "batch_size", "queries per coalesced device batch",
@@ -959,10 +1101,10 @@ class _BatchDispatcher:
                     predict_sp.attrs["jit_compiles"] = (
                         compile_snapshot()[0] - compiles
                     )
-                self.last_batch_sec = time.perf_counter() - t0
-                for i in range(len(group)):
-                    _child(i, "batch.device_dispatch", now_wall,
-                           self.last_batch_sec, span_id=dev_ids[i])
+                t_done = time.perf_counter()
+                self.last_batch_sec = t_done - t0
+                for p in group:
+                    p.t_dispatched = t_done
                 if registry is not None:
                     # one observation per coalesced BATCH (the per-query
                     # device spans above share its wall time; bridging
@@ -997,31 +1139,29 @@ class _BatchDispatcher:
                     "batch.serve", server="query", batch_size=len(group),
                 ):
                     for i, p in enumerate(group):
-                        t_s = time.perf_counter()
+                        # result-transfer/serve: per-query fetch +
+                        # combinator, between the two stamps
+                        p.t_serve = time.perf_counter()
                         try:
                             result = rt.serving.serve(
                                 p.query, [pa[i] for pa in per_algo]
                             )
                         except Exception as e:  # serve failure is per-query
-                            dur = time.perf_counter() - t_s
-                            _child(i, "batch.result_transfer",
-                                   time.time() - dur, dur, error=True)
-                            p.fut.set_exception(e)
+                            p.t_served = time.perf_counter()
+                            p.serve_error = True
+                            self._resolve(p, exc=e)
                             continue
-                        dur = time.perf_counter() - t_s
-                        # result-transfer/serve: per-query fetch + combinator
-                        _child(i, "batch.result_transfer",
-                               time.time() - dur, dur)
-                        p.fut.set_result(result)
+                        p.t_served = time.perf_counter()
+                        self._resolve(p, result=result)
             except Exception:
                 # one bad query must not poison the batch: retry
                 # individually so each waiter gets its own result or its
                 # own error. The failed device span is recorded errored
                 # so tail sampling always retains these traces.
-                for i in range(len(group)):
-                    _child(i, "batch.device_dispatch", now_wall,
-                           time.perf_counter() - t0, span_id=dev_ids[i],
-                           error=True)
+                t_failed = time.perf_counter()
+                for p in group:
+                    if not p.fut.done():
+                        p.t_dispatched, p.dispatch_error = t_failed, True
                 charge = getattr(
                     self.owner, "charge_device_seconds", None
                 )
@@ -1050,12 +1190,11 @@ class _BatchDispatcher:
                             algo.predict(model, p.query)
                             for algo, model in zip(rt.algorithms, rt.models)
                         ]
-                        p.fut.set_result(
-                            rt.serving.serve(p.query, predictions)
+                        self._resolve(
+                            p, result=rt.serving.serve(p.query, predictions)
                         )
                     except Exception as e:
-                        if not p.fut.done():
-                            p.fut.set_exception(e)
+                        self._resolve(p, exc=e)
                     finally:
                         # fallback predicts are real device work: debit
                         # the post-paid device-seconds bucket here too,
@@ -1074,164 +1213,195 @@ class _BatchDispatcher:
 
     def _loop(self) -> None:
         import queue as _q
-        import time as _t
 
         while not self._stop.is_set():
             try:
                 first = self._queue.get(timeout=0.2)
             except _q.Empty:
+                self._flush_no_work()
                 continue
-            # Drain policy (VERDICT r3 #3; its measured basis is gone —
-            # ROADMAP S4 re-measures): grab everything already queued;
-            # once the queue is dry, dispatch IMMEDIATELY if nothing is
-            # in flight (the pipeline is idle — any wait is pure dead
-            # time, and a lone idle query sees zero added window
-            # latency). With buckets in
-            # flight the two modes differ (ISSUE 11):
-            #
-            # - continuous (default): keep ADMITTING arrivals into this
-            #   assembling bucket until an in-flight bucket actually
-            #   RETIRES — then ours is next onto the freed slot. No
-            #   fixed window: a bucket never sits closed at the
-            #   semaphore while new arrivals queue behind it. The
-            #   max_window/1.2×batch-time bound survives only as a
-            #   wedged-batch backstop.
-            # - windowed: linger up to that bound for more arrivals
-            #   (the PR-2 behavior; ROADMAP D4). With
-            #   tenants active, the tenant_drain knob ends the linger
-            #   as soon as every still-backlogged tenant is represented
-            #   in the bucket — one group per tenant per round beats a
-            #   full bucket for fairness latency.
-            batch = [first]
-            retired_mark = self._retired
-            round_t0 = _t.monotonic()
-            hard_deadline = _t.monotonic() + max(
-                self.max_window_s,
-                getattr(self, "last_batch_sec", 0.0) * 1.2,
-            )
-            # continuous mode's backstop exists ONLY for a wedged
-            # in-flight batch (device hang, in-flight accounting leak):
-            # closing early never serves anyone sooner — the bucket
-            # just parks at the semaphore while later arrivals fragment
-            # into a second device round-trip. Before the FIRST batch
-            # retires there is no last_batch_sec measurement, so give
-            # an unmeasured flight several windows before declaring it
-            # wedged; shed_dead and the clients' own deadlines still
-            # bound how long any held query can suffer.
-            wedge_deadline = _t.monotonic() + max(
-                10.0 * self.max_window_s,
-                getattr(self, "last_batch_sec", 0.0) * 1.2,
-            )
-            while len(batch) < self.max_batch:
-                skip = self._admission_skip(batch)
-                try:
-                    batch.append(self._queue.get_nowait(skip=skip))
-                    continue
-                except _q.Empty:
-                    pass
-                with self._active_lock:
-                    active = self._active
-                if active == 0:
-                    # pipeline idle: dispatch once the arrival stream
-                    # pauses. Under recent load the pause threshold
-                    # scales with the measured batch time (a closed-loop
-                    # response burst spreads over tens of ms; splitting
-                    # it costs a full device round-trip per fragment);
-                    # after a quiet second it drops back to min_window so
-                    # sporadic queries keep near-zero added latency.
-                    patience = self.min_window_s
-                    if (
-                        _t.monotonic() - getattr(self, "_last_dispatch", 0.0)
-                        < 1.0
-                    ):
-                        patience = max(
-                            patience,
-                            min(
-                                0.1 * getattr(self, "last_batch_sec", 0.0),
-                                0.02,
-                            ),
-                        )
-                    try:
-                        # the admission cap still applies: a capped
-                        # tenant's overflow waits for the next bucket
-                        # even when the pipeline just went idle
-                        batch.append(
-                            self._queue.get(timeout=patience, skip=skip)
-                        )
-                        continue
-                    except _q.Empty:
-                        break
-                if self.batching == "continuous":
-                    if self._retired != retired_mark:
-                        break  # a bucket retired — dispatch onto the slot
-                    if _t.monotonic() >= wedge_deadline:
-                        break  # wedged in-flight batch: don't hold queries
-                    try:
-                        batch.append(
-                            self._queue.get(timeout=0.002, skip=skip)
-                        )
-                    except _q.Empty:
-                        pass
-                    continue
-                if self.tenant_drain and (
-                    _t.monotonic() - round_t0 >= self.min_window_s
+            first.t_taken = time.perf_counter()
+            # the loop thread's own two states, a state span each a batch
+            # (ISSUE 37; in stats() and on a profiler trace, in no
+            # request's trace): collecting — the first entry taken until
+            # the batch closes — and handing the closed batch over — the
+            # wait for an `_inflight` slot. Under a profiler the idle
+            # chip of those seconds carries the dispatcher's state, not
+            # the name of whichever handler thread waits shortest
+            with _spans.state_span("dispatch.collect", server="query") as sp:
+                batch, closed_by = self._collect(first)
+                sp.attrs["size"] = len(batch)
+                sp.attrs["closed_by"] = closed_by
+            with _spans.state_span("dispatch.slot", server="query"):
+                t_closed = time.perf_counter()
+                for p in batch:
+                    p.t_closed, p.closed_by = t_closed, closed_by
+                self._hand_over(batch)
+
+    def _collect(self, first: _Pending) -> tuple[list, str]:
+        """Assemble one batch around `first`; returns it with the branch
+        that closed it (`closed_by`, on `dispatch.collect` and on every
+        query's `batch.assemble`).
+
+        Drain policy (VERDICT r3 #3): grab everything already queued;
+        once the queue is dry, dispatch IMMEDIATELY if nothing is in
+        flight (`idle_pipeline`: any wait is pure dead time, and a lone
+        idle query sees zero added window latency). What each branch
+        costs is measured where it happens: `closed_by` splits
+        `batch.assemble` (a query's wait from the loop thread taking it
+        to its batch closing) by the branch that ended it. With buckets
+        in flight the two modes differ (ISSUE 11):
+
+        - continuous (default): keep ADMITTING arrivals into this
+          assembling bucket until an in-flight bucket actually RETIRES
+          (`retired`) — then ours is next onto the freed slot. No fixed
+          window: a bucket never sits closed at the semaphore while new
+          arrivals queue behind it. The max_window/1.2×batch-time bound
+          survives only as a wedged-batch backstop (`wedge`).
+        - windowed: linger up to that bound for more arrivals (`window`;
+          the PR-2 behavior; ROADMAP D4). With tenants active, the
+          tenant_drain knob ends the linger as soon as every
+          still-backlogged tenant is represented in the bucket — one
+          group per tenant per round beats a full bucket for fairness
+          latency.
+
+        `full`: the bucket reached max_batch."""
+        import queue as _q
+
+        batch = [first]
+
+        def take(p: _Pending) -> None:
+            p.t_taken = time.perf_counter()
+            batch.append(p)
+
+        retired_mark = self._retired
+        round_t0 = time.monotonic()
+        hard_deadline = time.monotonic() + max(
+            self.max_window_s,
+            getattr(self, "last_batch_sec", 0.0) * 1.2,
+        )
+        # continuous mode's backstop exists ONLY for a wedged
+        # in-flight batch (device hang, in-flight accounting leak):
+        # closing early never serves anyone sooner — the bucket
+        # just parks at the semaphore while later arrivals fragment
+        # into a second device round-trip. Before the FIRST batch
+        # retires there is no last_batch_sec measurement, so give
+        # an unmeasured flight several windows before declaring it
+        # wedged; shed_dead and the clients' own deadlines still
+        # bound how long any held query can suffer.
+        wedge_deadline = time.monotonic() + max(
+            10.0 * self.max_window_s,
+            getattr(self, "last_batch_sec", 0.0) * 1.2,
+        )
+        while len(batch) < self.max_batch:
+            skip = self._admission_skip(batch)
+            try:
+                take(self._queue.get_nowait(skip=skip))
+                continue
+            except _q.Empty:
+                pass
+            with self._active_lock:
+                active = self._active
+            if active == 0:
+                # pipeline idle: dispatch once the arrival stream
+                # pauses. Under recent load the pause threshold
+                # scales with the measured batch time (a closed-loop
+                # response burst spreads over tens of ms; splitting
+                # it costs a full device round-trip per fragment);
+                # after a quiet second it drops back to min_window so
+                # sporadic queries keep near-zero added latency.
+                patience = self.min_window_s
+                if (
+                    time.monotonic() - getattr(self, "_last_dispatch", 0.0)
+                    < 1.0
                 ):
-                    # only after the base window: closing on a
-                    # momentarily-dry queue would ship one-tenant
-                    # rounds before the other tenants' arrivals land
-                    backlog = self._queue.backlogged()
-                    present = {p.tenant for p in batch}
-                    tenancy_active = bool(
-                        (present | backlog) - {None}
+                    patience = max(
+                        patience,
+                        min(
+                            0.1 * getattr(self, "last_batch_sec", 0.0),
+                            0.02,
+                        ),
                     )
-                    if tenancy_active and backlog <= present:
-                        break  # every backlogged tenant has a group
-                remaining = hard_deadline - _t.monotonic()
-                if remaining <= 0:
-                    break
                 try:
-                    batch.append(
-                        self._queue.get(timeout=min(remaining, 0.002))
-                    )
+                    # the admission cap still applies: a capped
+                    # tenant's overflow waits for the next bucket
+                    # even when the pipeline just went idle
+                    take(self._queue.get(timeout=patience, skip=skip))
+                    continue
+                except _q.Empty:
+                    return batch, "idle_pipeline"
+            if self.batching == "continuous":
+                if self._retired != retired_mark:
+                    # a bucket retired — dispatch onto the slot
+                    return batch, "retired"
+                if time.monotonic() >= wedge_deadline:
+                    # wedged in-flight batch: don't hold queries
+                    return batch, "wedge"
+                try:
+                    take(self._queue.get(timeout=0.002, skip=skip))
                 except _q.Empty:
                     pass
-            self.window_s = self.min_window_s  # status display only
-            # drain-time shedding (ISSUE 4): entries whose client already
-            # gave up (cancelled) or whose deadline passed while queued
-            # are dropped HERE — before the backpressure semaphore and
-            # the device dispatch, which is exactly the time they'd waste
-            ready = self._shed_dead(batch)
-            # group by runtime snapshot: queries spanning a /reload are
-            # served by the runtime they were extracted against
-            groups: dict[int, tuple[Any, list]] = {}
-            for p in ready:
-                groups.setdefault(id(p.runtime), (p.runtime, []))[1].append(p)
-            for rt, group in groups.values():
-                # poll the semaphore so a stop() during backpressure
-                # doesn't leave this thread blocked forever
-                acquired = False
-                while not self._stop.is_set():
-                    if self._inflight.acquire(timeout=0.2):
-                        acquired = True
-                        break
-                if acquired:
-                    try:
-                        with self._active_lock:
-                            self._active += 1
-                        self._last_dispatch = _t.monotonic()
-                        self._pool.submit(
-                            self._run_group_released, rt, group
-                        )
-                        continue
-                    except RuntimeError:  # pool already shut down
-                        with self._active_lock:
-                            self._active -= 1
-                        self._inflight.release()
-                for p in group:
-                    if not p.fut.done():
-                        p.fut.set_exception(
-                            RuntimeError("query server stopped")
-                        )
+                continue
+            if self.tenant_drain and (
+                time.monotonic() - round_t0 >= self.min_window_s
+            ):
+                # only after the base window: closing on a
+                # momentarily-dry queue would ship one-tenant
+                # rounds before the other tenants' arrivals land
+                backlog = self._queue.backlogged()
+                present = {p.tenant for p in batch}
+                tenancy_active = bool(
+                    (present | backlog) - {None}
+                )
+                if tenancy_active and backlog <= present:
+                    # every backlogged tenant has a group
+                    return batch, "window"
+            remaining = hard_deadline - time.monotonic()
+            if remaining <= 0:
+                return batch, "window"
+            try:
+                take(self._queue.get(timeout=min(remaining, 0.002)))
+            except _q.Empty:
+                pass
+        return batch, "full"
+
+    def _hand_over(self, batch: list) -> None:
+        """A closed batch onto the pool, a group a runtime, each behind
+        the `_inflight` semaphore."""
+        self.window_s = self.min_window_s  # status display only
+        # drain-time shedding (ISSUE 4): entries whose client already
+        # gave up (cancelled) or whose deadline passed while queued
+        # are dropped HERE — before the backpressure semaphore and
+        # the device dispatch, which is exactly the time they'd waste
+        ready = self._shed_dead(batch)
+        # group by runtime snapshot: queries spanning a /reload are
+        # served by the runtime they were extracted against
+        groups: dict[int, tuple[Any, list]] = {}
+        for p in ready:
+            groups.setdefault(id(p.runtime), (p.runtime, []))[1].append(p)
+        for rt, group in groups.values():
+            # poll the semaphore so a stop() during backpressure
+            # doesn't leave this thread blocked forever
+            acquired = False
+            while not self._stop.is_set():
+                if self._inflight.acquire(timeout=0.2):
+                    acquired = True
+                    break
+            if acquired:
+                try:
+                    with self._active_lock:
+                        self._active += 1
+                    self._last_dispatch = time.monotonic()
+                    self._pool.submit(
+                        self._run_group_released, rt, group
+                    )
+                    continue
+                except RuntimeError:  # pool already shut down
+                    with self._active_lock:
+                        self._active -= 1
+                    self._inflight.release()
+            for p in group:
+                self._resolve(p, exc=RuntimeError("query server stopped"))
 
     def _admission_skip(self, batch: list) -> Optional[set]:
         """Tenants whose slots in the ASSEMBLING bucket are used up
@@ -1265,10 +1435,9 @@ class _BatchDispatcher:
             if p.cancelled or (
                 p.deadline is not None and now_m >= p.deadline
             ):
-                if not p.fut.done():
-                    p.fut.set_exception(DeadlineExceeded(
-                        "deadline expired before device dispatch"
-                    ))
+                self._resolve(p, exc=DeadlineExceeded(
+                    "deadline expired before device dispatch"
+                ))
                 shed = getattr(self.owner, "count_shed", None)
                 if shed is not None:
                     shed("cancelled" if p.cancelled else "expired_in_queue")
@@ -1348,7 +1517,9 @@ class QueryServer(ServerProcess):
             "batch.queue_wait", self._queue_wait_bridge
         )
         # the sharded tier's batches and exclusion bytes, counted off
-        # its own `sharded.dispatch` span the same way (ISSUE 27)
+        # its own `sharded.dispatch` span the same way (ISSUE 27); the
+        # two counters are mounted on this registry by the first such
+        # span, so only where a ShardedRuntime serves (ISSUE 37)
         from predictionio_tpu.fleet import bridge_sharded_metrics
 
         self._sharded_bridge = bridge_sharded_metrics(self.metrics)
