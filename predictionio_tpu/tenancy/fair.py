@@ -95,13 +95,21 @@ class FairQueue:
     def get(
         self, timeout: Optional[float] = None,
         skip: Optional[set] = None,
+        unless: Optional[Callable[[], bool]] = None,
     ) -> Any:
         """Pop the next item by DRR. `skip` (ISSUE 14 satellite —
         continuous-batching admission caps) names tenant keys whose
         items must stay queued this call: when every backlogged tenant
         is skipped the call behaves as empty, so the dispatcher's
         assembling bucket keeps room for the un-capped tenants'
-        arrivals instead of filling with one tenant's backlog."""
+        arrivals instead of filling with one tenant's backlog.
+
+        `unless` (ISSUE 38) is the caller's other reason to stop
+        waiting: with nothing to pop and `unless()` true the call
+        raises ``queue.Empty`` as at its timeout. It is read under the
+        queue's lock, before the first wait and after every `wake()`,
+        so whoever changes what it reads and THEN calls `wake()` is
+        never missed."""
         deadline = (
             None if timeout is None else time.monotonic() + timeout
         )
@@ -112,6 +120,8 @@ class FairQueue:
                         return self._pop_locked(skip)
                     except _q.Empty:
                         pass  # only skipped tenants queued: wait
+                if unless is not None and unless():
+                    raise _q.Empty
                 if deadline is None:
                     self._not_empty.wait()
                 else:
@@ -119,6 +129,13 @@ class FairQueue:
                     if remaining <= 0:
                         raise _q.Empty
                     self._not_empty.wait(remaining)
+
+    def wake(self) -> None:
+        """Wake a blocked `get` without an item, to read its `unless`
+        again: the condition an arrival notifies is the one the
+        consumer sleeps on, so one wait serves both events."""
+        with self._not_empty:
+            self._not_empty.notify_all()
 
     def _pop_locked(self, skip: Optional[set] = None) -> Any:  # lint: holds=_not_empty
         if not self._size:

@@ -63,30 +63,41 @@ class QueryServerConfig:
     # micro-batching: coalesce concurrent queries into one device program
     # (the "one model, many queries → batched inference queue" hard part,
     # SURVEY.md §7 — no reference analogue; JVM serving was per-request).
-    # ON by default — the measured fast path IS the default path. The
-    # window adapts between batch_window_ms and max_window_ms: it grows
-    # when drains saturate max_batch (queue pressure) and decays back
-    # when traffic is light, so a single idle query still sees ~2 ms
-    # added latency while a 32-client burst batches deeply.
+    # ON by default — the measured fast path IS the default path.
+    # `batch_window_ms` / `max_window_ms` are WINDOWED mode's timers (its
+    # window adapts between the two: it grows when drains saturate
+    # max_batch and decays back when traffic is light). Continuous mode
+    # (the default) closes a batch on events and waits on no window:
+    # `max_window_ms` is left to it only as the scale of `wedge`, the
+    # backstop for a batch whose answers never come (10 × max_window_ms,
+    # or 1.2 × the last batch if that is longer).
     micro_batch: bool = True
     batch_window_ms: float = 2.0
     max_window_ms: float = 60.0
     max_batch: int = 64
-    # in-flight device batches (VERDICT r3 #3): the dispatcher loop hands
-    # each drained batch to a worker pool and immediately collects the
-    # next one, so batch N+1's device dispatch overlaps batch N's result
-    # fetch and serve/JSON — XLA queues programs on the device stream.
-    # 1 restores the old strictly-serial behavior.
+    # batches on the worker pool at once (VERDICT r3 #3). Short of a
+    # backlog that fills whole batches (`full` hands over at once, and
+    # XLA queues the programs on the device stream), at most ONE of them
+    # is between hand-over and its answers (its batch_predict has not
+    # returned: nothing runs concurrently inside a ShardedRuntime or the
+    # resident correlators); the others are FINISHING — serving their
+    # queries, setting their futures — each on its own slot beside the
+    # next batch's put, launch and program (span `batch.finish`). A
+    # closed batch that finds every slot taken waits for one
+    # (`batch.slot_wait`). 1 lets a batch start only when the one ahead
+    # has let go of the pool.
     pipeline_depth: int = 4
-    # continuous batching (ISSUE 11): while device buckets are in
-    # flight, newly-arrived queries keep joining the ASSEMBLING bucket,
-    # which dispatches the moment an in-flight bucket retires (a
-    # pipeline slot actually frees) instead of when a fixed window
-    # expires — the old windowed drain could close a bucket at the
-    # window bound and then sit blocked on the semaphore while new
-    # arrivals queued behind it unbatched. "windowed" restores the
-    # PR-2 adaptive-window behavior (ROADMAP D4: nothing measures the
-    # two against each other any more).
+    # continuous batching (ISSUE 11; the close rule is ISSUE 38's): a
+    # batch closes at once when the queue is dry and no batch awaits its
+    # answers (`closed_by` idle_pipeline: a lone query on an idle
+    # pipeline waits for nothing); while one does, arrivals keep joining
+    # the ASSEMBLING batch, which closes the instant that batch's
+    # batch_predict returns (answers_ready) — not when a window expires,
+    # not when the worker retires. The loop thread sleeps on the one
+    # condition an arrival and a batch's answers both notify.
+    # "windowed" restores the PR-2 adaptive-window behavior with its own
+    # timers (ROADMAP D4: nothing measures the two against each other
+    # any more).
     batching: str = "continuous"
     # adaptive continuous-batching admission (ISSUE 14 satellite,
     # carried serving-kernel follow-up): while a bucket ASSEMBLES in
@@ -102,7 +113,7 @@ class QueryServerConfig:
     # soon as every still-backlogged tenant is represented in the
     # assembling bucket — fairness needs one group per tenant per
     # round, not a full bucket. Windowed mode only (continuous mode's
-    # retirement signal supersedes it); kept a separate knob so it is
+    # answers-ready signal supersedes it); kept a separate knob so it is
     # testable in isolation.
     tenant_drain: bool = True
     # remote log shipping (reference CreateServer.scala:441-452 --log-url):
@@ -741,17 +752,18 @@ class _BatchDispatcher:
     """Coalesces concurrent queries into one batch_predict device call.
 
     Handler threads submit a supplemented query and block on a Future; a
-    single dispatcher thread drains the queue every `window_ms` (or at
-    `max_batch`) and hands the batch to a `pipeline_depth`-wide worker
-    pool. The pool is the pipelining seam (VERDICT r3 #3): while worker
-    A blocks fetching batch N's device results (the GIL is released in
-    the transfer wait), worker B dispatches batch N+1 onto the device
-    stream and the dispatcher thread is already collecting batch N+2 —
-    the device never idles waiting for serve/JSON of a finished batch.
-    A semaphore bounds in-flight batches so queue pressure backs up into
-    the drain loop (deeper adaptive windows) instead of unbounded device
-    memory. The reference never solved this (its serving hot path keeps
-    the "TODO: Parallelize" comment, CreateServer.scala:514-517)."""
+    single dispatcher thread assembles the queued queries into batches
+    (`_collect` has the rule that closes one) and hands each to a
+    `pipeline_depth`-wide worker pool. The pool is the pipelining seam
+    (VERDICT r3 #3): the moment worker A's batch_predict has returned
+    batch N's answers, the dispatcher thread closes batch N+1 and worker
+    B puts it on the device while A still serves N's queries and wakes
+    their handlers — the device never idles waiting for serve/JSON of a
+    finished batch, and holds two batches' programs only when a backlog
+    fills whole batches. A semaphore bounds the batches on the pool so
+    queue pressure backs up into the drain loop instead of unbounded
+    device memory. The reference never solved this (its serving hot path
+    keeps the "TODO: Parallelize" comment, CreateServer.scala:514-517)."""
 
     def __init__(
         self,
@@ -783,13 +795,25 @@ class _BatchDispatcher:
         self.tenant_drain = tenant_drain
         self.admission_cap = max(0, int(admission_cap))
         self.pipeline_depth = max(1, pipeline_depth)
-        self._retired = 0  # buckets retired — continuous mode's signal
         self._pool = ThreadPoolExecutor(
             max_workers=self.pipeline_depth, thread_name_prefix="query-batch"
         )
         self._inflight = threading.BoundedSemaphore(self.pipeline_depth)
         self._active_lock = threading.Lock()
-        self._active = 0
+        self._active = 0  # guarded-by: _active_lock
+        # the groups between hand-over and their answers (batch_predict
+        # has not returned), by id(): continuous mode's close rule reads
+        # whether there is one (ISSUE 38)
+        self._awaiting: set[int] = set()  # guarded-by: _active_lock
+        self.last_batch_sec = 0.0
+        self._last_dispatch = 0.0  # windowed mode's pause heuristic
+        registry = getattr(owner, "metrics", None)
+        self._closed_counter = None if registry is None else registry.counter(
+            "dispatch_batches_closed_total",
+            "batches the dispatcher closed, by the branch of the close "
+            "rule that closed them",
+            ("closed_by",),  # label-bound: _collect's five literals
+        )
         # weighted-fair queueing (ISSUE 6): per-tenant sub-queues drained
         # by deficit round robin replace the single FIFO, so one hog
         # tenant's backlog cannot starve the batch assembler. With no
@@ -992,6 +1016,7 @@ class _BatchDispatcher:
 
     def stop(self) -> None:
         self._stop.set()
+        self._queue.wake()  # the loop thread sleeps on the queue's condition
         self._thread.join(timeout=1.0)
         self._pool.shutdown(wait=False)
         # fail any waiters still queued so their handler threads don't
@@ -1009,8 +1034,9 @@ class _BatchDispatcher:
     def _run_group(self, rt: "EngineRuntime", group: list) -> None:
         # last-chance shed: entries can be cancelled (or expire) while
         # the batch waits on the backpressure semaphore — re-filter at
-        # the moment device time is about to be spent (ISSUE 4)
-        group = self._shed_dead(group)
+        # the moment device time is about to be spent (ISSUE 4). In
+        # place: the list is the one the hand-over counted (`_awaiting`)
+        group[:] = self._shed_dead(group)
         if not group:
             return
         queries = [(i, p.query) for i, p in enumerate(group)]
@@ -1102,57 +1128,17 @@ class _BatchDispatcher:
                         compile_snapshot()[0] - compiles
                     )
                 t_done = time.perf_counter()
-                self.last_batch_sec = t_done - t0
-                for p in group:
-                    p.t_dispatched = t_done
-                if registry is not None:
-                    # one observation per coalesced BATCH (the per-query
-                    # device spans above share its wall time; bridging
-                    # them would inflate the count). The name says
-                    # "device"; what it times is all of batch_predict on
-                    # the host's clock — lookups, the device pass, the
-                    # decode. The device pass alone is als.predict.device.
-                    registry.histogram(
-                        "batch_device_seconds",
-                        "wall time of batch_predict per coalesced batch: "
-                        "host lookups, device pass and decode",
-                    ).observe(self.last_batch_sec)
-                self.owner.bookkeep_predict(self.last_batch_sec, len(group))
-                # per-tenant device-seconds accounting (ISSUE 6): each
-                # tenant in the batch is charged its per-query share of
-                # the measured device time — the post-paid debit the
-                # device-seconds quota enforces at the next admission
-                charge = getattr(
-                    self.owner, "charge_device_seconds", None
-                )
-                if charge is not None and group_tenant is not None:
-                    per_query = self.last_batch_sec / len(group)
-                    counts: dict[str, int] = {}
-                    for p in group:
-                        if p.tenant:
-                            counts[p.tenant] = counts.get(p.tenant, 0) + 1
-                    for tid, n in counts.items():
-                        charge(tid, per_query * n)
-                # a trace of its own: the first reply below lets its
-                # request finish, and sample, while this loop still runs
-                with _spans.detached(), _spans.span(
-                    "batch.serve", server="query", batch_size=len(group),
+                # the answers are host arrays and the device is free: the
+                # assembling batch closes on this (ISSUE 38), and what is
+                # left of this one — the bookkeeping, every query's
+                # serve, its future, its handler's wake — runs on this
+                # slot of the pool beside the next batch's put, launch
+                # and program. `batch.finish` is that stretch
+                self._answers_ready(group)
+                with _spans.state_span(
+                    "batch.finish", server="query", batch_size=len(group),
                 ):
-                    for i, p in enumerate(group):
-                        # result-transfer/serve: per-query fetch +
-                        # combinator, between the two stamps
-                        p.t_serve = time.perf_counter()
-                        try:
-                            result = rt.serving.serve(
-                                p.query, [pa[i] for pa in per_algo]
-                            )
-                        except Exception as e:  # serve failure is per-query
-                            p.t_served = time.perf_counter()
-                            p.serve_error = True
-                            self._resolve(p, exc=e)
-                            continue
-                        p.t_served = time.perf_counter()
-                        self._resolve(p, result=result)
+                    self._finish_group(rt, group, per_algo, t_done, registry)
             except Exception:
                 # one bad query must not poison the batch: retry
                 # individually so each waiter gets its own result or its
@@ -1211,12 +1197,69 @@ class _BatchDispatcher:
             if tok_t is not None:
                 _tracing.reset_trace_id(tok_t)
 
+    def _finish_group(
+        self, rt: "EngineRuntime", group: list, per_algo: list,
+        t_done: float, registry: Any,
+    ) -> None:
+        """What is left of a batch once batch_predict has returned its
+        answers at `t_done`: its bookkeeping, then every query's serve
+        and future. The span `batch.finish` (`_run_group`) covers it."""
+        self.last_batch_sec = t_done - group[0].t_run
+        for p in group:
+            p.t_dispatched = t_done
+        if registry is not None:
+            # one observation per coalesced BATCH (the per-query device
+            # spans share its wall time; bridging them would inflate the
+            # count). The name says "device"; what it times is all of
+            # batch_predict on the host's clock — lookups, the device
+            # pass, the decode. The device pass alone is
+            # als.predict.device.
+            registry.histogram(
+                "batch_device_seconds",
+                "wall time of batch_predict per coalesced batch: "
+                "host lookups, device pass and decode",
+            ).observe(self.last_batch_sec)
+        self.owner.bookkeep_predict(self.last_batch_sec, len(group))
+        # per-tenant device-seconds accounting (ISSUE 6): each tenant in
+        # the batch is charged its per-query share of the measured
+        # device time — the post-paid debit the device-seconds quota
+        # enforces at the next admission
+        charge = getattr(self.owner, "charge_device_seconds", None)
+        if charge is not None and group[0].tenant is not None:
+            per_query = self.last_batch_sec / len(group)
+            counts: dict[str, int] = {}
+            for p in group:
+                if p.tenant:
+                    counts[p.tenant] = counts.get(p.tenant, 0) + 1
+            for tid, n in counts.items():
+                charge(tid, per_query * n)
+        # a trace of its own: the first reply below lets its request
+        # finish, and sample, while this loop still runs
+        with _spans.detached(), _spans.span(
+            "batch.serve", server="query", batch_size=len(group),
+        ):
+            for i, p in enumerate(group):
+                # result-transfer/serve: per-query fetch + combinator,
+                # between the two stamps
+                p.t_serve = time.perf_counter()
+                try:
+                    result = rt.serving.serve(
+                        p.query, [pa[i] for pa in per_algo]
+                    )
+                except Exception as e:  # serve failure is per-query
+                    p.t_served = time.perf_counter()
+                    p.serve_error = True
+                    self._resolve(p, exc=e)
+                    continue
+                p.t_served = time.perf_counter()
+                self._resolve(p, result=result)
+
     def _loop(self) -> None:
         import queue as _q
 
         while not self._stop.is_set():
             try:
-                first = self._queue.get(timeout=0.2)
+                first = self._queue.get(timeout=0.2, unless=self._stop.is_set)
             except _q.Empty:
                 self._flush_no_work()
                 continue
@@ -1232,6 +1275,8 @@ class _BatchDispatcher:
                 batch, closed_by = self._collect(first)
                 sp.attrs["size"] = len(batch)
                 sp.attrs["closed_by"] = closed_by
+            if self._closed_counter is not None:
+                self._closed_counter.inc(closed_by=closed_by)
             with _spans.state_span("dispatch.slot", server="query"):
                 t_closed = time.perf_counter()
                 for p in batch:
@@ -1240,63 +1285,112 @@ class _BatchDispatcher:
 
     def _collect(self, first: _Pending) -> tuple[list, str]:
         """Assemble one batch around `first`; returns it with the branch
-        that closed it (`closed_by`, on `dispatch.collect` and on every
-        query's `batch.assemble`).
+        that closed it (`closed_by`: on `dispatch.collect`, on every
+        query's `batch.assemble`, in `dispatch_batches_closed_total`).
 
-        Drain policy (VERDICT r3 #3): grab everything already queued;
-        once the queue is dry, dispatch IMMEDIATELY if nothing is in
-        flight (`idle_pipeline`: any wait is pure dead time, and a lone
-        idle query sees zero added window latency). What each branch
-        costs is measured where it happens: `closed_by` splits
-        `batch.assemble` (a query's wait from the loop thread taking it
-        to its batch closing) by the branch that ended it. With buckets
-        in flight the two modes differ (ISSUE 11):
+        Continuous mode (the default) closes a batch on events (ISSUE
+        38). It takes everything already queued, and then:
 
-        - continuous (default): keep ADMITTING arrivals into this
-          assembling bucket until an in-flight bucket actually RETIRES
-          (`retired`) — then ours is next onto the freed slot. No fixed
-          window: a bucket never sits closed at the semaphore while new
-          arrivals queue behind it. The max_window/1.2×batch-time bound
-          survives only as a wedged-batch backstop (`wedge`).
-        - windowed: linger up to that bound for more arrivals (`window`;
-          the PR-2 behavior; ROADMAP D4). With tenants active, the
-          tenant_drain knob ends the linger as soon as every
-          still-backlogged tenant is represented in the bucket — one
-          group per tenant per round beats a full bucket for fairness
-          latency.
+        - `idle_pipeline`: the queue is dry and no batch is between
+          hand-over and its answers — close NOW. Any wait is dead time
+          on an idle device: a lone query on an idle pipeline is handed
+          over without a timed wait in front of it.
+        - `answers_ready`: a batch is awaiting its answers, so the
+          device is taken and closing early would serve nobody sooner —
+          keep ADMITTING arrivals into this assembling batch until that
+          batch's batch_predict has returned (`_answers_ready`), then
+          close. The batch ahead finishes (`batch.finish`) on its own
+          slot while this one runs: at most one batch awaits its answers
+          at a time, `_inflight` bounds those that are finishing.
+        - `full`: the batch reached max_batch, and is handed over
+          whatever is awaited: under a backlog that fills batches up to
+          pipeline_depth of them are on the pool, their programs queued
+          on the device stream.
+        - `wedge`: the answers did not come within 10 × max_window (or
+          1.2 × the last batch, if longer: device hang, accounting leak)
+          — the ONE timed wait of the mode, the timeout of the sleep
+          below; shed_dead and the clients' own deadlines still bound
+          how long a held query can suffer.
 
-        `full`: the bucket reached max_batch."""
+        The loop thread sleeps on the queue's condition, which an
+        arrival (`put`) and a batch's answers (`wake`) both notify: no
+        window, no poll. Windowed mode (`window`; ROADMAP D4) shares no
+        rule with this: `_collect_windowed`."""
+        import queue as _q
+
+        if self.batching == "windowed":
+            return self._collect_windowed(first)
+        batch = [first]
+        wedge_deadline = time.monotonic() + max(
+            10.0 * self.max_window_s, self.last_batch_sec * 1.2
+        )
+        waited = False
+        while len(batch) < self.max_batch:
+            # the admission cap applies however the batch closes: a
+            # capped tenant's overflow waits for the next one
+            skip = self._admission_skip(batch)
+            try:
+                self._take(batch, self._queue.get_nowait(skip=skip))
+                continue
+            except _q.Empty:
+                pass
+            if self._none_awaited():
+                return batch, "answers_ready" if waited else "idle_pipeline"
+            remaining = wedge_deadline - time.monotonic()
+            if remaining <= 0:
+                return batch, "wedge"
+            waited = True
+            try:
+                self._take(batch, self._queue.get(
+                    timeout=remaining, skip=skip, unless=self._none_awaited
+                ))
+            except _q.Empty:
+                pass
+        return batch, "full"
+
+    @staticmethod
+    def _take(batch: list, p: _Pending) -> None:
+        p.t_taken = time.perf_counter()
+        batch.append(p)
+
+    def _none_awaited(self) -> bool:
+        """No batch is between hand-over and its answers (or the
+        dispatcher is stopping): what ends the assembling batch's wait.
+        Read under the queue's lock by its `get`; `_answers_ready` and
+        `stop` change it and then `wake` the queue."""
+        with self._active_lock:
+            return not self._awaiting or self._stop.is_set()
+
+    def _answers_ready(self, group: list) -> None:
+        """`group` no longer awaits its answers: its batch_predict has
+        returned — or it ended without (every entry shed, or the batch
+        failed and its per-query fallback, which runs programs of its
+        own, is through). Once a group; wakes the loop thread."""
+        with self._active_lock:
+            if id(group) not in self._awaiting:
+                return
+            self._awaiting.remove(id(group))
+        self._queue.wake()
+
+    def _collect_windowed(self, first: _Pending) -> tuple[list, str]:
+        """Windowed mode's drain (the PR-2 behavior; ROADMAP D4), on its
+        own timers: with nothing in flight dispatch once the arrival
+        stream pauses (`idle_pipeline`); with batches in flight linger
+        up to max_window / 1.2 × the last batch for more arrivals
+        (`window`). With tenants active, the tenant_drain knob ends the
+        linger as soon as every still-backlogged tenant is represented
+        in the bucket — one group per tenant per round beats a full
+        bucket for fairness latency. `full`: max_batch."""
         import queue as _q
 
         batch = [first]
-
-        def take(p: _Pending) -> None:
-            p.t_taken = time.perf_counter()
-            batch.append(p)
-
-        retired_mark = self._retired
         round_t0 = time.monotonic()
         hard_deadline = time.monotonic() + max(
-            self.max_window_s,
-            getattr(self, "last_batch_sec", 0.0) * 1.2,
-        )
-        # continuous mode's backstop exists ONLY for a wedged
-        # in-flight batch (device hang, in-flight accounting leak):
-        # closing early never serves anyone sooner — the bucket
-        # just parks at the semaphore while later arrivals fragment
-        # into a second device round-trip. Before the FIRST batch
-        # retires there is no last_batch_sec measurement, so give
-        # an unmeasured flight several windows before declaring it
-        # wedged; shed_dead and the clients' own deadlines still
-        # bound how long any held query can suffer.
-        wedge_deadline = time.monotonic() + max(
-            10.0 * self.max_window_s,
-            getattr(self, "last_batch_sec", 0.0) * 1.2,
+            self.max_window_s, self.last_batch_sec * 1.2
         )
         while len(batch) < self.max_batch:
-            skip = self._admission_skip(batch)
             try:
-                take(self._queue.get_nowait(skip=skip))
+                self._take(batch, self._queue.get_nowait())
                 continue
             except _q.Empty:
                 pass
@@ -1311,37 +1405,15 @@ class _BatchDispatcher:
                 # after a quiet second it drops back to min_window so
                 # sporadic queries keep near-zero added latency.
                 patience = self.min_window_s
-                if (
-                    time.monotonic() - getattr(self, "_last_dispatch", 0.0)
-                    < 1.0
-                ):
+                if time.monotonic() - self._last_dispatch < 1.0:
                     patience = max(
-                        patience,
-                        min(
-                            0.1 * getattr(self, "last_batch_sec", 0.0),
-                            0.02,
-                        ),
+                        patience, min(0.1 * self.last_batch_sec, 0.02)
                     )
                 try:
-                    # the admission cap still applies: a capped
-                    # tenant's overflow waits for the next bucket
-                    # even when the pipeline just went idle
-                    take(self._queue.get(timeout=patience, skip=skip))
+                    self._take(batch, self._queue.get(timeout=patience))
                     continue
                 except _q.Empty:
                     return batch, "idle_pipeline"
-            if self.batching == "continuous":
-                if self._retired != retired_mark:
-                    # a bucket retired — dispatch onto the slot
-                    return batch, "retired"
-                if time.monotonic() >= wedge_deadline:
-                    # wedged in-flight batch: don't hold queries
-                    return batch, "wedge"
-                try:
-                    take(self._queue.get(timeout=0.002, skip=skip))
-                except _q.Empty:
-                    pass
-                continue
             if self.tenant_drain and (
                 time.monotonic() - round_t0 >= self.min_window_s
             ):
@@ -1360,7 +1432,9 @@ class _BatchDispatcher:
             if remaining <= 0:
                 return batch, "window"
             try:
-                take(self._queue.get(timeout=min(remaining, 0.002)))
+                self._take(
+                    batch, self._queue.get(timeout=min(remaining, 0.002))
+                )
             except _q.Empty:
                 pass
         return batch, "full"
@@ -1391,6 +1465,7 @@ class _BatchDispatcher:
                 try:
                     with self._active_lock:
                         self._active += 1
+                        self._awaiting.add(id(group))
                     self._last_dispatch = time.monotonic()
                     self._pool.submit(
                         self._run_group_released, rt, group
@@ -1399,6 +1474,7 @@ class _BatchDispatcher:
                 except RuntimeError:  # pool already shut down
                     with self._active_lock:
                         self._active -= 1
+                        self._awaiting.discard(id(group))
                     self._inflight.release()
             for p in group:
                 self._resolve(p, exc=RuntimeError("query server stopped"))
@@ -1406,11 +1482,10 @@ class _BatchDispatcher:
     def _admission_skip(self, batch: list) -> Optional[set]:
         """Tenants whose slots in the ASSEMBLING bucket are used up
         (ISSUE 14 satellite — adaptive continuous-batching admission).
-        Only continuous mode caps, and only with more than one active
-        stream: a solo tenant (or untenanted traffic alone) keeps the
-        whole bucket. Auto cap = max_batch // active streams."""
-        if self.batching != "continuous":
-            return None
+        Only continuous mode caps (windowed mode's drain never asks),
+        and only with more than one active stream: a solo tenant (or
+        untenanted traffic alone) keeps the whole bucket. Auto cap =
+        max_batch // active streams."""
         counts: dict = {}
         for p in batch:
             counts[p.tenant] = counts.get(p.tenant, 0) + 1
@@ -1449,9 +1524,11 @@ class _BatchDispatcher:
         try:
             self._run_group(rt, group)
         finally:
+            # a group that ended without answers must not hold the
+            # assembling batch (a no-op where batch_predict returned)
+            self._answers_ready(group)
             with self._active_lock:
                 self._active -= 1
-                self._retired += 1  # continuous drain's dispatch signal
             self._inflight.release()
 
 

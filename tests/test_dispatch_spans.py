@@ -18,7 +18,9 @@ from predictionio_tpu.workflow import server as S
 QUERY_SPANS = ("batch.queue_wait", "batch.pickup", "batch.assemble",
                "batch.slot_wait", "batch.device_dispatch",
                "batch.result_transfer", "query.wake", "query.wait")
-STATE_SPANS = ("dispatch.collect", "dispatch.slot", "dispatch.no_work")
+STATE_SPANS = ("dispatch.collect", "dispatch.slot", "dispatch.no_work",
+               "batch.finish")
+CLOSED_BY = {"full", "idle_pipeline", "answers_ready", "window", "wedge"}
 
 
 class _Owner:
@@ -140,7 +142,7 @@ def test_queue_wait_is_the_sum_of_its_three_hand_offs(recorder):
             root.span_id}
         closed.add(assemble.attrs["closed_by"])
         assert assemble.attrs["batch_size"] >= 1
-    assert closed <= {"full", "idle_pipeline", "retired", "window", "wedge"}
+    assert closed <= CLOSED_BY
 
 
 def test_closed_by_names_the_branch_that_closed_the_batch(recorder):
@@ -154,7 +156,7 @@ def test_closed_by_names_the_branch_that_closed_the_batch(recorder):
     first.start()
     time.sleep(0.05)  # the lone query's batch is in flight, the loop idle
     # four more while the one slot is taken: the loop thread collects all
-    # four (max_batch) before the in-flight batch retires
+    # four (max_batch) before the in-flight batch has its answers
     ids = [f"full-{time.monotonic_ns()}-{i}" for i in range(4)]
     threads = [threading.Thread(target=_submit_traced, args=(d, rt, t, t))
                for t in ids]
@@ -177,6 +179,134 @@ def test_closed_by_names_the_branch_that_closed_the_batch(recorder):
     waits = [_by_name(recorder, t)["batch.slot_wait"][0].duration for t in ids]
     assert min(waits) > 0.03
     assert recorder.seen["dispatch.slot"][-1].duration > 0.03
+
+
+class _HeldServing(_Serving):
+    """Serves a query only once the test lets it."""
+
+    def __init__(self):
+        self.gate = threading.Event()
+
+    def serve(self, q, preds):
+        assert self.gate.wait(20)
+        return preds[0]
+
+
+class _FirstCallHeld(_Algo):
+    """Its first batch_predict says it has begun and returns (or raises
+    `fault`) only once the test lets it; later calls return at once."""
+
+    def __init__(self, fault=None):
+        super().__init__()
+        self.fault = fault
+        self.calls: list = []
+        self.begun, self.gate = threading.Event(), threading.Event()
+
+    def batch_predict(self, ctx, model, queries):
+        self.calls.append(len(queries))
+        if len(self.calls) == 1:
+            self.begun.set()
+            assert self.gate.wait(20)
+            if self.fault is not None:
+                raise self.fault
+        return super().batch_predict(ctx, model, queries)
+
+
+def _wait_for(cond, what, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.001)
+
+
+def _behind_a_held_batch(d, rt, algo, ids):
+    """One query whose batch_predict is held, then `ids` submitted and
+    taken by the loop thread behind it; returns all the threads."""
+    lone = f"ahead-{time.monotonic_ns()}"
+    threads = [threading.Thread(target=_submit_traced, args=(d, rt, lone, lone))]
+    threads[0].start()
+    assert algo.begun.wait(10)
+    for tid in ids:
+        threads.append(threading.Thread(
+            target=_submit_traced, args=(d, rt, tid, tid)))
+        threads[-1].start()
+    _wait_for(lambda: d._queue.qsize() == 0 and d._held == 1 + len(ids),
+              "the queries behind to be taken")
+    return threads
+
+
+def test_batch_finish_covers_what_the_next_batch_no_longer_waits_for(recorder):
+    """One `batch.finish` a batch, from its `batch.predict`'s end to the
+    end of its serve loop, with the batch's size; while the first batch's
+    serve is held, the batch that assembled behind it closes by
+    `answers_ready` and its `batch.predict` runs INSIDE the first one's
+    `batch.finish`."""
+    t0 = time.monotonic()
+    d = S._BatchDispatcher(_Owner(), 1.0, 8, 30_000.0, 4)
+    rt = _runtime()
+    rt.algorithms = [algo := _FirstCallHeld()]
+    rt.serving = held = _HeldServing()
+    ids = [f"behind-{time.monotonic_ns()}-{i}" for i in range(3)]
+    threads = _behind_a_held_batch(d, rt, algo, ids)
+    algo.gate.set()  # the first batch's answers; its serve is held
+    _wait_for(lambda: len(algo.calls) == 2, "the second batch to run")
+    assert threads[0].is_alive()  # the first batch is still finishing
+    held.gate.set()
+    for t in threads:
+        t.join(20)
+    d.stop()
+    # (gathered as they END: the first batch's finish may end last)
+    predicts, finishes, collects = (
+        sorted((s for s in recorder.seen[n] if s.start_mono >= t0),
+               key=lambda s: s.start_mono)
+        for n in ("batch.predict", "batch.finish", "dispatch.collect"))
+    assert [s.attrs["batch_size"] for s in predicts] == [1, 3]
+    assert [s.attrs["batch_size"] for s in finishes] == [1, 3]
+    assert [(s.attrs["closed_by"], s.attrs["size"]) for s in collects] == [
+        ("idle_pipeline", 1), ("answers_ready", 3)]
+    for predict, finish in zip(predicts, finishes):
+        assert _interval(finish)[0] >= _interval(predict)[1]
+    (f_lo, f_hi), (p_lo, p_hi) = _interval(finishes[0]), _interval(predicts[1])
+    assert f_lo < p_lo and p_hi < f_hi
+    for tid in ids:
+        assemble = _by_name(recorder, tid)["batch.assemble"][0]
+        assert assemble.attrs["closed_by"] == "answers_ready"
+        # closed at the first batch's answers — not before them, and
+        # before its own batch ran, which was before the held serve ended
+        assert _interval(predicts[0])[1] - 1e-4 <= _interval(assemble)[1] <= p_lo
+    # a state span: accounted, in no trace
+    assert recorder.stats(t0)["batch.finish"]["count"] >= 2
+    assert all(s.trace_id == spans.NO_TRACE for s in finishes)
+
+
+def test_a_failed_batch_has_no_finish_and_lets_go_after_its_fallback(recorder):
+    """A batch whose batch_predict raised never had its answers: no
+    `batch.finish`, and the batch assembling behind it closes only once
+    the per-query fallback (programs of its own) is through."""
+    t0 = time.monotonic()
+    d = S._BatchDispatcher(_Owner(), 1.0, 8, 30_000.0, 4)
+    rt = _runtime()
+    rt.algorithms = [algo := _FirstCallHeld(fault=RuntimeError("device fault"))]
+    in_fallback, fallback = threading.Event(), threading.Event()
+
+    def predict(model, query):
+        in_fallback.set()
+        assert fallback.wait(20)
+        return {"echo": query}
+
+    algo.predict = predict
+    threads = _behind_a_held_batch(d, rt, algo, [f"behind-{time.monotonic_ns()}"])
+    algo.gate.set()  # the fault strikes; the fallback is held
+    assert in_fallback.wait(10)
+    time.sleep(0.05)
+    assert algo.calls == [1] and len(d._awaiting) == 1
+    fallback.set()
+    for t in threads:
+        t.join(20)
+    d.stop()
+    assert algo.calls == [1, 1]
+    assert len([s for s in recorder.seen["batch.finish"]
+                if s.start_mono >= t0]) == 1
 
 
 def test_query_wake_lies_inside_its_query_wait(recorder):
@@ -328,6 +458,24 @@ def test_state_spans_are_in_stats_and_in_no_trace(served, recorder):
     with recorder._lock:
         assert spans.NO_TRACE not in recorder._active
         assert spans.NO_TRACE not in recorder._traces
+
+
+def test_closed_batches_are_counted_on_the_servers_registry(served):
+    """`dispatch_batches_closed_total{closed_by}` beside `batch_size` on a
+    real QueryServer's registry and in its `/metrics`: as many closed as
+    ran, each under one of the rule's words."""
+    srv, port = served
+    for i in range(5):
+        assert _post(port, {"n": i}, f"count-{time.monotonic_ns()}")[0] == 200
+    closed = srv.metrics.counter(
+        "dispatch_batches_closed_total", labelnames=("closed_by",))
+    ran = next(f for f in srv.metrics.families() if f.name == "batch_size")
+    assert closed.total == ran.count == 5
+    assert closed.value(closed_by="idle_pipeline") == 5
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/metrics", timeout=30) as r:
+        text = r.read().decode()
+    assert 'dispatch_batches_closed_total{closed_by="idle_pipeline"} 5' in text
 
 
 def test_state_span_is_accounted_and_never_stored():

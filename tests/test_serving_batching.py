@@ -5,8 +5,11 @@ The dispatcher tests drive `_BatchDispatcher` directly with a fake
 runtime whose batch_predict sleeps — the same harness shape
 test_query_server uses for its drain tests — so batching decisions are
 observable as recorded batch sizes rather than wall-clock flakiness
-wherever possible."""
+wherever possible. The close rule's tests (ISSUE 38) go further: their
+batch_predict blocks on an event the test sets, so they hold whatever
+the clock does."""
 
+import sys
 import threading
 import time
 
@@ -71,8 +74,9 @@ def test_batching_mode_validated():
 
 def test_continuous_coalesces_arrivals_into_inflight_bucket():
     """With one slow bucket in flight, arrivals trickling in must join
-    ONE assembling bucket that dispatches on retirement — the windowed
-    drain at a short max_window splits the same stream into fragments."""
+    ONE assembling bucket that dispatches when the bucket ahead has its
+    answers — the windowed drain at a short max_window splits the same
+    stream into fragments."""
 
     def run(mode, max_window_ms):
         d = S._BatchDispatcher(
@@ -101,8 +105,8 @@ def test_continuous_coalesces_arrivals_into_inflight_bucket():
 
     cont = run("continuous", 30.0)
     # bucket A (1 query) + ONE coalesced bucket for the trickle (a
-    # straggler bucket can appear if the last arrival lands after the
-    # retirement break)
+    # straggler bucket can appear if the last arrival lands after A's
+    # answers closed it)
     assert cont[0] == 1
     assert len(cont) <= 3, cont
     assert max(cont[1:]) >= 8, cont
@@ -111,13 +115,324 @@ def test_continuous_coalesces_arrivals_into_inflight_bucket():
     assert len(windowed) >= len(cont), (windowed, cont)
 
 
-def test_continuous_retirement_signal_counts():
-    d = S._BatchDispatcher(_Owner(), 1.0, 8, 30.0, 2, batching="continuous")
+def test_continuous_answers_signal_counts():
+    """Every batch handed over is counted as awaiting its answers and
+    let go of once: three lone queries are three `idle_pipeline` batches
+    and leave nothing awaited."""
+    owner = _CountingOwner()
+    d = S._BatchDispatcher(owner, 1.0, 8, 30.0, 2, batching="continuous")
     rt = _runtime()
     for _ in range(3):
         assert d.submit(object(), rt, timeout=5) == 0
-    assert d._retired >= 1
+    assert _closed(owner) == {"idle_pipeline": 3}
+    _settle(d)
+    assert not d._awaiting and d._active == 0
     d.stop()
+
+
+# ---------------------------------------------------------------------------
+# the close rule (ISSUE 38), on events the test sets
+# ---------------------------------------------------------------------------
+
+CLOSED_BY = {"full", "idle_pipeline", "answers_ready", "window", "wedge"}
+
+
+class _CountingOwner(_Owner):
+    """An owner with a registry: `dispatch_batches_closed_total` and the
+    `batch_size` histogram land on it."""
+
+    def __init__(self):
+        from predictionio_tpu.obs.registry import MetricsRegistry
+
+        self.metrics = MetricsRegistry()
+
+
+def _closed(owner) -> dict:
+    """{closed_by: batches} off the owner's registry."""
+    fam = owner.metrics.counter(
+        "dispatch_batches_closed_total", labelnames=("closed_by",))
+    out = {c: int(fam.value(closed_by=c)) for c in CLOSED_BY}
+    assert sum(out.values()) == fam.total  # no sixth word
+    return {c: n for c, n in out.items() if n}
+
+
+def _batches_run(owner) -> int:
+    for fam in owner.metrics.families():
+        if fam.name == "batch_size":
+            return int(fam.count)
+    return 0
+
+
+def _wait_for(cond, what, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.001)
+
+
+def _settle(d):
+    """Until every worker has let go of its slot."""
+    _wait_for(lambda: d._active == 0, "the pool to drain")
+
+
+class _Gated:
+    """A runtime whose batch_predict blocks until the test opens that
+    call's gate, and whose serve blocks while `serve_gate` is closed.
+    It counts the calls inside batch_predict at once."""
+
+    def __init__(self, calls=16):
+        self.calls: list[list] = []       # the queries of each call
+        self.entered = [threading.Event() for _ in range(calls)]
+        self.gates = [threading.Event() for _ in range(calls)]
+        self.serve_gate = threading.Event()
+        self.serve_gate.set()
+        self.lock = threading.Lock()
+        self.inside = self.max_inside = 0
+        gated = self
+
+        class _Algo:
+            serving_context = None
+
+            def batch_predict(self, ctx, model, queries):
+                with gated.lock:
+                    n = len(gated.calls)
+                    gated.calls.append([q for _, q in queries])
+                    gated.inside += 1
+                    gated.max_inside = max(gated.max_inside, gated.inside)
+                gated.entered[n].set()
+                try:
+                    assert gated.gates[n].wait(20), "gate never opened"
+                finally:
+                    with gated.lock:
+                        gated.inside -= 1
+                return [(i, q) for i, q in queries]
+
+            def predict(self, model, query):
+                return query
+
+        class _GatedServing:
+            def serve(self, q, preds):
+                assert gated.serve_gate.wait(20), "serve gate never opened"
+                return preds[0]
+
+        self.algorithms = [_Algo()]
+        self.models = [None]
+        self.serving = _GatedServing()
+
+    def open_all(self):
+        self.serve_gate.set()
+        for g in self.gates:
+            g.set()
+
+
+def _submit_all(d, rt, queries, **kw):
+    results = {}
+
+    def one(q):
+        try:
+            results[q] = d.submit(q, rt, timeout=20, **kw)
+        except Exception as e:  # the test reads it
+            results[q] = e
+
+    threads = [threading.Thread(target=one, args=(q,)) for q in queries]
+    for t in threads:
+        t.start()
+    return threads, results
+
+
+def _join(threads):
+    for t in threads:
+        t.join(20)
+        assert not t.is_alive()
+
+
+def _recorded_waits(d):
+    """Every blocking `get` on the dispatcher's queue from here on, as
+    (timeout, unless): the loop thread's only way to sleep."""
+    waits = []
+    real = d._queue.get
+
+    def get(timeout=None, skip=None, unless=None):
+        waits.append((timeout, unless))
+        return real(timeout=timeout, skip=skip, unless=unless)
+
+    d._queue.get = get
+    return waits
+
+
+def test_lone_query_on_idle_pipeline_is_handed_over_without_a_timed_wait():
+    """The queue is dry and no batch awaits its answers: the batch
+    closes NOW. The loop thread's only sleeps are its idle wait for a
+    first query; `_collect` asks the queue for nothing it would have to
+    wait for."""
+    owner = _CountingOwner()
+    d = S._BatchDispatcher(owner, 50.0, 8, 5000.0, 4)
+    waits = _recorded_waits(d)
+    rt = _Gated()
+    rt.open_all()
+    for q in ("a", "b", "c"):
+        assert d.submit(q, rt, timeout=20) == q
+        _settle(d)
+    d.stop()
+    assert rt.calls == [["a"], ["b"], ["c"]]
+    assert _closed(owner) == {"idle_pipeline": 3}
+    # the idle loop's wait for a first entry ends on stop(), nothing else
+    assert waits and all(u == d._stop.is_set for _, u in waits), waits
+
+
+def test_arrivals_join_one_batch_handed_over_when_the_answers_return():
+    """While batch A's batch_predict is blocked, five arrivals join ONE
+    assembling batch; it is handed over the moment A's batch_predict
+    returns, although A's serve is still blocked (A is finishing on its
+    own slot); and its own wait is the one sleep of the rule: timeout =
+    the wedge deadline, woken by `_none_awaited`."""
+    owner = _CountingOwner()
+    d = S._BatchDispatcher(owner, 50.0, 64, 5000.0, 4)
+    rt = _Gated()
+    rt.serve_gate.clear()
+    first, got_first = _submit_all(d, rt, ["A"])
+    assert rt.entered[0].wait(10)
+    waits = _recorded_waits(d)
+    rest, got_rest = _submit_all(d, rt, [f"b{i}" for i in range(5)])
+    _wait_for(lambda: d._queue.qsize() == 0 and d._held == 6,
+              "the five to be taken")
+    time.sleep(0.05)
+    assert len(rt.calls) == 1  # held: the device is A's
+    rt.gates[0].set()  # A's answers return; its serve stays blocked
+    assert rt.entered[1].wait(10), "B was not handed over at A's answers"
+    assert sorted(rt.calls[1]) == [f"b{i}" for i in range(5)]
+    assert got_first == {} and first[0].is_alive()  # A is still finishing
+    assert d._active == 2
+    rt.open_all()
+    _join(first + rest)
+    _settle(d)
+    d.stop()
+    assert got_first == {"A": "A"}
+    assert got_rest == {f"b{i}": f"b{i}" for i in range(5)}
+    assert [len(c) for c in rt.calls] == [1, 5]
+    assert rt.max_inside == 1
+    assert _closed(owner) == {"idle_pipeline": 1, "answers_ready": 1}
+    in_collect = [(t, u) for t, u in waits if u != d._stop.is_set]
+    assert in_collect and all(
+        u == d._none_awaited and 0.0 < t <= 10 * 5.0 for t, u in in_collect
+    ), waits
+
+
+def test_never_two_batches_between_hand_over_and_answers():
+    """Forty threads, 400 queries, a thread switch every 10 µs, and no
+    backlog that could fill a batch (`full` hands over whatever is
+    awaited: the saturated regime keeps the pool's slots on the device
+    stream): at no instant are two calls inside batch_predict, every
+    query is answered with its own answer, nothing stays awaited or
+    held, and the closed batches are the batches run."""
+    owner = _CountingOwner()
+    d = S._BatchDispatcher(owner, 1.0, 64, 30.0, 4)
+    rt = _Gated(calls=400)
+    rt.open_all()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        wrong = []
+
+        def client(k):
+            for i in range(10):
+                q = f"{k}-{i}"
+                if d.submit(q, rt, timeout=20) != q:
+                    wrong.append(q)
+
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(40)]
+        for t in threads:
+            t.start()
+        _join(threads)
+    finally:
+        sys.setswitchinterval(old)
+    _settle(d)
+    d.stop()
+    assert not wrong
+    assert rt.max_inside == 1
+    assert sum(len(c) for c in rt.calls) == 400
+    assert not d._awaiting and d._held == 0
+    closed = _closed(owner)
+    assert set(closed) <= {"idle_pipeline", "answers_ready"}
+    assert sum(closed.values()) == len(rt.calls) == _batches_run(owner)
+    assert closed.get("answers_ready", 0) >= 1
+
+
+@pytest.mark.parametrize("where", ["idle", "assembling"])
+def test_stop_wakes_a_sleeping_loop(where):
+    """stop() ends the loop thread where it sleeps — on the idle wait
+    for a first query, or inside `_collect` behind a batch whose answers
+    never come (its wedge deadline is 100 s away) — and fails the
+    assembling query instead of holding it."""
+    d = S._BatchDispatcher(_Owner(), 50.0, 8, 10_000.0, 2)
+    rt = _Gated()
+    threads, got = [], {}
+    if where == "assembling":
+        threads, got = _submit_all(d, rt, ["A"])
+        assert rt.entered[0].wait(10)
+        more, got_more = _submit_all(d, rt, ["B"])
+        threads += more
+        _wait_for(lambda: d._queue.qsize() == 0 and d._held == 2,
+                  "B to be taken")
+    else:
+        time.sleep(0.05)  # the loop is in its idle wait
+    d.stop()
+    assert not d._thread.is_alive()
+    if where == "assembling":
+        _wait_for(lambda: "B" in got_more, "B to be failed")
+        assert isinstance(got_more["B"], RuntimeError)
+        rt.open_all()
+        _join(threads)
+        assert got == {"A": "A"}
+
+
+def _close_by(branch, owner):
+    """Drive one dispatcher so that a batch closes by `branch`."""
+    rt = _Gated()
+    if branch == "window":  # lingers 200 ms
+        d = S._BatchDispatcher(owner, 1.0, 8, 200.0, 2, batching="windowed")
+    elif branch == "wedge":  # wedged after 200 ms
+        d = S._BatchDispatcher(owner, 1.0, 8, 20.0, 2)
+    else:
+        d = S._BatchDispatcher(owner, 50.0, 4, 5000.0, 2)
+    threads, _ = _submit_all(d, rt, ["A"])
+    assert rt.entered[0].wait(10)
+    if branch != "idle_pipeline":
+        more, _ = _submit_all(
+            d, rt, [f"b{i}" for i in range(4 if branch == "full" else 2)])
+        threads += more
+        if branch == "answers_ready":
+            _wait_for(lambda: d._queue.qsize() == 0 and d._held == 3,
+                      "the two to be taken")
+            rt.gates[0].set()
+        # full, wedge and window close while A's batch_predict is blocked
+        assert rt.entered[1].wait(10)
+    rt.open_all()
+    _join(threads)
+    _settle(d)
+    d.stop()
+    return rt
+
+
+@pytest.mark.parametrize(
+    "branch", ["idle_pipeline", "answers_ready", "full", "wedge", "window"])
+def test_every_branch_of_the_close_rule_is_counted(branch):
+    """`dispatch_batches_closed_total{closed_by}`: each branch closes a
+    batch under its own word, no batch under a sixth, and the counter's
+    sum is the number of batches."""
+    owner = _CountingOwner()
+    rt = _close_by(branch, owner)
+    closed = _closed(owner)
+    assert set(closed) <= CLOSED_BY
+    assert sum(closed.values()) == len(rt.calls) == _batches_run(owner)
+    assert closed.pop("idle_pipeline") == 1  # the lone A
+    if branch in ("wedge", "window"):
+        # a timer's branch: on a slow hour the two arrivals may straddle
+        # one deadline and close two batches under the same word
+        assert set(closed) == {branch} and closed[branch] in (1, 2)
+    else:
+        assert closed == ({} if branch == "idle_pipeline" else {branch: 1})
 
 
 def test_tenant_drain_closes_round_once_all_tenants_represented():

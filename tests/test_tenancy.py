@@ -121,6 +121,47 @@ class TestFairQueue:
         q.put(_Item(None, 0))
         assert q.depths() == {"a": 2, "(default)": 1}
 
+    def test_unless_ends_an_empty_wait_and_an_item_comes_first(self):
+        """ISSUE 38: `get(unless=)` raises Empty at once where the
+        caller's other reason holds and nothing can be popped; an item
+        that can be popped is returned whatever `unless` says."""
+        q = FairQueue()
+        with pytest.raises(stdlib_queue.Empty):
+            q.get(timeout=30.0, unless=lambda: True)
+        q.put(_Item("a", 0))
+        assert q.get(timeout=30.0, unless=lambda: True).tenant == "a"
+        # a skipped tenant's item is nothing to pop
+        q.put(_Item("a", 1))
+        with pytest.raises(stdlib_queue.Empty):
+            q.get(timeout=30.0, skip={"a"}, unless=lambda: True)
+
+    def test_wake_makes_a_blocked_get_read_unless_again(self):
+        """A getter asleep with no timeout ends when what `unless` reads
+        has changed and `wake()` was called after the change; a `wake()`
+        with nothing changed leaves it asleep."""
+        q = FairQueue()
+        done = threading.Event()
+        flag: list = []
+        out: list = []
+
+        def getter():
+            try:
+                out.append(q.get(unless=lambda: bool(flag)))
+            except stdlib_queue.Empty:
+                out.append("empty")
+            done.set()
+
+        t = threading.Thread(target=getter, daemon=True)
+        t.start()
+        time.sleep(0.05)
+        q.wake()
+        assert not done.wait(0.1)  # nothing changed: still asleep
+        flag.append(1)
+        q.wake()
+        assert done.wait(5.0) and out == ["empty"]
+        t.join(timeout=5.0)
+        assert not t.is_alive()
+
 
 # ---------------------------------------------------------------------------
 # quotas
