@@ -12,7 +12,8 @@ Shape of the engine:
   indicator is the PRIMARY — its targets define the recommendation item
   space) and optionally self-cleans the event store first.
 - Algorithm computes, per indicator, each item's top correlators by CCO+LLR
-  (models/cco.py — dense MXU matmuls, user-sharded over the mesh).
+  (models/cco.py — Mahout's downsampling, then a join of sorted sparse
+  pairs on the device).
 - Serving reads the user's RECENT event history live from the event store
   (the reason the reference fork needed serving-time LEventStore reads) and
   scores items by summed LLR over history hits, minus business rules.
@@ -64,6 +65,19 @@ _EXCLUSION_BYTES = get_default_registry().counter(
 _HISTORY_READ_FAILURES = get_default_registry().counter(
     "ur_history_read_failures_total",
     "serving-time history reads the event store failed (served as empty)",
+)
+# what a train job counts, by indicator (the engine.json's event names):
+# the distinct events the downsampling kept, and the primary x indicator
+# pairs the join expanded
+_EVENTS_KEPT = get_default_registry().counter(
+    "ur_train_events_kept_total",
+    "distinct events kept by the downsampling, by indicator",
+    labelnames=("indicator",),  # label-bound: the engine's event names
+)
+_PAIRS = get_default_registry().counter(
+    "ur_train_pairs_total",
+    "primary x indicator pairs the cross-occurrence join expanded",
+    labelnames=("indicator",),  # label-bound: the engine's event names
 )
 
 
@@ -165,6 +179,9 @@ class URDataSource(DataSource, SelfCleaningDataSource):
 class URAlgorithmParams:
     app_name: str
     max_correlators_per_item: int = 50
+    # Mahout's maxNumInteractions (the UR's maxEventsPerEventType): the
+    # downsampling's cap on a user's and on an item's events of a type
+    max_events_per_event_type: int = 500
     max_query_events: int = 100  # recent history depth per indicator
     indicators: Optional[tuple[str, ...]] = None  # default: all from data
 
@@ -228,34 +245,42 @@ class URAlgorithm(Algorithm):
         self.params = params
 
     def train(self, ctx: RuntimeContext, pd: TrainingData) -> URModel:
+        """Each indicator's events binarised, grouped by user and
+        downsampled on the host, then every indicator's cross-occurrence
+        with the primary joined from sorted pairs on the device, in blocks
+        (`cco.join_indicators`): no (users × items) matrix at any size."""
         primary = pd.indicators[0]
-        n_items = len(primary.target_vocab)
-        p_matrix = cco.edges_to_indicator(
-            primary.rows, primary.cols, pd.n_users, n_items
-        )
         wanted = self.params.indicators or tuple(i.name for i in pd.indicators)
-        models = []
-        for ind in pd.indicators:
-            if ind.name not in wanted:
+        cap = self.params.max_events_per_event_type
+        kept = {}
+        for m, ind in enumerate(pd.indicators):
+            if ind.name not in wanted and ind is not primary:
                 continue
-            s_matrix = cco.edges_to_indicator(
-                ind.rows, ind.cols, pd.n_users, len(ind.target_vocab)
-            )
-            scores, idx = cco.cross_occurrence_topn(
-                p_matrix,
-                s_matrix,
-                top_n=self.params.max_correlators_per_item,
-                self_indicator=ind.name == primary.name,
-                mesh=ctx.mesh,
-            )
-            models.append(
-                IndicatorModel(
-                    name=ind.name,
-                    correlator_scores=scores,
-                    correlator_idx=idx,
-                    target_vocab=ind.target_vocab,
-                )
-            )
+            with _spans.span("ur.train.group", indicator=ind.name):
+                grouped = cco.group_by_user(
+                    ind.rows, ind.cols, pd.n_users, len(ind.target_vocab))
+            with _spans.span("ur.train.downsample", indicator=ind.name) as sp:
+                kept[ind.name] = cco.downsample(
+                    grouped, cap, cco.DOWNSAMPLE_SEED, m)
+                sp.attrs["distinct"] = int(grouped.cols.size)
+                sp.attrs["kept"] = int(kept[ind.name].cols.size)
+            _EVENTS_KEPT.inc(float(kept[ind.name].cols.size),
+                             indicator=ind.name)
+        joined = [ind for ind in pd.indicators if ind.name in wanted]
+        tables, stats = cco.join_indicators(
+            kept[primary.name], [kept[ind.name] for ind in joined],
+            pd.n_users, self.params.max_correlators_per_item,
+            self_first=bool(joined) and joined[0] is primary,
+        )
+        models = []
+        for ind, (scores, idx), pairs in zip(joined, tables, stats["pairs"]):
+            _PAIRS.inc(float(pairs), indicator=ind.name)
+            models.append(IndicatorModel(
+                name=ind.name,
+                correlator_scores=scores,
+                correlator_idx=idx,
+                target_vocab=ind.target_vocab,
+            ))
         return URModel(
             item_vocab=primary.target_vocab,
             indicator_models=models,

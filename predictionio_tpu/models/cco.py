@@ -30,8 +30,89 @@ from predictionio_tpu.obs import spans as _spans
 from predictionio_tpu.ops.topk import NEG_INF, masked_top_k
 
 
-def _x_log_x(x: jax.Array) -> jax.Array:
-    return jnp.where(x > 0, x * jnp.log(jnp.maximum(x, 1e-30)), 0.0)
+def _log(x):
+    """log(x) of float32 x > 0 from multiplies, adds and one divide: x =
+    m·2^k with m in [√½, √2), log m = 2·atanh(s), s = (m − 1)/(m + 1),
+    |s| ≤ 0.172, by its series to s¹¹ (error < s¹³/13 ≈ 10⁻¹¹), and k·ln 2
+    in two parts: ~2·10⁻⁷ relative, the same on any backend. The LLR's
+    earlier form, with the backend's log1p, read up to 7.8·10⁻⁵ on the
+    v5e against 6·10⁻⁷ on a CPU (PERF.md, PR 39)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    k = (bits >> 23) - 127
+    m = jax.lax.bitcast_convert_type(
+        (bits & 0x7FFFFF) | 0x3F800000, jnp.float32)
+    big = m > 1.4142135
+    m = jnp.where(big, 0.5 * m, m)
+    k = (k + big).astype(jnp.float32)
+    s = (m - 1.0) / (m + 1.0)
+    s2 = s * s
+    series = 2.0 * s * (1.0 + s2 * (1 / 3 + s2 * (1 / 5 + s2 * (
+        1 / 7 + s2 * (1 / 9 + s2 / 11)))))
+    return k * 0.693145751953125 + (k * 1.428606765330187e-06 + series)
+
+
+def _kl_cell(k, e, delta):
+    """E·φ(u), φ(u) = (1 + u)·log(1 + u) − u ≥ 0, for a cell of count k,
+    expectation e and deviation delta = k − e (u = delta / e): the cell's
+    share of the LLR, k·log(k/E) − (k − E). Near u = 0 the direct form
+    cancels, so there its series u²/2 − u³/6 + u⁴/12 − u⁵/20 + u⁶/30 −
+    u⁷/42 is taken (error under u⁸/56: < 10⁻⁹ of φ at |u| ≤ 0.1); an
+    empty cell adds E (φ(−1) = 1). The log is `_log`, of k/E itself."""
+    e = jnp.where(e > 0, e, 1.0)
+    u = delta / e
+    small = jnp.abs(u) < 0.1
+    us = jnp.where(small, u, 0.0)
+    series = us * us * (0.5 + us * (-1 / 6 + us * (1 / 12 + us * (
+        -1 / 20 + us * (1 / 30 + us * (-1 / 42))))))
+    ratio = jnp.where(small | (k <= 0), 1.0, k / e)
+    direct = jnp.where(k > 0, ratio * _log(ratio), 0.0) - u
+    return e * jnp.where(small, series, direct)
+
+
+def llr(k11, r, c, n):
+    """Dunning's log-likelihood ratio of the 2×2 contingency table of a
+    pair, elementwise: k11 users did both, r the first, c the second, of n.
+
+    Written as 2·Σ E·φ(δ/E) over the four cells, E a cell's expectation
+    r·c/n, r·(n − c)/n, (n − r)·c/n, (n − r)·(n − c)/n and δ = ±d/n its
+    deviation, d = k11·n − r·c: the same sum as 2·Σ k·log(k/E) (the
+    deviations sum to 0), but of four terms ≥ 0, each computed where it
+    does not cancel (`_kl_cell`), with a log of multiplies and adds
+    (`_log`). The entropy form (Σ x·log x of cells,
+    rows, columns and n) takes differences of terms of ~1.4·10⁷ at
+    n ≈ 10⁶ and loses about one unit in float32; 2·Σ k·log(k/E) with
+    log1p for the three cells beside k11 read up to 7.8·10⁻⁵ relative on
+    the v5e, against ~6·10⁻⁷ on a CPU (PERF.md, PR 39). A table with d
+    exactly 0 — counts under 2²⁴, as float32 holds them — reads exactly
+    0, any other table is positive, so `> 0` tells a correlator from an
+    independent pair whatever the rounding. k11 = 0 reads 0: a pair that
+    never co-occurs is no correlator."""
+    f32 = jnp.float32
+    k11, r, c = (jnp.asarray(x, f32) for x in (k11, r, c))
+    n = jnp.asarray(n, f32)
+    live = (k11 > 0) & (r > 0) & (c > 0)
+    # the guards keep every value finite where a table is dead (it is
+    # masked to 0 below), so no NaN reaches a sort or a top-k
+    r, c = jnp.where(live, r, 1.0), jnp.where(live, c, 1.0)
+    pr, pc = r / n, c / n
+    e11 = r * pc  # r·c / n
+    e = k11 - e11  # d / n
+    e12, e21 = r * (1.0 - pc), (1.0 - pr) * c
+    e22 = n * (1.0 - pr) * (1.0 - pc)
+    out = 2.0 * (
+        _kl_cell(k11, e11, e)
+        + _kl_cell(r - k11, e12, -e)
+        + _kl_cell(c - k11, e21, -e)
+        + _kl_cell(n - r - c + k11, e22, e)
+    )
+    # d = 0 exactly: d mod 2³² in int32 (wrapping) is 0 and the float
+    # estimate of |d| is far under 2³¹, which it misses by < 2²⁶
+    i32 = jnp.int32
+    d_wrapped = k11.astype(i32) * n.astype(i32) - r.astype(i32) * c.astype(i32)
+    independent = (d_wrapped == 0) & (jnp.abs(e * n) < 2.0**30)
+    return jnp.where(
+        live & ~independent, jnp.maximum(out, jnp.finfo(f32).tiny), 0.0
+    )
 
 
 def llr_scores(
@@ -40,19 +121,9 @@ def llr_scores(
     sec_totals: jax.Array,  # (J,) per-thing event totals
     n_users: jax.Array | float,
 ) -> jax.Array:
-    """Dunning log-likelihood ratio of the 2×2 contingency per pair."""
-    k12 = prim_totals[:, None] - k11
-    k21 = sec_totals[None, :] - k11
-    k22 = n_users - k11 - k12 - k21
-    row_entropy = _x_log_x(k11 + k12) + _x_log_x(k21 + k22)
-    col_entropy = _x_log_x(k11 + k21) + _x_log_x(k12 + k22)
-    mat_entropy = (
-        _x_log_x(k11) + _x_log_x(k12) + _x_log_x(k21) + _x_log_x(k22)
-    )
-    llr = 2.0 * (mat_entropy - row_entropy - col_entropy + _x_log_x(
-        jnp.asarray(n_users, jnp.float32)
-    ))
-    return jnp.maximum(llr, 0.0)
+    """Dunning LLR (`llr`) of the 2×2 contingency per pair of a dense
+    count matrix."""
+    return llr(k11, prim_totals[:, None], sec_totals[None, :], n_users)
 
 
 @partial(jax.jit, static_argnames=("top_n", "exclude_diagonal"))
@@ -72,15 +143,15 @@ def _cco_topn(
     )  # (I_blk, J) — MXU, user dim contracted (psum over dp shards)
     prim_totals = jnp.sum(primary, axis=0)
     sec_totals = jnp.sum(secondary, axis=0)
-    llr = llr_scores(counts, prim_totals, sec_totals, n_users)
-    exclude = counts <= 0  # never correlate never-co-occurring pairs
+    scores = llr_scores(counts, prim_totals, sec_totals, n_users)
+    exclude = scores <= 0  # never-co-occurring or independent pairs
     if exclude_diagonal:
         # the diagonal of the GLOBAL (I, I) matrix: global row index =
         # diag_offset + local row (item blocking shifts the block)
-        r = jnp.arange(llr.shape[0], dtype=jnp.int32)[:, None] + diag_offset
-        c = jnp.arange(llr.shape[1], dtype=jnp.int32)[None, :]
+        r = jnp.arange(scores.shape[0], dtype=jnp.int32)[:, None] + diag_offset
+        c = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
         exclude = exclude | (r == c)
-    vals, idx = masked_top_k(llr, top_n, exclude)
+    vals, idx = masked_top_k(scores, top_n, exclude)
     idx = jnp.where(vals > 0.0, idx, -1)  # llr 0 → not a correlator
     return vals, idx
 
@@ -151,6 +222,388 @@ def cross_occurrence_topn(
         out_vals[lo:hi] = np.asarray(vals)[: hi - lo]
         out_idx[lo:hi] = np.asarray(idx)[: hi - lo]
     return out_vals, out_idx
+
+
+# ---------------------------------------------------------------------------
+# The train path from sparse pairs (ISSUE 39)
+# ---------------------------------------------------------------------------
+#
+# The dense product above builds a (users × things) matrix a side: 16 TB
+# at Taobao UserBehavior's 988 k users × 4.16 M items, where the events
+# are 100 M pairs and the co-occurrence counts are ~5·10⁻⁵ dense. So a job
+# works on sorted pairs: each indicator's events binarised and grouped by
+# user on the host (one sort of packed keys, as `models/als.py`
+# `_group_unique_pairs` does), Mahout's downsampling drawn from a stated
+# hash, then every user's primary × secondary pairs, indicator after
+# indicator, cut by item range into blocks of `BLOCK_PAIRS`: for each
+# block the host lays the pairs out and ONE device program sorts them by
+# (item, thing) and counts each run, ONE scores the runs by LLR and
+# places each item's best `top_n` into the resident tables of every
+# indicator, in the (I, top_n) layout `IndicatorModel` and
+# `ResidentCorrelators` take.
+#
+# Why this split (PERF.md, PR 39, probes on the v5e): the chip sorts
+# ~5 ns a pair but GATHERS ~7.5 ns an element from a 1.9 M-entry table and
+# ~47 from an 86 M-entry one, so the pairs are laid out on the host (a
+# fill and a cumulative sum over reused buffers) with the LLR's margins
+# riding along; a sort compiles in ~40 s whatever its length, so every
+# block has ONE shape and two programs serve every job and indicator.
+
+#: the downsampling draw's seed: Mahout's `cooccurrences` default
+#: `randomSeed`
+DOWNSAMPLE_SEED = 0xDEADBEEF
+
+#: pairs a block holds at most. A job with more cuts its pairs into
+#: blocks of this one shape, so each program compiles once; a smaller job
+#: takes one block of its own size (`_bucket`). An item's pairs never
+#: straddle two blocks
+BLOCK_PAIRS = 1 << 25
+
+#: sort key of a dead pair (padding, the diagonal, a pair not kept): after
+#: every item and thing
+_DEAD = np.iinfo(np.int32).max
+
+_M32 = np.uint64(0xFFFFFFFF)
+
+
+def _fmix32(h: np.ndarray) -> np.ndarray:
+    """murmur3's 32-bit finaliser on uint64 holding 32-bit values, in
+    place for an array."""
+    h = np.array(h, np.uint64, ndmin=1)
+    tmp = np.empty_like(h)
+    for shift, mul in ((16, 0x85EBCA6B), (13, 0xC2B2AE35), (16, None)):
+        np.right_shift(h, np.uint64(shift), out=tmp)
+        np.bitwise_xor(h, tmp, out=h)
+        if mul is not None:
+            np.multiply(h, np.uint64(mul), out=h)
+            np.bitwise_and(h, _M32, out=h)
+    return h
+
+
+def sample_draw(seed: int, indicator: int, rows: np.ndarray,
+                cols: np.ndarray) -> np.ndarray:
+    """The downsampling's draw for each (user row, item row) of an
+    indicator, a uint64 holding a 32-bit value: with fmix32 murmur3's
+    finaliser and seed = hi·2³² + lo (mod 2⁶⁴), salt =
+    fmix32(fmix32(fmix32(indicator) ^ hi) ^ lo), draw =
+    fmix32(fmix32(salt ^ row) ^ col). Counter-based: a job is repeatable
+    and a reference redoes it from this text."""
+    seed = int(seed) % 2**64
+    salt = _fmix32(np.uint64(indicator))
+    salt = _fmix32(salt ^ np.uint64(seed >> 32))
+    salt = _fmix32(salt ^ np.uint64(seed & 0xFFFFFFFF))
+    h = _fmix32(np.asarray(rows, np.uint64) ^ salt)
+    h ^= np.asarray(cols, np.uint64)
+    return _fmix32(h)
+
+
+class UserEvents(NamedTuple):
+    """One indicator's distinct (user, thing) events grouped by user: user
+    u's things are `cols[ptr[u]:ptr[u + 1]]`, ascending."""
+
+    cols: np.ndarray  # (n,) int32
+    ptr: np.ndarray  # (n_users + 1,) int64
+    n_cols: int
+
+    def rows(self) -> np.ndarray:
+        return np.repeat(
+            np.arange(len(self.ptr) - 1, dtype=np.int32), np.diff(self.ptr))
+
+    def totals(self) -> np.ndarray:
+        """Events a thing (int32; float32 holds them exactly below 2²⁴)."""
+        return np.bincount(self.cols, minlength=self.n_cols).astype(np.int32)
+
+
+def group_by_user(rows: np.ndarray, cols: np.ndarray, n_users: int,
+                  n_cols: int) -> UserEvents:
+    """Binarise an indicator's events (a pair seen twice counts once) and
+    group them by user: one in-place sort of int64 keys user << b | col
+    (2^b ≥ n_cols), neighbours compared, the users' starts found by
+    binary search."""
+    bits = max(int(n_cols - 1).bit_length(), 1)
+    key = np.left_shift(np.asarray(rows, np.int64), bits)
+    key |= np.asarray(cols, np.int64)
+    key.sort()
+    if key.size:
+        fresh = np.empty(key.size, bool)
+        fresh[0] = True
+        np.not_equal(key[1:], key[:-1], out=fresh[1:])
+        key = key[fresh]
+    ptr = np.searchsorted(
+        key, np.left_shift(np.arange(n_users + 1, dtype=np.int64), bits))
+    key &= (1 << bits) - 1
+    return UserEvents(key.astype(np.int32), ptr, int(n_cols))
+
+
+def downsample(events: UserEvents, cap: int, seed: int,
+               indicator: int) -> UserEvents:
+    """Mahout's `sampleDownAndBinarize` (`maxNumInteractions` = `cap`):
+    each distinct (user, thing) is kept with probability
+    min(1, cap / n_user, cap / n_thing), the counts those of the distinct
+    events before the draw; it is kept iff draw · max(n_user, n_thing) <
+    cap · 2³², in integers (`sample_draw`). The draw is evaluated only
+    where a count is over the cap: elsewhere the pair is always kept."""
+    n_user = np.diff(events.ptr)
+    n_thing = np.bincount(events.cols, minlength=events.n_cols)
+    over_u = n_user > cap
+    over_t = n_thing > cap
+    if not (over_u.any() or over_t.any()):
+        return events
+    binding = over_t[events.cols]
+    binding |= np.repeat(over_u, n_user)
+    binding = np.flatnonzero(binding)
+    r = np.searchsorted(events.ptr, binding, side="right") - 1
+    c = events.cols[binding]
+    most = np.maximum(n_user[r], n_thing[c]).astype(np.uint64)
+    drop = sample_draw(seed, indicator, r, c)
+    drop *= most
+    drop = drop >= np.uint64(cap) << np.uint64(32)
+    keep = np.ones(events.cols.size, bool)
+    keep[binding[drop]] = False
+    dropped = np.bincount(r[drop], minlength=n_user.size)
+    ptr = events.ptr.copy()
+    ptr[1:] -= np.cumsum(dropped)
+    return UserEvents(events.cols[keep], ptr, events.n_cols)
+
+
+def _bucket(n: int) -> int:
+    """A padded length for `n` entries: n rounded up to a multiple of
+    2^(bits(n) − 4), at most 1/8 more."""
+    quantum = 1 << max(10, int(n).bit_length() - 4)
+    return max(quantum, -(-int(n) // quantum) * quantum)
+
+
+def _plan_blocks(pairs_per_item: np.ndarray, size: int) -> list:
+    """Cut the axis of (indicator, item) — g = m·I + item — into blocks of
+    at most `size` pairs: [(g_lo, g_hi), ...], an item whole in one
+    block."""
+    cum = np.concatenate([[0], np.cumsum(pairs_per_item, dtype=np.int64)])
+    blocks, lo = [], 0
+    while lo < pairs_per_item.size:
+        hi = int(np.searchsorted(cum, cum[lo] + size, side="right")) - 1
+        if hi <= lo:
+            raise ValueError(
+                f"an item makes {int(pairs_per_item[lo])} pairs: more than "
+                f"a block of {size}")
+        blocks.append((lo, hi))
+        lo = hi
+    return blocks
+
+
+class _PairBlock:
+    """The host's buffers of one block, reused block after block: each
+    pair's key g = m·I + item, its thing, and the LLR's margins (the
+    item's and the thing's kept events)."""
+
+    def __init__(self, size: int):
+        self.items = np.empty(size, np.int32)
+        self.things = np.empty(size, np.int32)
+        self.prim_totals = np.empty(size, np.int32)
+        self.sec_totals = np.empty(size, np.int32)
+        self.at = np.empty(size, np.int32)
+        self.arange = np.arange(size, dtype=np.int32)
+
+    @staticmethod
+    def _fill(buf, starts, values):
+        """`buf` constant `values[j]` from `starts[j]` to the next start:
+        the differences at the starts, then one cumulative sum."""
+        buf.fill(0)
+        buf[starts] = np.diff(values, prepend=0)
+        np.cumsum(buf, out=buf)
+
+    def lay_out(self, at: int, items, prim_totals, firsts, lens,
+                secondary: UserEvents, sec_totals) -> None:
+        """Write the pairs of primary events (their keys, their items'
+        totals, their users' first secondary event, the number of those
+        events) from `at`: each event's key repeated over its user's
+        secondary events, which are the things."""
+        end = at + int(lens.sum())
+        starts = np.cumsum(lens) - lens
+        span = slice(at, end)
+        self._fill(self.items[span], starts, items)
+        self._fill(self.prim_totals[span], starts, prim_totals)
+        # the position of the pair's thing among the secondary events:
+        # its user's first, plus how far into the event's run it lies
+        self._fill(self.at[span], starts, firsts - starts)
+        self.at[span] += self.arange[: end - at]
+        np.take(secondary.cols, self.at[span], out=self.things[span])
+        np.take(sec_totals, self.things[span], out=self.sec_totals[span])
+
+
+@jax.jit
+def _pair_counts_jit(  # lint: disable=jit-boundary — train, once a block
+    items,  # (B,) int32: each pair's key g = m·I + item (padding: any)
+    things,  # (B,) int32: its secondary thing
+    prim_totals,  # (B,) int32: the item's kept events
+    sec_totals,  # (B,) int32: the thing's kept events
+    n_pairs,  # () int32: live pairs; the rest is padding
+    diagonal_below,  # () int32: keys under it are the self indicator's
+):
+    """The pairs sorted by (key, thing), the totals riding along, and
+    each run of equal pairs counted: at the run's first pair its length,
+    0 elsewhere; and the number of runs. A self-indicator pair of an item
+    with itself is dropped; a dead pair sorts last under `_DEAD`."""
+    p = jnp.arange(items.shape[0], dtype=jnp.int32)
+    live = (p < n_pairs) & ~((things == items) & (items < diagonal_below))
+    items, things, prim_totals, sec_totals = jax.lax.sort(
+        (jnp.where(live, items, _DEAD), jnp.where(live, things, _DEAD),
+         prim_totals, sec_totals),
+        num_keys=2,
+    )
+    dead = items == _DEAD
+    first = jnp.concatenate([
+        jnp.ones((1,), bool),
+        (items[1:] != items[:-1]) | (things[1:] != things[:-1]),
+    ]) & ~dead
+    # a run's length: where the next run (or the dead tail) starts, less
+    # its own start
+    nxt = jax.lax.cummin(jnp.where(first | dead, p, p.shape[0]), reverse=True)
+    nxt = jnp.concatenate([nxt[1:], jnp.full((1,), p.shape[0], jnp.int32)])
+    counts = jnp.where(first, nxt - p, 0)
+    return (items, things, counts, prim_totals, sec_totals,
+            jnp.sum(first, dtype=jnp.int32))
+
+
+@partial(jax.jit, static_argnames=("top_n",), donate_argnums=(0, 1))
+def _llr_topn_jit(  # lint: disable=jit-boundary — train, once a block
+    table_idx,  # (M·I·top_n,) int32, every indicator's (donated)
+    table_scores,  # (M·I·top_n,) float32 (donated)
+    items,  # (B,) int32 sorted keys, `_DEAD` past the live pairs
+    things,  # (B,) int32
+    counts,  # (B,) int32: a run's length at its first pair, else 0
+    prim_totals,  # (B,) int32
+    sec_totals,  # (B,) int32
+    n_users,  # () float32
+    *,
+    top_n: int,
+):
+    """Each run's LLR, then each key's best `top_n` things: the runs
+    sorted by (key, −LLR, thing), a run's rank in its key's segment, and
+    the first `top_n` of a segment scattered into the key's row of the
+    tables, in place; a row keeps -1 / 0 where it has fewer."""
+    score = llr(counts, prim_totals, sec_totals, n_users)
+    keep = score > 0
+    items, neg, things = jax.lax.sort(
+        (jnp.where(keep, items, _DEAD), jnp.where(keep, -score, 0.0),
+         jnp.where(keep, things, _DEAD)),
+        num_keys=3,
+    )
+    p = jnp.arange(items.shape[0], dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool), items[1:] != items[:-1]])
+    rank = p - jax.lax.cummax(jnp.where(first, p, 0))
+    slot = jnp.where((rank < top_n) & (items != _DEAD),
+                     items * top_n + rank, table_idx.shape[0])
+    return (table_idx.at[slot].set(things, mode="drop"),
+            table_scores.at[slot].set(-neg, mode="drop"))
+
+
+@partial(jax.jit, static_argnames=("rows", "top_n"))
+def _table_rows(table_idx, table_scores, start, *, rows: int, top_n: int):  # lint: disable=jit-boundary — train, once an indicator
+    """One indicator's (rows, top_n) tables out of every indicator's."""
+    size = rows * top_n
+    return (
+        jax.lax.dynamic_slice_in_dim(table_idx, start, size).reshape(
+            rows, top_n),
+        jax.lax.dynamic_slice_in_dim(table_scores, start, size).reshape(
+            rows, top_n),
+    )
+
+
+_pair_counts_jit = _devprof.instrument("cco.pair_counts", _pair_counts_jit)
+_llr_topn_jit = _devprof.instrument("cco.llr_topn", _llr_topn_jit)
+
+
+def join_indicators(
+    primary: UserEvents,
+    secondaries: list,
+    n_users: int,
+    top_n: int,
+    self_first: bool = False,
+) -> tuple[list, dict]:
+    """[(scores (I, top_n), idx (I, top_n) -1 padded), ...] one an
+    indicator of `secondaries` (each `UserEvents` over its own things),
+    joined with the primary's kept events, and the stats: the pairs of
+    each indicator, and each block's indicators, pairs and distinct. With
+    `self_first`, `secondaries[0]` is the primary itself and an item is
+    not its own correlator.
+
+    The pairs of every indicator, item range by item range, fill blocks
+    of at most `BLOCK_PAIRS` (`_plan_blocks`); a block's pairs are laid out on the
+    host and joined on the device (span `ur.train.join` a block: attrs
+    `indicators`, `pairs`, `distinct`), then scored and placed
+    (`ur.train.llr_topn`). An indicator's tables start home as soon as
+    its last block is placed; `ur.train.copy_back` waits for what is
+    left."""
+    n_items = primary.n_cols
+    n_ind = len(secondaries)
+    if n_ind * n_items * top_n >= _DEAD:
+        raise ValueError("the tables' slots outnumber an int32")
+    prim_rows = primary.rows()
+    order = np.argsort(primary.cols, kind="stable")
+    by_item = primary.cols[order]
+    prim_totals = primary.totals()
+    per_item = np.zeros((n_ind, n_items), np.int64)
+    lens = []
+    for m, sec in enumerate(secondaries):
+        lens.append(np.diff(sec.ptr)[prim_rows[order]])
+        per_item[m] = np.bincount(by_item, weights=lens[m],
+                                  minlength=n_items).astype(np.int64)
+    size = min(BLOCK_PAIRS, _bucket(max(int(per_item.sum()), 1)))
+    blocks = _plan_blocks(per_item.reshape(-1), size)
+    table_idx = jnp.full((n_ind * n_items * top_n,), -1, jnp.int32)
+    table_scores = jnp.zeros((n_ind * n_items * top_n,), jnp.float32)
+    buf = _PairBlock(size)
+    sec_totals = [sec.totals() for sec in secondaries]
+    homeward, tables, stats = [], [], []
+    for g_lo, g_hi in blocks:
+        block = {"indicators": [], "pairs": 0}
+        with _spans.span("ur.train.join") as sp:
+            for m in range(g_lo // n_items, (g_hi - 1) // n_items + 1):
+                lo = max(g_lo - m * n_items, 0)
+                hi = min(g_hi - m * n_items, n_items)
+                ev = slice(*np.searchsorted(by_item, [lo, hi]))
+                live = lens[m][ev] > 0
+                items = by_item[ev][live]
+                users = prim_rows[order[ev]][live]
+                buf.lay_out(
+                    block["pairs"], items + m * n_items, prim_totals[items],
+                    secondaries[m].ptr[users], lens[m][ev][live],
+                    secondaries[m], sec_totals[m])
+                block["indicators"].append(m)
+                block["pairs"] += int(lens[m][ev][live].sum())
+            *pairs, distinct = _pair_counts_jit(
+                *(jax.device_put(a) for a in (
+                    buf.items, buf.things, buf.prim_totals, buf.sec_totals)),
+                jnp.int32(block["pairs"]),
+                jnp.int32(n_items if self_first else 0),
+            )
+            block["distinct"] = int(distinct)  # the buffers are free again
+            sp.attrs.update(block)
+        stats.append(block)
+        # an indicator whose last item this block placed starts home
+        # while the next block is laid out
+        for pending in homeward:
+            tables.append(tuple(np.asarray(a) for a in pending))
+        homeward = []
+        with _spans.span("ur.train.llr_topn"):
+            table_idx, table_scores = _llr_topn_jit(
+                table_idx, table_scores, *pairs, jnp.float32(n_users),
+                top_n=top_n)
+            del pairs
+            jax.block_until_ready(table_scores)
+        while len(tables) + len(homeward) < n_ind and (
+                len(tables) + len(homeward) + 1) * n_items <= g_hi:
+            m = len(tables) + len(homeward)
+            idx, scores = _table_rows(
+                table_idx, table_scores, jnp.int32(m * n_items * top_n),
+                rows=n_items, top_n=top_n)
+            idx.copy_to_host_async()
+            scores.copy_to_host_async()
+            homeward.append((scores, idx))
+    with _spans.span("ur.train.copy_back"):
+        for pending in homeward:
+            tables.append(tuple(np.asarray(a) for a in pending))
+    return tables, {"pairs": [int(n.sum()) for n in lens], "blocks": stats}
 
 
 def score_history(
