@@ -256,8 +256,7 @@ def run_train(
                                 ctx, models, engine_params, instance_id
                             )
                             with _spans.span("persist.serialize") as sp:
-                                blob = serialize_models(serializable)
-                                sp.attrs["bytes"] = len(blob)
+                                blob = serialize_models(serializable, sp.attrs)
                             with _spans.span("persist.write"):
                                 storage.get_model_data_models().insert(
                                     Model(id=instance_id, models=blob)
