@@ -18,6 +18,11 @@ Counts stay exact in float32 (counts ≤ U < 2²⁴) with HIGHEST precision.
 
 from __future__ import annotations
 
+import contextvars
+import os
+import threading
+import time
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import NamedTuple, Optional
 
@@ -26,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from predictionio_tpu.obs import devprof as _devprof
+from predictionio_tpu.obs import get_default_registry
 from predictionio_tpu.obs import spans as _spans
 from predictionio_tpu.ops.topk import NEG_INF, masked_top_k
 
@@ -256,8 +262,28 @@ DOWNSAMPLE_SEED = 0xDEADBEEF
 #: pairs a block holds at most. A job with more cuts its pairs into
 #: blocks of this one shape, so each program compiles once; a smaller job
 #: takes one block of its own size (`_bucket`). An item's pairs never
-#: straddle two blocks
+#: straddle two blocks.
+#:
+#: The host lays the blocks out one ahead of the device, into two sets of
+#: buffers used in turn: while the caller's thread runs block n's two
+#: programs, block n + 1 is laid out into the other set and handed over
+#: (`_Pipeline`). A block's layout is cut into chunks of contiguous
+#: primary events, each writing its own span of the buffers, and the
+#: chunks run on `_LAYOUT_WORKERS` threads. A set is written again only
+#: once the program that read it has run, and block n + 1 is handed over
+#: only once block n's `_pair_counts_jit` has returned: at most one
+#: block's inputs wait on the device
 BLOCK_PAIRS = 1 << 25
+
+#: threads that lay a block's chunks out: the CPUs this process may run
+#: on, at most 8
+_LAYOUT_WORKERS = max(1, min(8, (
+    len(os.sched_getaffinity(0)) if hasattr(os, "sched_getaffinity")
+    else os.cpu_count() or 1)))
+
+#: pairs a layout chunk holds, as near as whole primary events allow: a
+#: block of fewer is one chunk
+_LAYOUT_CHUNK_PAIRS = 1 << 21
 
 #: sort key of a dead pair (padding, the diagonal, a pair not kept): after
 #: every item and thing
@@ -391,17 +417,19 @@ def _plan_blocks(pairs_per_item: np.ndarray, size: int) -> list:
 
 
 class _PairBlock:
-    """The host's buffers of one block, reused block after block: each
-    pair's key g = m·I + item, its thing, and the LLR's margins (the
-    item's and the thing's kept events)."""
+    """One set of the host's buffers of a block, reused every other
+    block: each pair's key g = m·I + item, its thing, and the LLR's
+    margins (the item's and the thing's kept events). Two sets may share
+    one read-only `arange`."""
 
-    def __init__(self, size: int):
+    def __init__(self, size: int, arange: Optional[np.ndarray] = None):
         self.items = np.empty(size, np.int32)
         self.things = np.empty(size, np.int32)
         self.prim_totals = np.empty(size, np.int32)
         self.sec_totals = np.empty(size, np.int32)
         self.at = np.empty(size, np.int32)
-        self.arange = np.arange(size, dtype=np.int32)
+        self.arange = (np.arange(size, dtype=np.int32) if arange is None
+                       else arange)
 
     @staticmethod
     def _fill(buf, starts, values):
@@ -428,6 +456,146 @@ class _PairBlock:
         self.at[span] += self.arange[: end - at]
         np.take(secondary.cols, self.at[span], out=self.things[span])
         np.take(sec_totals, self.things[span], out=self.sec_totals[span])
+
+
+class _Layout:
+    """What laying a block out reads, for every block of a join: the
+    primary's kept events in item order (their items, their users and,
+    an indicator each, how many secondary events their user has), the
+    items' totals, and every indicator's events and totals."""
+
+    def __init__(self, primary: UserEvents, secondaries: list):
+        order = np.argsort(primary.cols, kind="stable")
+        self.by_item = primary.cols[order]
+        self.users = primary.rows()[order]
+        self.prim_totals = primary.totals()
+        self.secondaries = secondaries
+        self.sec_totals = [sec.totals() for sec in secondaries]
+        self.lens = [np.diff(sec.ptr)[self.users] for sec in secondaries]
+        self.n_items = primary.n_cols
+
+    def chunks(self, g_lo: int, g_hi: int, limit: int) -> tuple[list, dict]:
+        """The block [g_lo, g_hi) cut into chunks of about `limit` pairs:
+        [(m, first event, end event, at), ...], each a run of the primary
+        events of indicator m's item range in the block, whose pairs are
+        written from `at`; and the block's indicators and pairs."""
+        chunks, at, indicators = [], 0, []
+        n = self.n_items
+        for m in range(g_lo // n, (g_hi - 1) // n + 1):
+            e_lo, e_hi = (int(e) for e in np.searchsorted(
+                self.by_item, [max(g_lo - m * n, 0), min(g_hi - m * n, n)]))
+            # the pairs up to each event's end; a chunk ends at the last
+            # event that ends within its multiple of `limit`
+            ends = np.cumsum(self.lens[m][e_lo:e_hi])
+            pairs = int(ends[-1]) if ends.size else 0
+            cuts = np.searchsorted(ends, np.arange(limit, pairs, limit),
+                                   side="right")
+            bounds = np.unique(np.concatenate([[0], cuts, [e_hi - e_lo]]))
+            for a, b in zip(bounds[:-1].tolist(), bounds[1:].tolist()):
+                chunks.append(
+                    (m, e_lo + a, e_lo + b, at + (int(ends[a - 1]) if a else 0)))
+            indicators.append(m)
+            at += pairs
+        return chunks, {"indicators": indicators, "pairs": at}
+
+    def lay_out(self, buf: _PairBlock, chunk: tuple) -> None:
+        """One chunk's pairs into its span of `buf`."""
+        m, e_lo, e_hi, at = chunk
+        lens = self.lens[m][e_lo:e_hi]
+        live = lens > 0
+        items = self.by_item[e_lo:e_hi][live]
+        sec = self.secondaries[m]
+        buf.lay_out(at, items + m * self.n_items, self.prim_totals[items],
+                    sec.ptr[self.users[e_lo:e_hi][live]], lens[live], sec,
+                    self.sec_totals[m])
+
+
+def _hand_over(buf: _PairBlock) -> tuple:
+    """A laid-out block's four arrays on the device, transferred."""
+    return jax.block_until_ready(tuple(jax.device_put(a) for a in (
+        buf.items, buf.things, buf.prim_totals, buf.sec_totals)))
+
+
+_LAYOUT_BLOCKS = get_default_registry().counter(
+    "ur_train_layout_blocks_total",
+    "join blocks by whether their layout was on the device when the join "
+    "asked for it (ahead) or set the pace (waited; a job's first block)",
+    labelnames=("ready",),  # label-bound: literal set ahead / waited
+)
+
+
+class _Pipeline:
+    """A join's blocks laid out and handed over one ahead of the caller,
+    on a coordinating thread and `_LAYOUT_WORKERS` chunk threads: block k
+    goes into buffer set k mod 2, so block k + 2 is laid out only once
+    the caller has `consumed` block k, and block k + 1 is handed over only
+    then too. A context manager: on exit, by error too, the threads are
+    stopped and joined."""
+
+    def __init__(self, layout: _Layout, blocks: list, size: int):
+        self._layout, self._blocks = layout, blocks
+        first = _PairBlock(size)
+        self._sets = (first, _PairBlock(size, first.arange))
+        self._consumed = [threading.Event() for _ in blocks]
+        self._closed = False
+        self._chunk_pool = ThreadPoolExecutor(
+            _LAYOUT_WORKERS, thread_name_prefix="ur-layout")
+        self._coordinator = ThreadPoolExecutor(
+            1, thread_name_prefix="ur-layout-hand-over")
+        self._jobs: dict = {}
+        for k in range(min(2, len(blocks))):
+            self._submit(k)
+
+    def __enter__(self) -> "_Pipeline":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+    def _submit(self, k: int) -> None:
+        # in a copy of the caller's context: the span counts in the job's
+        # collected timings
+        self._jobs[k] = self._coordinator.submit(
+            contextvars.copy_context().run, self._prepare, k)
+
+    def _prepare(self, k: int):
+        """Block k laid out into its set and handed over: (its indicators
+        and pairs, the four device arrays)."""
+        buf = self._sets[k % 2]
+        # a root of its own: the caller's spans keep their self time. It
+        # times the layout alone, not the wait for the device to take it
+        with _spans.detached(), _spans.span(
+                "ur.train.lay_out", workers=_LAYOUT_WORKERS) as sp:
+            chunks, block = self._layout.chunks(
+                *self._blocks[k], _LAYOUT_CHUNK_PAIRS)
+            sp.attrs.update(chunks=len(chunks), pairs=block["pairs"])
+            for done in [self._chunk_pool.submit(self._layout.lay_out, buf, c)
+                         for c in chunks]:
+                done.result()
+        if k:
+            self._consumed[k - 1].wait()
+        if self._closed:
+            return None
+        return block, _hand_over(buf)
+
+    def ready(self, k: int):
+        """Block k's (indicators and pairs, device arrays), once handed
+        over; a layout's error is raised here."""
+        return self._jobs.pop(k).result()
+
+    def consumed(self, k: int) -> None:
+        """Block k's program has run: its set is free, and block k + 1
+        may go to the device."""
+        self._consumed[k].set()
+        if k + 2 < len(self._blocks):
+            self._submit(k + 2)
+
+    def close(self) -> None:
+        self._closed = True
+        for event in self._consumed:
+            event.set()
+        self._coordinator.shutdown(wait=True, cancel_futures=True)
+        self._chunk_pool.shutdown(wait=True, cancel_futures=True)
 
 
 @jax.jit
@@ -528,82 +696,73 @@ def join_indicators(
     not its own correlator.
 
     The pairs of every indicator, item range by item range, fill blocks
-    of at most `BLOCK_PAIRS` (`_plan_blocks`); a block's pairs are laid out on the
-    host and joined on the device (span `ur.train.join` a block: attrs
-    `indicators`, `pairs`, `distinct`), then scored and placed
-    (`ur.train.llr_topn`). An indicator's tables start home as soon as
-    its last block is placed; `ur.train.copy_back` waits for what is
-    left."""
+    of at most `BLOCK_PAIRS` (`_plan_blocks`). A block is laid out on the
+    host one ahead of the device (`_Pipeline`: two buffer sets used in
+    turn, a block in chunks on worker threads; span `ur.train.lay_out`
+    a block, attrs `chunks`, `workers`, `pairs`). On the caller's thread
+    a block is waited for and joined on the device (span `ur.train.join`
+    a block: attrs `indicators`, `pairs`, `distinct`, `ready_wait_s`;
+    counter `ur_train_layout_blocks_total{ready}`), then scored and
+    placed (`ur.train.llr_topn`). An indicator's tables start home as
+    soon as its last block is placed; `ur.train.copy_back` waits for
+    what is left."""
     n_items = primary.n_cols
     n_ind = len(secondaries)
     if n_ind * n_items * top_n >= _DEAD:
         raise ValueError("the tables' slots outnumber an int32")
-    prim_rows = primary.rows()
-    order = np.argsort(primary.cols, kind="stable")
-    by_item = primary.cols[order]
-    prim_totals = primary.totals()
+    layout = _Layout(primary, secondaries)
     per_item = np.zeros((n_ind, n_items), np.int64)
-    lens = []
-    for m, sec in enumerate(secondaries):
-        lens.append(np.diff(sec.ptr)[prim_rows[order]])
-        per_item[m] = np.bincount(by_item, weights=lens[m],
+    for m, lens in enumerate(layout.lens):
+        per_item[m] = np.bincount(layout.by_item, weights=lens,
                                   minlength=n_items).astype(np.int64)
     size = min(BLOCK_PAIRS, _bucket(max(int(per_item.sum()), 1)))
     blocks = _plan_blocks(per_item.reshape(-1), size)
     table_idx = jnp.full((n_ind * n_items * top_n,), -1, jnp.int32)
     table_scores = jnp.zeros((n_ind * n_items * top_n,), jnp.float32)
-    buf = _PairBlock(size)
-    sec_totals = [sec.totals() for sec in secondaries]
     homeward, tables, stats = [], [], []
-    for g_lo, g_hi in blocks:
-        block = {"indicators": [], "pairs": 0}
-        with _spans.span("ur.train.join") as sp:
-            for m in range(g_lo // n_items, (g_hi - 1) // n_items + 1):
-                lo = max(g_lo - m * n_items, 0)
-                hi = min(g_hi - m * n_items, n_items)
-                ev = slice(*np.searchsorted(by_item, [lo, hi]))
-                live = lens[m][ev] > 0
-                items = by_item[ev][live]
-                users = prim_rows[order[ev]][live]
-                buf.lay_out(
-                    block["pairs"], items + m * n_items, prim_totals[items],
-                    secondaries[m].ptr[users], lens[m][ev][live],
-                    secondaries[m], sec_totals[m])
-                block["indicators"].append(m)
-                block["pairs"] += int(lens[m][ev][live].sum())
-            *pairs, distinct = _pair_counts_jit(
-                *(jax.device_put(a) for a in (
-                    buf.items, buf.things, buf.prim_totals, buf.sec_totals)),
-                jnp.int32(block["pairs"]),
-                jnp.int32(n_items if self_first else 0),
-            )
-            block["distinct"] = int(distinct)  # the buffers are free again
-            sp.attrs.update(block)
-        stats.append(block)
-        # an indicator whose last item this block placed starts home
-        # while the next block is laid out
-        for pending in homeward:
-            tables.append(tuple(np.asarray(a) for a in pending))
-        homeward = []
-        with _spans.span("ur.train.llr_topn"):
-            table_idx, table_scores = _llr_topn_jit(
-                table_idx, table_scores, *pairs, jnp.float32(n_users),
-                top_n=top_n)
-            del pairs
-            jax.block_until_ready(table_scores)
-        while len(tables) + len(homeward) < n_ind and (
-                len(tables) + len(homeward) + 1) * n_items <= g_hi:
-            m = len(tables) + len(homeward)
-            idx, scores = _table_rows(
-                table_idx, table_scores, jnp.int32(m * n_items * top_n),
-                rows=n_items, top_n=top_n)
-            idx.copy_to_host_async()
-            scores.copy_to_host_async()
-            homeward.append((scores, idx))
+    with _Pipeline(layout, blocks, size) as ahead:
+        for k, (_g_lo, g_hi) in enumerate(blocks):
+            with _spans.span("ur.train.join") as sp:
+                t0 = time.monotonic()
+                block, inputs = ahead.ready(k)
+                waited = time.monotonic() - t0
+                _LAYOUT_BLOCKS.inc(
+                    ready="ahead" if k and waited < 1e-3 else "waited")
+                *pairs, distinct = _pair_counts_jit(
+                    *inputs,
+                    jnp.int32(block["pairs"]),
+                    jnp.int32(n_items if self_first else 0),
+                )
+                del inputs
+                block["distinct"] = int(distinct)  # the set is free again
+                ahead.consumed(k)
+                sp.attrs.update(block, ready_wait_s=waited)
+            stats.append(block)
+            # an indicator whose last item this block placed starts home
+            # while the next block is joined
+            for pending in homeward:
+                tables.append(tuple(np.asarray(a) for a in pending))
+            homeward = []
+            with _spans.span("ur.train.llr_topn"):
+                table_idx, table_scores = _llr_topn_jit(
+                    table_idx, table_scores, *pairs, jnp.float32(n_users),
+                    top_n=top_n)
+                del pairs
+                jax.block_until_ready(table_scores)
+            while len(tables) + len(homeward) < n_ind and (
+                    len(tables) + len(homeward) + 1) * n_items <= g_hi:
+                m = len(tables) + len(homeward)
+                idx, scores = _table_rows(
+                    table_idx, table_scores, jnp.int32(m * n_items * top_n),
+                    rows=n_items, top_n=top_n)
+                idx.copy_to_host_async()
+                scores.copy_to_host_async()
+                homeward.append((scores, idx))
     with _spans.span("ur.train.copy_back"):
         for pending in homeward:
             tables.append(tuple(np.asarray(a) for a in pending))
-    return tables, {"pairs": [int(n.sum()) for n in lens], "blocks": stats}
+    return tables, {"pairs": [int(n.sum()) for n in layout.lens],
+                    "blocks": stats}
 
 
 def score_history(

@@ -5,8 +5,11 @@ that holds float32 at a million users, Mahout's downsampling, and the whole
 
 from __future__ import annotations
 
+import json
 import os
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -141,6 +144,15 @@ def train_small(cfg: dict, seed: int):
     """(events, the persisted model read back) of one `run_train` job
     through the benchmark's in-memory UR data source."""
     from predictionio_tpu.controller.persistent import deserialize_models
+
+    events, inst, storage = run_small(cfg, seed)
+    blob = storage.get_model_data_models().get(inst.id).models
+    return events, deserialize_models(blob)[0]
+
+
+def run_small(cfg: dict, seed: int):
+    """(events, the COMPLETED instance, its storage) of one `run_train`
+    job through the benchmark's in-memory UR data source."""
     from predictionio_tpu.workflow.core import run_train
 
     events = ur_train_data.make_events(cfg, seed)
@@ -157,8 +169,7 @@ def train_small(cfg: dict, seed: int):
     finally:
         ur_train_data.CORPORA.clear()
     assert inst.status == "COMPLETED"
-    blob = storage.get_model_data_models().get(inst.id).models
-    return events, deserialize_models(blob)[0]
+    return events, inst, storage
 
 
 #: the caps the parity runs at: 5, where users and items are over it by
@@ -290,3 +301,211 @@ def test_blocks_of_any_size_give_the_same_tables(monkeypatch):
     for (s1, i1), (s2, i2) in zip(one, many):
         np.testing.assert_array_equal(i1, i2)
         np.testing.assert_array_equal(s1, s2)
+
+
+# -- the layout pipeline: chunks on worker threads, one block ahead ------------
+
+
+def kept_small(seed: int = 23) -> list:
+    """SMALL's four indicators grouped and downsampled at cap 5: the
+    primary (buy) first."""
+    events = ur_train_data.make_events(SMALL, seed)
+    return [cco.downsample(cco.group_by_user(
+        *events[name], SMALL["n_users"], SMALL["n_items"]), 5,
+        cco.DOWNSAMPLE_SEED, m) for m, name in enumerate(SMALL["indicators"])]
+
+
+def join_small(kept: list):
+    return cco.join_indicators(kept[0], kept, SMALL["n_users"], 50,
+                               self_first=True)
+
+
+def pool_threads() -> list:
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("ur-layout")]
+
+
+@pytest.mark.parametrize("workers", [1, 2, 8])
+def test_chunks_on_any_number_of_workers_place_the_tables_of_one_block(
+        monkeypatch, workers):
+    """Many blocks, several chunks each, a block over several indicators,
+    laid out on 1, 2 or 8 threads: the tables of one whole block, bit for
+    bit, and the stats of the same blocks laid out whole."""
+    kept = kept_small()
+    one, one_stats = join_small(kept)
+    monkeypatch.setattr(cco, "BLOCK_PAIRS", 1024)
+    whole, whole_stats = join_small(kept)
+    calls = []
+    lay_out = cco._Layout.lay_out
+
+    def counted(self, buf, chunk):
+        calls.append(chunk)
+        lay_out(self, buf, chunk)
+
+    monkeypatch.setattr(cco._Layout, "lay_out", counted)
+    monkeypatch.setattr(cco, "_LAYOUT_CHUNK_PAIRS", 100)
+    monkeypatch.setattr(cco, "_LAYOUT_WORKERS", workers)
+    many, stats = join_small(kept)
+    assert len(one_stats["blocks"]) == 1
+    assert len(stats["blocks"]) > 2 * len(kept)
+    assert any(len(b["indicators"]) > 1 for b in stats["blocks"])
+    assert len(calls) > 4 * len(stats["blocks"])
+    assert stats == whole_stats
+    assert stats["pairs"] == one_stats["pairs"]
+    for (s1, i1), (s2, i2) in zip(one, many):
+        np.testing.assert_array_equal(i1, i2)
+        np.testing.assert_array_equal(s1, s2)
+    assert not pool_threads()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_a_blocks_chunks_lay_out_what_the_whole_block_does(seed):
+    """Random lens (users with no secondary events among them): the
+    chunks, laid out in any order, write the slots one whole `lay_out`
+    writes, in contiguous and disjoint spans."""
+    rng = np.random.default_rng(seed)
+    n_users, n_items, n_things = 400, 60, 90
+    primary = cco.group_by_user(rng.integers(0, n_users, 3000),
+                                rng.integers(0, n_items, 3000), n_users,
+                                n_items)
+    # a third of the users have no secondary events
+    busy = rng.permutation(n_users)[: 2 * n_users // 3]
+    secondary = cco.group_by_user(rng.choice(busy, 5000),
+                                  rng.integers(0, n_things, 5000), n_users,
+                                  n_things)
+    layout = cco._Layout(primary, [secondary])
+    lens = layout.lens[0]
+    assert (lens == 0).any()
+    live = lens > 0
+    items = layout.by_item[live]
+    whole = cco._PairBlock(int(lens.sum()))
+    whole.lay_out(0, items, layout.prim_totals[items],
+                  secondary.ptr[layout.users[live]], lens[live], secondary,
+                  layout.sec_totals[0])
+    chunks, block = layout.chunks(0, n_items, 97)
+    assert block == {"indicators": [0], "pairs": int(lens.sum())}
+    assert len(chunks) > 3
+    at = 0
+    for _m, e_lo, e_hi, start in chunks:
+        assert start == at
+        at += int(lens[e_lo:e_hi].sum())
+    assert at == block["pairs"]
+    chunked = cco._PairBlock(block["pairs"])
+    for chunk in rng.permutation(len(chunks)):
+        layout.lay_out(chunked, chunks[chunk])
+    for name in ("items", "things", "prim_totals", "sec_totals"):
+        np.testing.assert_array_equal(getattr(chunked, name),
+                                      getattr(whole, name))
+
+
+def test_the_next_block_is_laid_out_while_the_device_runs_this_one(
+        monkeypatch):
+    """Block n + 1's `ur.train.lay_out` starts before block n's
+    `_pair_counts_jit` returns, so before its `_llr_topn_jit` does, and a
+    block is handed over only once the block before it has been counted:
+    never two ahead."""
+    from predictionio_tpu.obs import spans
+
+    kept = kept_small()
+    monkeypatch.setattr(cco, "BLOCK_PAIRS", 1024)
+    llr_runs, counted, handed = [], [], []
+    llr_topn, pair_counts, hand_over = (
+        cco._llr_topn_jit, cco._pair_counts_jit, cco._hand_over)
+
+    def slow_llr_topn(*args, **kwargs):
+        t0 = time.monotonic()
+        time.sleep(0.05)
+        out = llr_topn(*args, **kwargs)
+        llr_runs.append((t0, time.monotonic()))
+        return out
+
+    def pair_counts_counted(*args, **kwargs):
+        time.sleep(0.05)
+        out = pair_counts(*args, **kwargs)
+        counted.append(time.monotonic())
+        return out
+
+    def hand_over_noted(buf):
+        handed.append(len(counted))
+        return hand_over(buf)
+
+    monkeypatch.setattr(cco, "_llr_topn_jit", slow_llr_topn)
+    monkeypatch.setattr(cco, "_pair_counts_jit", pair_counts_counted)
+    monkeypatch.setattr(cco, "_hand_over", hand_over_noted)
+    t0 = time.monotonic()
+    _tables, stats = join_small(kept)
+    n = len(stats["blocks"])
+    assert n > 4 and len(llr_runs) == n
+    # block k is handed over once blocks up to k - 1 are counted, no later
+    # than block k - 1's scoring
+    assert handed == list(range(n))
+    lay_outs = sorted(
+        (sp for sp in spans.get_default_recorder().recent()
+         if sp.name == "ur.train.lay_out" and sp.start_mono >= t0),
+        key=lambda sp: sp.start_mono)
+    assert len(lay_outs) == n
+    for k in range(n - 1):
+        assert lay_outs[k + 1].start_mono < counted[k] < llr_runs[k][1]
+    assert all(sp.attrs["workers"] == cco._LAYOUT_WORKERS
+               and sp.attrs["chunks"] >= 1 for sp in lay_outs)
+    assert [sp.attrs["pairs"] for sp in lay_outs] == [
+        b["pairs"] for b in stats["blocks"]]
+
+
+class LayoutFault(Exception):
+    pass
+
+
+@pytest.mark.parametrize("where", ["layout worker", "caller"])
+def test_an_error_comes_out_of_the_join_and_no_thread_outlives_it(
+        monkeypatch, where):
+    kept = kept_small()
+    monkeypatch.setattr(cco, "BLOCK_PAIRS", 1024)
+    monkeypatch.setattr(cco, "_LAYOUT_CHUNK_PAIRS", 100)
+    calls = []
+
+    def fail_in_layout(self, buf, chunk):
+        calls.append(chunk)
+        if len(calls) == 12:
+            raise LayoutFault("a chunk failed")
+
+    def fail_in_scoring(*_args, **_kwargs):
+        raise LayoutFault("a program failed")
+
+    if where == "caller":
+        monkeypatch.setattr(cco, "_llr_topn_jit", fail_in_scoring)
+    else:
+        monkeypatch.setattr(cco._Layout, "lay_out", fail_in_layout)
+    with pytest.raises(LayoutFault) as err:
+        join_small(kept)
+    faulty = "fail_in_scoring" if where == "caller" else "fail_in_layout"
+    assert any(entry.name == faulty for entry in err.traceback)
+    assert not pool_threads()
+
+
+def test_a_job_times_its_layout_beside_the_join_and_counts_every_block(
+        monkeypatch):
+    """`run_train`: the workers' span is in the job's timings, the job's
+    unattributed time stays >= 0, and the counter counts each block once,
+    ahead or waited."""
+    monkeypatch.setattr(cco, "BLOCK_PAIRS", 1024)
+    joined = []
+    join = cco.join_indicators
+
+    def noted(*args, **kwargs):
+        out = join(*args, **kwargs)
+        joined.append(out[1])
+        return out
+
+    monkeypatch.setattr(cco, "join_indicators", noted)
+    counter = cco._LAYOUT_BLOCKS
+    before, waited = counter.total, counter.value(ready="waited")
+    _events, inst, _storage = run_small(
+        dict(SMALL, n_users=500, n_behaviours=20_000), 3)
+    timings = json.loads(inst.env["stage_timings"])
+    assert timings["ur.train.lay_out"] > 0
+    assert timings["unattributed"] >= 0
+    blocks = len(joined[0]["blocks"])
+    assert blocks > 1
+    assert counter.total - before == blocks
+    assert counter.value(ready="waited") - waited >= 1  # the first block
